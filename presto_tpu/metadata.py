@@ -80,13 +80,13 @@ class Session:
         #            single-key INNER/LEFT builds; table-friendly group
         #            counts) — ineligible shapes fall back to sorted, never
         #            raise;
-        #   auto   — pallas only where the runtime heuristics also expect it
-        #            to be PROFITABLE (joins: compiled backends only — the
-        #            interpreted kernels measurably lose; agg: small
-        #            observed group counts on sync-cheap backends), sorted
-        #            everywhere else.
-        # Kernels interpret off-TPU, so all three values are row-identical
-        # on every backend (tests/test_pallas_hash.py is the contract).
+        #   auto   — sorted on every backend until a kernel compiles for
+        #            the chip (local_planner._hash_kernels).
+        # The kernels run only under the Pallas interpreter (every backend
+        # but a TPU), where all three values are row-identical
+        # (tests/test_pallas_hash.py is the contract). The v5e compiler
+        # refuses both ("64-bit types are not supported";
+        # tests/test_chip_compile.py), so `pallas` on a TPU raises that.
         # Note: aggregations whose partials run inside FUSED segments keep
         # the sort kernel (the segment compiles the sort partial config at
         # plan time); the agg half engages on unfused pipelines.
@@ -215,10 +215,11 @@ class Session:
 
 def default_page_capacity() -> int:
     """Platform default page size, resolved at execution time. Pages are the
-    unit of dispatch: on an accelerator every page costs kernel-launch
-    round-trips (over a remote tunnel each is a network RTT), so pages are
-    sized to make the page COUNT small — SF1 lineitem is 2 x 4M-row pages
-    instead of 23 x 256k. XLA-CPU prefers cache-sized batches (256k)."""
+    unit of dispatch: on an accelerator every page costs host-side dispatch
+    work per operator, so pages are sized to make the page COUNT small (a
+    scan's pages are further bounded by its splits: SF1 lineitem arrives as
+    8 x 1M-row pages, not 23 x 256k). XLA-CPU prefers cache-sized batches
+    (256k)."""
     import jax
 
     return (1 << 22) if jax.default_backend() != "cpu" else (1 << 18)

@@ -43,6 +43,7 @@ import numpy as np
 from ..block import Block, Dictionary, Page
 from ..types import BIGINT, BOOLEAN, Type, is_string
 from ..utils import kernel_cache
+from ..utils.batching import clamp_capacity
 from .aggregates import ACARRY, AMAX, AMIN, MAX, MIN, SUM, AggregateCall
 from .operator import Operator, OperatorContext, OperatorFactory, timed
 from .sorting import lexsort_fast
@@ -403,8 +404,7 @@ class GroupedAggregationBuilder:
         # Pallas insert-or-accumulate grouping (ops/pallas_hash.py), the
         # `hash_kernels` session property's agg half: "force" engages
         # wherever CORRECT (integer-comparable keys, scalar states, grouping
-        # that reduces), "auto" only where the same heuristic that shrinks
-        # partial tables expects a win, "off" (default) keeps pure
+        # that reduces), "off" (default) keeps pure
         # sort+segment-reduce. Decided once from the first page's true group
         # count (_decide_strategy); an insert overflow at any later page
         # falls back to the sort kernel permanently — never a wrong result.
@@ -543,19 +543,14 @@ class GroupedAggregationBuilder:
         on_cpu = _jax.default_backend() == "cpu"
         if on_cpu and first_ng <= capacity // 8:
             self._out_groups = max(1024, _pow2(int(first_ng * 1.5) + 1))
-        if self._hash_grouping != "off" and self._keys_hashable():
-            # "auto" mirrors the shrunken-table heuristic (sync-cheap
-            # backend, strongly reducing grouping); "force" engages wherever
-            # the table is merely CORRECT — grouping reduces at all and the
-            # keys compare as int64 (the bench / differential posture)
-            friendly = capacity // 8 if self._hash_grouping == "auto" \
-                else capacity // 2
-            # decline upfront when the capped table provably cannot hold
-            # the observed count at load <= 0.5 — otherwise the first hash
-            # page would pay a full (interpreted) insert just to overflow
+        if self._hash_grouping == "force" and self._keys_hashable():
+            # engages wherever the table is merely CORRECT — grouping
+            # reduces at all and the keys compare as int64. Decline upfront
+            # when the capped table provably cannot hold the observed count
+            # at load <= 0.5 — otherwise the first hash page would pay a
+            # full insert just to overflow
             slot_cap = 1 << 16
-            if first_ng <= min(friendly, slot_cap // 4) and \
-                    (self._hash_grouping == "force" or on_cpu):
+            if first_ng <= min(capacity // 2, slot_cap // 4):
                 self._hash_slots = max(1 << 10, _pow2(4 * first_ng))
 
     def _keys_hashable(self) -> bool:
@@ -666,8 +661,8 @@ class GroupedAggregationBuilder:
         size = self._table_size or _pow2(min(total_rows, self.max_groups))
         while True:
             # concat + sort + reduce in ONE jitted dispatch (the eager
-            # per-column concatenates were a dispatch each — costly on a
-            # remote accelerator)
+            # per-column concatenates were a dispatch, and on first use a
+            # compiled program, each)
             gkeys, gstates, gvalid, ngroups = _combine_parts_kernel(
                 key_parts, valid_parts, state_parts, self.kinds,
                 self.identities, size, self.widths)
@@ -1319,7 +1314,11 @@ class HashAggregationOperator(Operator):
             total = int(np.asarray(valid).sum())
         else:
             total = int(valid.shape[0])
-        cap = self.output_capacity
+        # size result pages to the live groups' pow2 bucket: at the
+        # accelerator page capacity (1 << 22) a 4-group result padded to a
+        # full page made every downstream sort and the result transfer
+        # scale with the padding
+        cap = clamp_capacity(total, self.output_capacity)
         # final transform per aggregate
         out_cols: List[Tuple] = []  # (type, data, dictionary, nulls)
         # builders return interleaved (value, null_flag) arrays per key column
@@ -1411,7 +1410,7 @@ class HashAggregationOperatorFactory(OperatorFactory):
         self.page_capacity = page_capacity
         self.max_groups = max_groups
         # "hash_kernels" session property -> the sort builder's Pallas
-        # insert-or-accumulate mode (off | auto | force)
+        # insert-or-accumulate mode (off | force)
         self.hash_grouping = hash_grouping
         self._kernel_donor = None
 

@@ -48,6 +48,7 @@ from ..block import Block, Dictionary, Page
 from ..exec.spill import storage_type_for
 from ..types import BIGINT, Type
 from .operator import Operator, OperatorContext, OperatorFactory, timed
+from .sorting import lexsort_fast
 
 INNER, LEFT, RIGHT, FULL, SEMI, ANTI = "inner", "left", "right", "full", "semi", "anti"
 
@@ -308,15 +309,14 @@ class JoinBuildOperator(Operator):
                 payload_nulls=tuple(None for _ in self.f.payload_meta))
         # one fused kernel: concat across pages + count + (dense table | key
         # sort). On the device this is ONE dispatch instead of one eager
-        # concatenate per column plus a host count sync — the TPU build wall
-        # is dispatch round-trips, not FLOPs (operator/PagesHash.java:34's
-        # role, re-shaped for a remote accelerator).
+        # concatenate per column plus a host count sync
+        # (operator/PagesHash.java:34's role).
         null_cols = tuple(i for i in range(len(self.f.payload_channels))
                           if any(p.blocks[kc + i].nulls is not None
                                  for p in self._pages))
         # pad the page count to its pow2 bucket with a zero-row dummy so the
         # fused build kernel's trace signature is bounded by O(log pages)
-        # distinct counts (remote compiles cost seconds each)
+        # distinct counts (a build compiles for 15-30 s on the v5e)
         pages = list(self._pages)
         want = 1 << max(0, (len(pages) - 1).bit_length())
         if want > len(pages):
@@ -511,15 +511,23 @@ def _fused_build_dense(pages, kc, null_cols, base, domain):
     return keys, payload, pnulls, mask, n, table
 
 
+def _sort_build_keys(ck, mask):
+    """-> (sorted combined keys with dead rows as +inf at the end, the
+    permutation). Goes through lexsort_fast's single-array sort: a
+    two-operand argsort costs the TPU compiler about twice as long. Dead
+    rows sort by the smallest live key so they cannot widen the packed
+    domain."""
+    big = jnp.int64(np.iinfo(np.int64).max)
+    ck = jnp.where(mask, ck, big)
+    order = lexsort_fast((jnp.where(mask, ck, jnp.min(ck)), ~mask))
+    return ck[order], order
+
+
 @functools.partial(jax.jit, static_argnames=("kc", "null_cols"))
 def _fused_build_sorted(pages, kc, null_cols):
     keys, payload, pnulls, mask, n = _concat_parts_impl(pages, kc, null_cols)
-    ck = combined_key(keys)
-    big = jnp.int64(np.iinfo(np.int64).max)
-    ck = jnp.where(mask, ck, big)
-    order = jnp.argsort(ck)
-    return (keys, payload, pnulls, mask, n,
-            ck[order], order.astype(jnp.int32))
+    return (keys, payload, pnulls, mask, n) + \
+        _sort_build_keys(combined_key(keys), mask)
 
 
 @functools.partial(jax.jit, static_argnames=("domain",))
@@ -541,12 +549,7 @@ def _build_dense(key, payload, mask, n, kmin, kmax, payload_meta, unique) -> Loo
                         table=table, base=kmin)
 
 
-@jax.jit
-def _sorted_kernel_ck(ck, mask):
-    big = jnp.int64(np.iinfo(np.int64).max)
-    ck = jnp.where(mask, ck, big)
-    order = jnp.argsort(ck)
-    return ck[order], order.astype(jnp.int32)
+_sorted_kernel_ck = jax.jit(_sort_build_keys)
 
 
 @jax.jit

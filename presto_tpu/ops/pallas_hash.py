@@ -25,8 +25,10 @@ wins — or a written negative result". This module is that kernel pair:
   adversarial clustering can never truncate a scan.
 
 Both kernels run through ``pl.pallas_call``; off-TPU they run with
-``interpret=True`` so correctness and benches run in tier-1 on CPU today and
-the SAME kernel is TPU-ready. Load factor is held at <= 0.5
+``interpret=True``, which is the only way they have ever run: the v5e
+compiler refuses both ("64-bit types are not supported" — the int64 slot
+components and the uint64 mixer; tests/test_chip_compile.py keeps the strict
+xfail). Load factor is held at <= 0.5
 (``table_slots`` returns 2N slots), which keeps expected probe distances
 O(1) under the mix64 hash the rest of the engine already routes with.
 """
@@ -59,9 +61,9 @@ EMPTY = -1  # free-slot / miss sentinel (plain int: kernels must not capture jnp
 
 @functools.lru_cache(maxsize=1)
 def interpret_mode() -> bool:
-    """Pallas interprets everywhere except on a real TPU backend — the
-    kernels are correctness-identical either way (the differential suite
-    runs them interpreted on CPU in tier-1)."""
+    """Pallas interprets everywhere except on a TPU backend, where the
+    kernels go to the chip's compiler (which today refuses them — see the
+    module docstring)."""
     return jax.default_backend() != "tpu"
 
 

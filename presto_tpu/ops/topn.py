@@ -151,6 +151,29 @@ class TopNOperatorFactory(OperatorFactory):
                             self.n, self.orders, self.types, self.dicts)
 
 
+@functools.partial(jax.jit, static_argnames=("orders",))
+def _sort_pages(pages: Tuple[Page, ...],
+                orders: Tuple[SortOrder, ...]) -> Page:
+    """Concatenate the buffered pages and order their rows — one dispatch
+    (run eagerly, every step was its own compiled program, and the sort's
+    loop recompiled on each call)."""
+    merged_blocks = []
+    for i, b0 in enumerate(pages[0].blocks):
+        datas = jnp.concatenate([p.blocks[i].data for p in pages])
+        anynull = any(p.blocks[i].nulls is not None for p in pages)
+        nulls = (jnp.concatenate([p.blocks[i].null_mask() for p in pages])
+                 if anynull else None)
+        merged_blocks.append(Block(b0.type, datas, nulls, b0.dictionary))
+    merged = Page(tuple(merged_blocks),
+                  jnp.concatenate([p.mask for p in pages]))
+    order = lexsort_fast(_sort_key_arrays(merged, orders) + (~merged.mask,))
+    blocks = []
+    for b in merged.blocks:
+        nulls = b.nulls[order] if b.nulls is not None else None
+        blocks.append(Block(b.type, b.data[order], nulls, b.dictionary))
+    return Page(tuple(blocks), merged.mask[order])
+
+
 class OrderByOperator(Operator):
     """Full sort: buffers all pages, sorts once at finish (OrderByOperator.java).
     Spill arrives with the revocation rev; a query-sized sort fits HBM for the TPC
@@ -185,23 +208,7 @@ class OrderByOperator(Operator):
 
     def _sort(self) -> List[Page]:
         cap = self._pages[0].capacity
-        merged_blocks = []
-        for i in range(len(self._pages[0].blocks)):
-            datas = jnp.concatenate([p.blocks[i].data for p in self._pages])
-            anynull = any(p.blocks[i].nulls is not None for p in self._pages)
-            nulls = (jnp.concatenate([p.blocks[i].null_mask() for p in self._pages])
-                     if anynull else None)
-            b0 = self._pages[0].blocks[i]
-            merged_blocks.append(Block(b0.type, datas, nulls, b0.dictionary))
-        mask = jnp.concatenate([p.mask for p in self._pages])
-        merged = Page(tuple(merged_blocks), mask)
-        keys = _sort_key_arrays(merged, self.orders) + (~merged.mask,)
-        order = lexsort_fast(keys)
-        blocks = []
-        for b in merged.blocks:
-            nulls = b.nulls[order] if b.nulls is not None else None
-            blocks.append(Block(b.type, b.data[order], nulls, b.dictionary))
-        sorted_page = Page(tuple(blocks), merged.mask[order])
+        sorted_page = _sort_pages(tuple(self._pages), tuple(self.orders))
         if self.output_channels is not None:
             sorted_page = sorted_page.select_channels(self.output_channels)
         # re-page to capacity-sized pages

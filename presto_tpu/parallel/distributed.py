@@ -20,9 +20,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import PartitionSpec as P
-from .mesh import shard_map
 
 from ..ops.hash_agg import sort_group_reduce
 from ..utils import kernel_cache

@@ -19,11 +19,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.5 exports shard_map at the top level
-    from jax import shard_map
-except ImportError:  # 0.4.x keeps it in the experimental namespace
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 WORKER_AXIS = "w"
 
 

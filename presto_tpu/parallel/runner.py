@@ -622,13 +622,13 @@ def _build_exchange_program(mesh, kind: str,
                             ncols: int, W: int, L: int, out_cap: int):
     import jax
     import jax.numpy as jnp
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops.hash_join import combined_key
     from .exchange import (broadcast_gather, gather_to_single,
                            range_partition_ids, repartition,
                            repartition_by_pid)
-    from .mesh import shard_map
 
     n_arrays = 2 * ncols
 

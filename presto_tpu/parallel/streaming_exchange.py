@@ -370,11 +370,10 @@ def _build_streaming_program(mesh, kind: str,
                              skew: Optional[str] = None):
     import jax
     import jax.numpy as jnp
-    from jax import lax
+    from jax import lax, shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..ops.hash_join import combined_key
-    from .mesh import shard_map
     from .exchange import (broadcast_gather, gather_to_single, partition_ids,
                            range_partition_ids, repartition_by_pid_with_carry)
 

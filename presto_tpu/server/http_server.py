@@ -34,7 +34,6 @@ from __future__ import annotations
 import os
 import json
 import re
-import sys
 import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
@@ -547,25 +546,14 @@ def main(argv=None) -> None:
         runner = LocalQueryRunner(session=session, catalogs=catalogs)
         mode = "local"
     if args.compile_ahead:
-        # worker-start cache warm (tools/compile_ahead.py): the ladder
-        # queries run once so every fused-segment/operator kernel is in the
-        # process kernel cache before the first tenant arrives
-        try:
-            from tools.compile_ahead import warm
-        except ImportError:  # installed without the tools/ tree
-            warm = None
-        qids = tuple(int(x) for x in args.compile_ahead.split(",") if x)
-        if warm is not None:
-            warm(schemas=(session.schema or args.schema,), queries=qids,
-                 session=session)
-        else:
-            from ..models.tpch_sql import QUERIES
-            for qid in qids:
-                try:
-                    runner.execute(QUERIES[qid])
-                except Exception as e:  # noqa: BLE001 - warm what we can
-                    print(f"compile-ahead q{qid}: FAILED {e!r}",
-                          file=sys.stderr)
+        # worker-start cache warm: the ladder queries run once through the
+        # serving runner so every fused-segment/operator kernel is in the
+        # process kernel cache before the first tenant arrives. A query that
+        # fails here fails server start.
+        from ..models.tpch_sql import QUERIES
+        for qid in args.compile_ahead.split(","):
+            if qid:
+                runner.execute(QUERIES[int(qid)])
     server = PrestoTpuServer(runner, port=port, authenticator=authenticator)
     print(f"presto-tpu server listening on :{server.port} "  # prestocheck: ignore[print-hygiene] - CLI startup banner
           f"({mode}, schema={args.schema}"

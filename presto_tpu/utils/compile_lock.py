@@ -63,20 +63,12 @@ def install() -> None:
     if _INSTALLED:
         return
     _INSTALLED = True
-    try:
-        from jax._src import compiler as _compiler
-    except Exception:  # jax internals moved: fail open (no serialization)
-        return
+    from jax._src import compiler as _compiler
 
-    # the hook point was renamed across jax versions: 0.4.x calls the
-    # module-global `backend_compile` from _compile_and_write_cache; newer
-    # jax split out `backend_compile_and_load`. Bind whichever exists —
-    # silently failing open here re-exposes the concurrent-LLVM segfault
-    # on every runner thread that compiles mid-execution.
-    attr = next((a for a in ("backend_compile_and_load", "backend_compile")
-                 if getattr(_compiler, a, None) is not None), None)
-    if attr is None:
-        return
+    # a missing hook raises here: failing open would re-expose the
+    # concurrent-LLVM segfault on every runner thread that compiles
+    # mid-execution
+    attr = "backend_compile_and_load"
     inner = getattr(_compiler, attr)
     if getattr(inner, "_presto_tpu_locked", False):
         return

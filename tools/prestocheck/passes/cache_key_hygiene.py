@@ -8,8 +8,8 @@ counters), keyed by canonical fingerprints. Two ways to break it:
 * **jit built outside the funnel** — a ``jax.jit(...)`` /
   ``pl.pallas_call(...)`` created inside a function body acquires a fresh
   function identity per call, so jax's trace cache can never hit: every
-  invocation is a silent full recompile (~1s host, seconds through a TPU
-  tunnel). Module-level creations (decorators, module constants) compile
+  invocation is a silent full recompile (~1s on the CPU backend, seconds
+  to a minute in the TPU compiler). Module-level creations (decorators, module constants) compile
   once per process and are fine; so are creations reachable from a
   ``get_or_build`` / ``get_or_install`` builder or an ``lru_cache``-
   memoized factory — those identities are cached by construction.

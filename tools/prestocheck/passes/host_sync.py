@@ -3,9 +3,8 @@
 ``Operator.add_input`` / ``Operator.get_output`` run once per page on the
 driver's hottest path (exec/driver.py `_process_once`). A ``np.asarray``,
 ``.item()``, ``jax.device_get`` or ``.block_until_ready()`` there forces a
-device->host round-trip per page — on an accelerator behind a remote tunnel
-each is a network RTT, and it serializes XLA's async dispatch pipeline (the
-whole reason page hand-offs are device-array handles). The fused-segment
+device->host round-trip per page, and it serializes XLA's async dispatch
+pipeline (the whole reason page hand-offs are device-array handles). The fused-segment
 work (ops/fused_segment.py) exists to REMOVE per-page dispatch overhead;
 this pass keeps new per-page syncs from sneaking back in.
 
