@@ -1,0 +1,6 @@
+"""queue_wait_s: how long a request waited before it ran (program_counter)."""
+from benchmark.harness import engine_spans
+
+
+def read(spec, window):
+    return engine_spans.histogram_mean_gain(window, spec["histograms"])

@@ -77,7 +77,7 @@ class PoolClient:
         self.pool = pool
         self.key = key
         self.refs = 0
-        self.gens: deque = deque()   # runnable (generator, trace recorder)
+        self.gens: deque = deque()   # runnable (generator, trace.capture())
         self.live = 0                # submitted, not yet finished
         self.steps = 0
 
@@ -85,7 +85,7 @@ class PoolClient:
         """Enqueue a stage generator. The submitting thread's active trace
         recorder rides along so pool workers attribute the stage's spans to
         the owning query (per-query trace scoping under shared threads)."""
-        self.pool._submit(self, gen, trace.active())
+        self.pool._submit(self, gen, trace.capture())
 
     def release(self) -> None:
         self.pool._release(self)
@@ -139,9 +139,9 @@ class SharedWorkerPool:
             self._threads.append(t)
             t.start()
 
-    def _submit(self, client: PoolClient, gen: Iterator, rec) -> None:
+    def _submit(self, client: PoolClient, gen: Iterator, traced) -> None:
         with self._cv:
-            client.gens.append((gen, rec))
+            client.gens.append((gen, traced))
             client.live += 1
             self._cv.notify_all()
 
@@ -169,7 +169,7 @@ class SharedWorkerPool:
 
     def _next_work(self):
         """Round-robin pick: the next client (from the rotation cursor) with
-        a runnable generator. Returns (client, gen, recorder) or None."""
+        a runnable generator. Returns (client, gen, traced) or None."""
         with self._cv:
             keys = list(self._clients)
             n = len(keys)
@@ -177,8 +177,8 @@ class SharedWorkerPool:
                 c = self._clients[keys[(self._rr + i) % n]]
                 if c.gens:
                     self._rr = (self._rr + i + 1) % max(n, 1)
-                    gen, rec = c.gens.popleft()
-                    return c, gen, rec
+                    gen, traced = c.gens.popleft()
+                    return c, gen, traced
             self._cv.wait(_IDLE_WAIT_S)
             return None
 
@@ -187,14 +187,14 @@ class SharedWorkerPool:
             work = self._next_work()
             if work is None:
                 continue
-            client, gen, rec = work
+            client, gen, traced = work
             finished = False
             try:
-                if rec is not None:
+                if traced[0] is not None:
                     # one coarse span per step: the black-box / flight
                     # recorder timeline shows WHEN each query's stages got
                     # pool service (category `pool`)
-                    with trace.bound(rec):
+                    with trace.bound(*traced):
                         with trace.span(trace.POOL, f"{self.name}_step",
                                         query=client.key):
                             next(gen)
@@ -219,7 +219,7 @@ class SharedWorkerPool:
                     client.live -= 1
                     self._maybe_drop_locked(client)
                 else:
-                    client.gens.append((gen, rec))
+                    client.gens.append((gen, traced))
                 self._cv.notify_all()
 
 

@@ -130,7 +130,7 @@ class _Run:
         # the submitting (query) thread's flight recorder rides with the run
         # so runner threads attribute driver spans to the right query even
         # when several traced queries share the process
-        self.recorder = trace.active()
+        self.traced = trace.capture()
         for d in drivers:
             heapq.heappush(self.ready, (0, next(self._seq), d))
 
@@ -177,7 +177,7 @@ class _Run:
                 self.cv.wait(timeout=0.001)
 
     def runner_loop(self) -> None:
-        with trace.bound(self.recorder):
+        with trace.bound(*self.traced):
             self._runner_loop()
 
     def _runner_loop(self) -> None:
@@ -189,9 +189,13 @@ class _Run:
             driver, consumed = nxt
             t0 = time.perf_counter_ns()
             try:
-                state = driver.process(self.quantum_ns)
-                cb = driver.blocked_on() if state == ProcessState.BLOCKED \
-                    else None
+                # one span per driver slice: the flight recorder's timeline
+                # of which pipelines ran when (and why they stopped)
+                with trace.span(trace.DRIVER, driver.trace_label) as sp:
+                    state = driver.process(self.quantum_ns)
+                    cb = driver.blocked_on() \
+                        if state == ProcessState.BLOCKED else None
+                    sp.note(state=state.name)
             except BaseException as e:  # noqa: BLE001 - propagated to caller
                 with self.cv:
                     if self.error is None:
@@ -199,11 +203,6 @@ class _Run:
                     self.cv.notify_all()
                 return
             spent = time.perf_counter_ns() - t0
-            if trace.active() is not None:
-                # one span per driver slice: the flight recorder's timeline
-                # of which pipelines ran when (and why they stopped)
-                trace.record(trace.DRIVER, driver.trace_label, t0, spent,
-                             {"state": state.name})
             with self.cv:
                 if state == ProcessState.FINISHED:
                     self.outstanding -= 1

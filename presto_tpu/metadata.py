@@ -150,9 +150,6 @@ class Session:
         "query_trace": False,
         # export directory for trace files; "" = the platform tempdir
         "query_trace_dir": "",
-        # span ring-buffer capacity: oldest spans overwrite beyond this
-        # (the export reports how many were dropped); 0 = engine default
-        "query_trace_max_events": 0,
         # always-on black-box recorder: every query keeps a small COARSE
         # span ring (driver quanta, exchange chunks, scan stage stalls,
         # pool steps, kernel builds, cluster HTTP — per-page operator spans
@@ -161,8 +158,6 @@ class Session:
         # (QueryInfo.failure_trace_path, GET /v1/query/{id}/trace). False =
         # recorder compiled out — the bench's overhead comparison point
         "query_blackbox": True,
-        # black-box ring capacity; 0 = engine default (trace.BLACKBOX_MAX_EVENTS)
-        "query_blackbox_max_events": 0,
         # --- cluster fault tolerance (cluster/retry.py) ---
         # NONE fails fast; QUERY re-plans + re-runs the whole query on
         # retryable failures (failed nodes excluded from placement); TASK

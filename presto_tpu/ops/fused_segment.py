@@ -370,10 +370,24 @@ class FusedSegmentOperator(Operator):
 
     def add_input(self, page: Page) -> None:
         # timed by hand instead of @timed: ONE clock pair feeds the stats
-        # accumulator, the per-page dispatch histogram AND the trace span
-        # (the decorator would add a second measurement of the same window
-        # and a duplicate `operator` span per page)
+        # accumulator and the per-page dispatch histogram (the decorator
+        # would add a duplicate `operator` span per page beside the
+        # `segment` span of the dispatch)
         t0 = time.perf_counter_ns()
+        try:
+            # `program` = the kernel-cache key's first field: what a reader
+            # of the profiler's trace groups the dispatched programs by
+            with trace.span(trace.SEGMENT, self.f.name, rows=page.capacity,
+                            program="fused-segment"):
+                self._add_page(page)
+        finally:
+            # per-page dispatch latency: one histogram observation per page
+            # (pages are large, so this is per-dispatch, not per-row)
+            dt = time.perf_counter_ns() - t0
+            self.context.stats.add_input_ns += dt
+            METRICS.histogram("segments.page_dispatch_s", dt / 1e9)
+
+    def _add_page(self, page: Page) -> None:
         self.context.record_input(page, page.capacity)
         in_key = kc.layout_key([b.type for b in page.blocks],
                                [b.dictionary for b in page.blocks])
@@ -384,31 +398,19 @@ class FusedSegmentOperator(Operator):
         auxes = tuple(st["aux"] for st in self._stages
                       if st["aux"] is not None)
         self._pages += 1
-        try:
-            if t is None:
-                self._pending = self._fused(page, auxes, None, out_groups=0)
-                return
+        if t is None:
+            self._pending = self._fused(page, auxes, None, out_groups=0)
+            return
+        og = t.out_groups(page.capacity)
+        result = self._fused(page, auxes, t.state(), out_groups=og)
+        if not t.absorb(result, page.capacity, og):
+            # the builder's shrunken partial table overflowed on this
+            # page and reset to full size: recompute at the new size
             og = t.out_groups(page.capacity)
-            result = self._fused(page, auxes, t.state(), out_groups=og)
-            if not t.absorb(result, page.capacity, og):
-                # the builder's shrunken partial table overflowed on this
-                # page and reset to full size: recompute at the new size
-                og = t.out_groups(page.capacity)
-                ok = t.absorb(
-                    self._fused(page, auxes, t.state(), out_groups=og),
-                    page.capacity, og)
-                assert ok, "full-size partial cannot overflow"
-        finally:
-            # per-page dispatch latency: one histogram observation per page
-            # (pages are large, so this is per-dispatch, not per-row) plus a
-            # flight-recorder span when a trace is live
-            dt = time.perf_counter_ns() - t0
-            stats = self.context.stats
-            stats.add_input_ns += dt
-            METRICS.histogram("segments.page_dispatch_s", dt / 1e9)
-            trace.record(trace.SEGMENT, self.f.name, t0, dt,
-                         {"rows": page.capacity}
-                         if trace.active() is not None else None)
+            ok = t.absorb(
+                self._fused(page, auxes, t.state(), out_groups=og),
+                page.capacity, og)
+            assert ok, "full-size partial cannot overflow"
 
     @timed("get_output_ns")
     def get_output(self) -> Optional[Page]:

@@ -206,16 +206,14 @@ def timed(stats_field: str):
 
     def deco(fn):
         def wrapper(self, *a, **kw):
+            stats = self.context.stats
             t0 = time.perf_counter_ns()
             try:
-                return fn(self, *a, **kw)
+                with trace.span(trace.OPERATOR, f"{stats.name}.{method}",
+                                min_ns=trace.MIN_OPERATOR_SPAN_NS):
+                    return fn(self, *a, **kw)
             finally:
                 dt = time.perf_counter_ns() - t0
-                stats = self.context.stats
                 setattr(stats, stats_field, getattr(stats, stats_field) + dt)
-                if trace.active() is not None and \
-                        dt >= trace.MIN_OPERATOR_SPAN_NS:
-                    trace.record(trace.OPERATOR, f"{stats.name}.{method}",
-                                 t0, dt)
         return wrapper
     return deco

@@ -250,8 +250,8 @@ class ScanPipeline:
         # operator state and the OOM killer sees it; None = unaccounted
         self._memory = memory
         # owning query's flight recorder: dedicated stage threads re-bind it
-        # (pool steps re-bind the recorder captured at submit)
-        self._recorder = trace.active()
+        # (pool steps re-bind what was captured at submit)
+        self._traced = trace.capture()
         readers = None
         if rebatch and self._target > 0:
             split = getattr(source, "split_readers", None)
@@ -295,11 +295,9 @@ class ScanPipeline:
         if not self._started:
             self._start()
         t0 = time.perf_counter_ns()
-        item = self._out.get()
-        dt = time.perf_counter_ns() - t0
-        self._add("compute_stall_s", dt / 1e9)
-        if dt >= _TRACE_STALL_NS:
-            trace.record(trace.SCAN, "compute_stall", t0, dt)
+        with trace.span(trace.SCAN, "compute_stall", min_ns=_TRACE_STALL_NS):
+            item = self._out.get()
+        self._add("compute_stall_s", (time.perf_counter_ns() - t0) / 1e9)
         if item is _EOS:
             self._out.put(_EOS)  # keep later next() calls returning None
             self._flush_metrics()
@@ -383,7 +381,7 @@ class ScanPipeline:
         """Dedicated-thread scheduler: the generator's internal bounded
         waits provide the blocking cadence, so draining it step-by-step is
         behaviorally the old thread loop."""
-        with trace.bound(self._recorder):
+        with trace.bound(*self._traced):
             for _ in gen:
                 pass
 
@@ -428,14 +426,11 @@ class ScanPipeline:
             while True:
                 t0 = time.perf_counter_ns()
                 try:
-                    item = next(it)
+                    with trace.span(trace.SCAN, "read", reader=ri, seq=seq):
+                        item = next(it)
                 except StopIteration:
                     break
-                dt = time.perf_counter_ns() - t0
-                self._add("read_busy_s", dt / 1e9)
-                trace.record(trace.SCAN, "read", t0, dt,
-                             {"reader": ri, "seq": seq}
-                             if trace.active() is not None else None)
+                self._add("read_busy_s", (time.perf_counter_ns() - t0) / 1e9)
                 nbytes = item.nbytes if isinstance(item, HostChunk) \
                     else page_nbytes(item)
                 ok = yield from self._stage_put_gen(ri, seq, item, nbytes)
@@ -453,6 +448,8 @@ class ScanPipeline:
         (deadlock freedom); returns False when the pipeline stopped."""
         key = (ri, seq)
         t0 = time.perf_counter_ns()
+        stall = trace.span(trace.SCAN, "read_stall",
+                           min_ns=_TRACE_STALL_NS).__enter__()
         while True:
             with self._cv:
                 if self._stop.is_set():
@@ -467,10 +464,8 @@ class ScanPipeline:
                 self._cv.wait(STEP_WAIT_S)
             yield WAIT
         self._account()
-        dt = time.perf_counter_ns() - t0
-        self._add("read_stall_s", dt / 1e9)
-        if dt >= _TRACE_STALL_NS:
-            trace.record(trace.SCAN, "read_stall", t0, dt)
+        stall.__exit__(None, None, None)
+        self._add("read_stall_s", (time.perf_counter_ns() - t0) / 1e9)
         return True
 
     def _stage_take_gen(self, ri: int, seq: int):
@@ -478,6 +473,8 @@ class ScanPipeline:
         pipeline stopped."""
         key = (ri, seq)
         t0 = time.perf_counter_ns()
+        stall = trace.span(trace.SCAN, "decode_stall",
+                           min_ns=_TRACE_STALL_NS).__enter__()
         while True:
             with self._cv:
                 self._needed = key
@@ -492,10 +489,8 @@ class ScanPipeline:
                 self._cv.wait(STEP_WAIT_S)
             yield WAIT
         self._account()
-        dt = time.perf_counter_ns() - t0
-        self._add("decode_stall_s", dt / 1e9)
-        if dt >= _TRACE_STALL_NS:
-            trace.record(trace.SCAN, "decode_stall", t0, dt)
+        stall.__exit__(None, None, None)
+        self._add("decode_stall_s", (time.perf_counter_ns() - t0) / 1e9)
         return item
 
     def _decode_gen(self):
@@ -514,10 +509,10 @@ class ScanPipeline:
                 seq += 1
                 if rb is not None:
                     t0 = time.perf_counter_ns()
-                    batches = rb.add(item)
-                    dt = time.perf_counter_ns() - t0
-                    self._add("decode_busy_s", dt / 1e9)
-                    trace.record(trace.SCAN, "rebatch", t0, dt)
+                    with trace.span(trace.SCAN, "rebatch"):
+                        batches = rb.add(item)
+                    self._add("decode_busy_s",
+                              (time.perf_counter_ns() - t0) / 1e9)
                     self._add("chunks", 1)
                     for page, nbytes, rows in batches:
                         ok = yield from self._emit_gen(page, nbytes, rows)
@@ -548,6 +543,8 @@ class ScanPipeline:
         on uploaded-but-unconsumed pages (the stall here means the CONSUMER
         is the bottleneck — the healthy state)."""
         t0 = time.perf_counter_ns()
+        stall = trace.span(trace.SCAN, "upload_stall",
+                           min_ns=_TRACE_STALL_NS).__enter__()
         while True:
             with self._ocv:
                 if self._stop.is_set():
@@ -559,10 +556,8 @@ class ScanPipeline:
                 self._ocv.wait(STEP_WAIT_S)
             yield WAIT
         self._account()
-        dt = time.perf_counter_ns() - t0
-        self._add("upload_stall_s", dt / 1e9)
-        if dt >= _TRACE_STALL_NS:
-            trace.record(trace.SCAN, "upload_stall", t0, dt)
+        stall.__exit__(None, None, None)
+        self._add("upload_stall_s", (time.perf_counter_ns() - t0) / 1e9)
         self._upq.put((page, nbytes, rows))
         return True
 
@@ -584,13 +579,10 @@ class ScanPipeline:
                 return
             page, nbytes, rows = item
             t0 = time.perf_counter_ns()
-            dev = jax.tree.map(
-                lambda a: jax.device_put(a, self._device), page)
-            dt = time.perf_counter_ns() - t0
-            self._add("upload_busy_s", dt / 1e9)
-            trace.record(trace.SCAN, "upload", t0, dt,
-                         {"rows": rows, "bytes": nbytes}
-                         if trace.active() is not None else None)
+            with trace.span(trace.SCAN, "upload", rows=rows, bytes=nbytes):
+                dev = jax.tree.map(
+                    lambda a: jax.device_put(a, self._device), page)
+            self._add("upload_busy_s", (time.perf_counter_ns() - t0) / 1e9)
             with self._stats_lock:
                 self._stats["pages"] += 1
                 self._stats["rows"] += rows

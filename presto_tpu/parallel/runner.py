@@ -84,6 +84,16 @@ class DistributedQueryRunner:
     def session(self):
         return self.local.session
 
+    @session.setter
+    def session(self, session: Session) -> None:
+        # the protocol layer scopes a shallow copy of the engine to one
+        # query's X-Presto-Catalog/Schema: the copy needs a local runner of
+        # its own to carry that session (the mesh and the catalogs are shared)
+        import copy
+
+        self.local = copy.copy(self.local)
+        self.local.session = session
+
     # ------------------------------------------------------------------ api
 
     def plan_sql(self, sql: str) -> SubPlan:
@@ -113,17 +123,26 @@ class DistributedQueryRunner:
         return "\n".join(parts)
 
     def execute(self, sql: str) -> QueryResult:
-        stmt = self.local.parser.parse(sql)
-        if isinstance(stmt, t.Explain) and stmt.analyze and \
-                isinstance(stmt.statement, t.Query):
-            # distributed EXPLAIN ANALYZE: execute over the mesh and render
-            # per-fragment per-operator stats rolled up across workers —
-            # before this, ANALYZE silently profiled the single-node path
-            return self._explain_analyze(stmt.statement)
-        if not isinstance(stmt, t.Query):
-            return self.local.execute(sql)  # EXPLAIN/SHOW et al stay local
-        sub = self.plan_statement(stmt)
-        return self._execute_subplan(sub)
+        """One statement under the scope every runner tier shares
+        (trace.QueryScope: recorder, forensic, wall and phase histograms)."""
+        with trace.QueryScope(self.session) as scope:
+            with trace.phase("parse"):
+                stmt = self.local.parser.parse(sql)
+            if isinstance(stmt, t.Explain) and stmt.analyze and \
+                    isinstance(stmt.statement, t.Query):
+                # distributed EXPLAIN ANALYZE: execute over the mesh and
+                # render per-fragment per-operator stats rolled up across
+                # workers — before this, ANALYZE silently profiled the
+                # single-node path
+                result = self._explain_analyze(stmt.statement)
+            elif not isinstance(stmt, t.Query):
+                # EXPLAIN/SHOW et al stay local, inside this scope
+                result = self.local._execute_statement(sql)
+            else:
+                with trace.phase("plan"):
+                    sub = self.plan_statement(stmt)
+                result = self._execute_subplan(sub)
+        return scope.finish(result)
 
     # ------------------------------------------------------------ execution
 
@@ -132,37 +151,11 @@ class DistributedQueryRunner:
                          ) -> QueryResult:
         """`frag_drivers`, when given, collects each fragment's per-worker
         driver lists for EXPLAIN ANALYZE's stats roll-up."""
-        import time as _time
-
         book = ExchangeStatsBook()
-
-        def run() -> QueryResult:
-            if bool(self.session.get("streaming_exchange", True)):
-                return self._execute_streaming(sub, book, frag_drivers)
-            return self._execute_barrier(sub, book, frag_drivers)
-
-        t0 = _time.perf_counter()
-        rec = trace.maybe_recorder(self.session)
-        installed = rec is not None and trace.install(rec)
-        try:
-            # span only on THIS query's recorder: an untraced query running
-            # concurrently with a traced one must not write a full-wall
-            # lifecycle span into the other query's timeline
-            if installed:
-                with rec.span(trace.LIFECYCLE, "query"):
-                    result = run()
-            else:
-                result = run()
-        except BaseException as e:
-            # black-box forensics: the failing query's coarse ring rides
-            # the exception (QueryInfo.failure_trace_path upstream)
-            if installed:
-                trace.attach_failure(e, rec, self.session)
-            raise
-        finally:
-            if installed:
-                trace.uninstall(rec)
-        METRICS.histogram("query.wall_s", _time.perf_counter() - t0)
+        if bool(self.session.get("streaming_exchange", True)):
+            result = self._execute_streaming(sub, book, frag_drivers)
+        else:
+            result = self._execute_barrier(sub, book, frag_drivers)
         snap = book.snapshot()
         if snap:
             snap["mode"] = "streaming" \
@@ -172,8 +165,6 @@ class DistributedQueryRunner:
             METRICS.count_many(
                 {k: v for k, v in snap.items()
                  if isinstance(v, (int, float))}, prefix="exchange.")
-        if installed and not rec.coarse:
-            result.trace_path = trace.export(rec, self.session)
         return result
 
     def _fragment_root(self, sub: SubPlan, frag: Fragment) -> OutputNode:
@@ -218,69 +209,74 @@ class DistributedQueryRunner:
         root_ep = None
         planned = []  # (fragment, local plan) — skew wiring scans consumers
         try:
-            for frag in sub.fragments:
-                is_root = frag is sub.root_fragment
-                root = self._fragment_root(sub, frag)
-                workers = [0] if frag.partitioning == SINGLE_PART \
-                    else list(range(W))
-                lp = LocalExecutionPlanner(self.metadata, self.session,
-                                           n_workers=W,
-                                           remote_dicts=frag_dicts,
-                                           devices=self.mesh.devices,
-                                           pool_key=pool_key)
-                lp.attach_memory(mem_ctx, over_target)
-                if is_root:
-                    ep = lp.plan(root)
-                else:
-                    key_idx, orderings = self._routing_spec(frag)
-                    holder: dict = {}
+            with trace.phase("local_plan"):
+                for frag in sub.fragments:
+                    is_root = frag is sub.root_fragment
+                    root = self._fragment_root(sub, frag)
+                    workers = [0] if frag.partitioning == SINGLE_PART \
+                        else list(range(W))
+                    lp = LocalExecutionPlanner(self.metadata, self.session,
+                                               n_workers=W,
+                                               remote_dicts=frag_dicts,
+                                               devices=self.mesh.devices,
+                                               pool_key=pool_key)
+                    lp.attach_memory(mem_ctx, over_target)
+                    if is_root:
+                        ep = lp.plan(root)
+                    else:
+                        key_idx, orderings = self._routing_spec(frag)
+                        holder: dict = {}
 
-                    def sink_factory(types, dicts, _frag=frag, _key=key_idx,
-                                     _ord=orderings, _holder=holder, _lp=lp):
-                        ex = StreamingExchange(
-                            self.mesh, _frag.id, _frag.output_kind, _key,
-                            types, dicts, orderings=_ord,
-                            chunk_rows=chunk_rows, inflight_bytes=inflight,
-                            page_capacity=page_cap, book=book,
-                            pool_key=pool_key,
-                            # in-flight exchange bytes reserve as the
-                            # query's user memory (unified accounting)
-                            memory=mem_ctx.user.new_local_memory_context(
-                                f"exchange_inflight_f{_frag.id}"))
-                        fac = ExchangeSinkOperatorFactory(
-                            next(_lp._ids), ex, types)
-                        _holder["exchange"] = ex
-                        _holder["factory"] = fac
-                        return fac
+                        def sink_factory(types, dicts, _frag=frag,
+                                         _key=key_idx, _ord=orderings,
+                                         _holder=holder, _lp=lp):
+                            ex = StreamingExchange(
+                                self.mesh, _frag.id, _frag.output_kind, _key,
+                                types, dicts, orderings=_ord,
+                                chunk_rows=chunk_rows, inflight_bytes=inflight,
+                                page_capacity=page_cap, book=book,
+                                pool_key=pool_key,
+                                # in-flight exchange bytes reserve as the
+                                # query's user memory (unified accounting)
+                                memory=mem_ctx.user.new_local_memory_context(
+                                    f"exchange_inflight_f{_frag.id}"))
+                            fac = ExchangeSinkOperatorFactory(
+                                next(_lp._ids), ex, types)
+                            _holder["exchange"] = ex
+                            _holder["factory"] = fac
+                            return fac
 
-                    ep = lp.plan(root, sink_factory=sink_factory)
-                    exchanges[frag.id] = holder["exchange"]
-                    sink_facs[frag.id] = holder["factory"]
-                    frag_dicts[frag.id] = ep.output_dicts
-                # consumer endpoints: attach the producers' streams (created
-                # in fragment order, so every referenced exchange exists)
-                for fid, slot in ep.remote_slots.items():
-                    slot.stream = exchanges[fid]
-                planned.append(ep)
-                for w in workers:
-                    worker_drivers = ep.create_drivers(w)
-                    drivers.extend(worker_drivers)
-                    if frag_drivers is not None:
-                        # per-worker lists: driver ordering is deterministic
-                        # per plan, so EXPLAIN ANALYZE's roll-up can line
-                        # operator instances up across workers
-                        frag_drivers.setdefault(frag.id, []).append(
-                            worker_drivers)
-                if is_root:
-                    root_ep = ep
-            # skew-aware routing: pair each INNER join's build-side and
-            # probe-side REPARTITION exchanges BEFORE any pump runs (the
-            # roles change the compiled routing program for the stream)
-            if bool(self.session.get("skew_aware_exchange", True)):
-                _wire_skew(planned, exchanges)
-            # all drivers exist: producer counts are exact — start the pumps
-            for fid, ex in exchanges.items():
-                ex.start(sink_facs[fid].created)
+                        ep = lp.plan(root, sink_factory=sink_factory)
+                        exchanges[frag.id] = holder["exchange"]
+                        sink_facs[frag.id] = holder["factory"]
+                        frag_dicts[frag.id] = ep.output_dicts
+                    # consumer endpoints: attach the producers' streams
+                    # (created in fragment order, so every referenced
+                    # exchange exists)
+                    for fid, slot in ep.remote_slots.items():
+                        slot.stream = exchanges[fid]
+                    planned.append(ep)
+                    for w in workers:
+                        worker_drivers = ep.create_drivers(w)
+                        drivers.extend(worker_drivers)
+                        if frag_drivers is not None:
+                            # per-worker lists: driver ordering is
+                            # deterministic per plan, so EXPLAIN ANALYZE's
+                            # roll-up can line operator instances up across
+                            # workers
+                            frag_drivers.setdefault(frag.id, []).append(
+                                worker_drivers)
+                    if is_root:
+                        root_ep = ep
+                # skew-aware routing: pair each INNER join's build-side and
+                # probe-side REPARTITION exchanges BEFORE any pump runs (the
+                # roles change the compiled routing program for the stream)
+                if bool(self.session.get("skew_aware_exchange", True)):
+                    _wire_skew(planned, exchanges)
+                # all drivers exist: producer counts are exact — start the
+                # pumps
+                for fid, ex in exchanges.items():
+                    ex.start(sink_facs[fid].created)
             # live progress across ALL fragments' drivers (exec/progress.py;
             # no-op outside a protocol-layer query scope)
             from ..exec import progress as _progress
@@ -292,13 +288,15 @@ class DistributedQueryRunner:
                 "memory_reserved_bytes": mem_ctx.total_bytes(),
                 "pool_steps": _pool_steps(pool_key)})
             try:
-                TaskExecutor(
-                    int(self.session.get("task_concurrency"))
-                ).execute(drivers)
+                with trace.phase("execute"):
+                    TaskExecutor(
+                        int(self.session.get("task_concurrency"))
+                    ).execute(drivers)
             finally:
                 unregister()
-            return QueryResult(root_ep.sink.rows(), sub.column_names,
-                               root_ep.output_types)
+            with trace.span(trace.LIFECYCLE, "result"):
+                rows = root_ep.sink.rows()
+            return QueryResult(rows, sub.column_names, root_ep.output_types)
         finally:
             err = sys.exc_info()[1]
             for ex in exchanges.values():
@@ -355,17 +353,19 @@ class DistributedQueryRunner:
                                        devices=self.mesh.devices,
                                        pool_key=pool_key)
             lp.attach_memory(*query_memory)
-            ep = lp.plan(root)
-            for fid, slot in ep.remote_slots.items():
-                for w in range(W):
-                    slot.set_pages(w, routed[fid][w])
-            # all workers' drivers share one executor: worker tasks and their
-            # build/probe pipelines time-slice across runner threads
-            per_worker_drivers = [ep.create_drivers(w) for w in workers]
+            with trace.phase("local_plan"):
+                ep = lp.plan(root)
+                for fid, slot in ep.remote_slots.items():
+                    for w in range(W):
+                        slot.set_pages(w, routed[fid][w])
+                # all workers' drivers share one executor: worker tasks and
+                # their build/probe pipelines time-slice across runner threads
+                per_worker_drivers = [ep.create_drivers(w) for w in workers]
             if frag_drivers is not None:
                 frag_drivers[frag.id] = per_worker_drivers
             drivers = [d for wd in per_worker_drivers for d in wd]
-            executor.execute(drivers)
+            with trace.phase("execute"):
+                executor.execute(drivers)
             if is_root:
                 return QueryResult(ep.sink.rows(), sub.column_names,
                                    ep.output_types)
@@ -391,7 +391,8 @@ class DistributedQueryRunner:
 
         from ..exec.explain import driver_stats, rollup, table
 
-        sub = self.plan_statement(stmt)
+        with trace.phase("plan"):
+            sub = self.plan_statement(stmt)
         frag_drivers: Dict[int, List[list]] = {}
         t0 = _time.perf_counter()
         result = self._execute_subplan(sub, frag_drivers)

@@ -594,8 +594,8 @@ class StreamingExchange:
         self._memory = memory
         self._mem_lock = threading.Lock()
         # owning query's flight recorder (re-bound by the pump thread; pool
-        # steps re-bind the recorder captured at submit)
-        self._recorder = trace.active()
+        # steps re-bind what was captured at submit)
+        self._traced = trace.capture()
         self._finished_ok = False
         # skew-aware routing (wired by parallel/runner._wire_skew onto the
         # REPARTITION pair feeding an INNER join): "detect" samples + splits
@@ -718,7 +718,7 @@ class StreamingExchange:
     def _pump_loop(self) -> None:
         """Dedicated-thread scheduler (shared_pools=False): drain the pump
         generator; its internal bounded waits provide the blocking cadence."""
-        with trace.bound(self._recorder):
+        with trace.bound(*self._traced):
             for _ in self._pump_steps():
                 pass
 
@@ -805,14 +805,14 @@ class StreamingExchange:
                         self._error is None and not self._closed:
                     # ONE bounded wait per step, not wait-until-work: a
                     # starved pump frees its pool worker every STEP_WAIT_S
-                    self._cv.wait(timeout=STEP_WAIT_S)
+                    # in the ring from 1 ms up: real starvation
+                    with trace.span(trace.EXCHANGE,
+                                    f"pump_stall f{self.fragment_id}",
+                                    min_ns=1_000_000):
+                        self._cv.wait(timeout=STEP_WAIT_S)
                     waited = True
-                    stalled = time.perf_counter_ns() - t0
-                    self.stats["stall_s"] += stalled / 1e9
-                    if stalled >= 1_000_000:  # >= 1ms: real starvation
-                        trace.record(trace.EXCHANGE,
-                                     f"pump_stall f{self.fragment_id}",
-                                     t0, stalled)
+                    self.stats["stall_s"] += \
+                        (time.perf_counter_ns() - t0) / 1e9
                 drained = self._inbox
                 self._inbox = [[] for _ in range(W)]
                 producers_done = (self._open_producers is not None and
@@ -1006,6 +1006,9 @@ class StreamingExchange:
         W, C = self.W, self.chunk_rows
         ncols = len(self.types)
         t0 = time.perf_counter_ns()
+        span = trace.span(trace.EXCHANGE,
+                          f"chunk_dispatch f{self.fragment_id}", kind=self.kind)
+        span.__enter__()
         range_keys = None
         if self.kind == MERGE:
             range_keys = self._merge_range_keys(state)
@@ -1069,11 +1072,8 @@ class StreamingExchange:
         if producing:
             self.stats["overlap_chunks"] += 1
             self.stats["overlap_s"] += dt
-        trace.record(trace.EXCHANGE, f"chunk_dispatch f{self.fragment_id}",
-                     t0, dt_ns,
-                     {"kind": self.kind, "chunk": chunk_no,
-                      "overlap": producing}
-                     if trace.active() is not None else None)
+        span.note(chunk=chunk_no, overlap=producing)
+        span.__exit__(None, None, None)
         if self.book is not None:
             self.book.bump("chunks")
             if producing:

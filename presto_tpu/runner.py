@@ -22,7 +22,6 @@ from .sql.planner.plan import OutputNode, plan_to_text
 from .types import BIGINT
 from .sql.planner.planner import LogicalPlanner
 from .utils import trace
-from .utils.metrics import METRICS
 
 
 def _virtual_remap(source_dict, target_dict):
@@ -235,37 +234,19 @@ class LocalQueryRunner:
 
     def execute(self, sql: str, user: Optional[str] = None) -> QueryResult:
         """Public entry: runs the statement under the per-query flight
-        recorder — a FULL one when `query_trace` is on, else the always-on
-        coarse black-box ring — and histograms the wall either way
-        (`query.wall_s` p50/p95/p99 at /v1/metrics). A failing statement
+        recorder — a FULL one when `query_trace` is on or a profile is being
+        taken, else the always-on coarse black-box ring — and histograms
+        the wall and the four phases either way (`query.wall_s`,
+        `query.parse_s` ... p50/p95/p99 at /v1/metrics). A failing statement
         dumps the ring as a forensic trace pinned to the exception."""
-        import time as _time
-
-        t0 = _time.perf_counter()
-        rec = trace.maybe_recorder(self.session)
-        installed = rec is not None and trace.install(rec)
-        try:
-            if installed:
-                with rec.span(trace.LIFECYCLE, "query"):
-                    result = self._execute_statement(sql, user)
-            else:
-                result = self._execute_statement(sql, user)
-        except BaseException as e:
-            if installed:
-                trace.attach_failure(e, rec, self.session)
-            raise
-        finally:
-            if installed:
-                trace.uninstall(rec)
-        METRICS.histogram("query.wall_s", _time.perf_counter() - t0)
-        if installed and not rec.coarse:
-            result.trace_path = trace.export(rec, self.session)
-        return result
+        with trace.QueryScope(self.session) as scope:
+            result = self._execute_statement(sql, user)
+        return scope.finish(result)
 
     def _execute_statement(self, sql: str,
                            user: Optional[str] = None) -> QueryResult:
         self.last_grouped = None  # set again on the grouped query path
-        with trace.span(trace.LIFECYCLE, "parse"):
+        with trace.phase("parse"):
             stmt = self.parser.parse(sql)
         self._check_access(stmt, user)
         if isinstance(stmt, t.Explain):
@@ -309,7 +290,7 @@ class LocalQueryRunner:
         if not isinstance(stmt, t.Query):
             raise ValueError(f"unsupported statement {type(stmt).__name__}")
 
-        with trace.span(trace.LIFECYCLE, "plan"):
+        with trace.phase("plan"):
             plan = self.plan_statement(stmt)
 
         # grouped (lifespan) execution: co-bucketed scans run one bucket at
@@ -352,7 +333,11 @@ class LocalQueryRunner:
             stats["scan_pipeline"] = scan
         if seg is not None:
             stats["segments"] = seg
-        return QueryResult(exec_plan.sink.rows(), exec_plan.output_names,
+        # the answer's pages fetched from the device and turned into rows:
+        # one blocking copy a block (0.4-0.7 ms each on a v5e, PERF.md)
+        with trace.span(trace.LIFECYCLE, "result"):
+            rows = exec_plan.sink.rows()
+        return QueryResult(rows, exec_plan.output_names,
                            exec_plan.output_types, stats=stats or None)
 
     def _execute_write(self, stmt) -> QueryResult:
@@ -563,7 +548,7 @@ class LocalQueryRunner:
         mem, over_target, release = self._query_memory()
         unregister = lambda: None  # noqa: E731 - rebound below
         try:
-            with trace.span(trace.LIFECYCLE, "local_plan"):
+            with trace.phase("local_plan"):
                 local = LocalExecutionPlanner(self.metadata, self.session,
                                               bucket_filter=bucket_filter)
                 local.attach_memory(mem, over_target)
@@ -585,7 +570,7 @@ class LocalQueryRunner:
             # task executor: build/probe pipelines overlap on runner threads
             # (blocked probes park until their lookup slot resolves)
             try:
-                with trace.span(trace.LIFECYCLE, "execute"):
+                with trace.phase("execute"):
                     TaskExecutor(
                         int(self.session.get("task_concurrency"))
                     ).execute(drivers)

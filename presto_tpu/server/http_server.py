@@ -39,6 +39,7 @@ import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from typing import Optional
 
+from ..utils import trace
 from .protocol import QueryManager
 
 _START_MONO = time.monotonic()
@@ -138,13 +139,17 @@ class _Handler(BaseHTTPRequestHandler):
         if not sql:
             return self._send_json(
                 {"error": {"message": "empty statement"}}, status=400)
-        info = self.manager.submit(
-            sql, user=user,
-            source=self.headers.get("X-Presto-Source", ""),
-            catalog=self.headers.get("X-Presto-Catalog", ""),
-            schema=self.headers.get("X-Presto-Schema", ""),
-            trace_token=self.headers.get("X-Presto-Trace-Token", ""))
-        self._send_json(self.manager.results_payload(info, 0, self._base_uri()))
+        with trace.request("POST /v1/statement") as span:
+            info = self.manager.submit(
+                sql, user=user,
+                source=self.headers.get("X-Presto-Source", ""),
+                catalog=self.headers.get("X-Presto-Catalog", ""),
+                schema=self.headers.get("X-Presto-Schema", ""),
+                trace_token=self.headers.get("X-Presto-Trace-Token", ""))
+            span.note(qid=info.query_id)
+            self._send_json(
+                self.manager.results_payload(info, 0, self._base_uri()))
+        self.manager.served(info)
 
     def do_GET(self) -> None:  # noqa: N802
         if self.path.rstrip("/") == "/v1/info":
@@ -174,8 +179,11 @@ class _Handler(BaseHTTPRequestHandler):
             info = self.manager.get(m.group(1))
             if info is None:
                 return self._not_found()
-            return self._send_json(self.manager.results_payload(
-                info, int(m.group(2)), self._base_uri()))
+            with trace.request("GET /v1/statement/{id}/{token}",
+                               info.query_id):
+                self._send_json(self.manager.results_payload(
+                    info, int(m.group(2)), self._base_uri()))
+            return self.manager.served(info)
         if self.path.rstrip("/") == "/v1/cluster":
             # ClusterStatsResource.java analogue (feeds the web UI)
             queries = self.manager.list_queries()

@@ -22,6 +22,9 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
+from ..utils import trace
+from ..utils.metrics import METRICS
+
 # QueryState.java vocabulary (narrowed to the states this engine reaches)
 QUEUED = "QUEUED"
 RUNNING = "RUNNING"
@@ -60,6 +63,12 @@ class QueryInfo:
     # survived a failed attempt; /v1/query/{id}/trace serves it when no
     # opted-in trace exists — failed queries are debuggable after the fact
     failure_trace_path: Optional[str] = None
+    # the protocol layer's open spans by name (utils/trace.Stage): `query`
+    # (submit -> the GET that serves the final state), `queued` (submit ->
+    # RUNNING), `result_wait` (the final state -> the first fetch of it).
+    # Whoever ends one takes it out under the manager's lock first.
+    stages: Dict = dataclasses.field(default_factory=dict, repr=False)
+    profiled: bool = False   # a jax.profiler trace was live at submit
 
     def done(self) -> bool:
         return self.state in _DONE
@@ -115,7 +124,11 @@ class QueryManager:
             qid = f"q{next(self._ids)}_{int(time.time())}"
             info = QueryInfo(qid, sql, user=user, source=source,
                              catalog=catalog, schema=schema,
-                             trace_token=trace_token)
+                             trace_token=trace_token,
+                             profiled=trace.profile_live())
+            info.stages["query"] = trace.Stage("query", qid, info.profiled)
+            info.stages["queued"] = trace.Stage("protocol.queued", qid,
+                                                info.profiled)
             self._queries[qid] = info
             self._expire_locked()
         if self.monitor is not None:
@@ -125,7 +138,6 @@ class QueryManager:
                 QueryCreatedEvent(qid, sql, user=user, source=source,
                                   trace_token=trace_token))
         from ..utils import events
-        from ..utils.metrics import METRICS
         METRICS.count("query_manager.submitted")
         events.emit("query.submitted", query_id=qid, user=user, source=source)
         # daemon (a wedged kernel must not block interpreter exit) but
@@ -137,8 +149,8 @@ class QueryManager:
                 info.state = FAILED
                 info.error = {"message": "server is shutting down",
                               "errorType": "ServerShuttingDown"}
-                info.end_time = time.time()
-                info.end_mono = time.monotonic()
+                info.stages.pop("queued").end()
+                self._finished(info)
                 return info
             self._run_threads[qid] = t
             # start INSIDE the lock: a concurrent close() must never snapshot
@@ -167,14 +179,38 @@ class QueryManager:
                 # engine slices are not interruptible mid-kernel; the query is
                 # marked canceled and its results are dropped on completion
                 info.state = CANCELED
-                info.end_time = time.time()
-                info.end_mono = time.monotonic()
+                self._finished(info)
                 canceled = True
         if canceled:
             from ..utils import events
             events.emit("query.canceled", severity=events.WARN,
                         query_id=query_id)
         return True
+
+    def _finished(self, info: QueryInfo) -> None:
+        """Under the lock, with the final state set: stamp the end, and from
+        here the answer lies ready until somebody asks (`result_wait`)."""
+        info.end_time = time.time()
+        info.end_mono = time.monotonic()
+        if "result_wait" not in info.stages:   # a canceled query that fails
+            info.stages["result_wait"] = trace.Stage(
+                "protocol.result_wait", info.query_id, info.profiled)
+
+    def _end_stage(self, info: QueryInfo, name: str) -> None:
+        """End the named open span, once: its `query.<name>_s` observation
+        and its event of the profiler's trace come from one clock read."""
+        with self._lock:
+            stage = info.stages.pop(name, None)
+        if stage is not None:
+            seconds = stage.end()
+            if name != "query":   # the root is a span, not a phase
+                METRICS.histogram(f"query.{name}_s", seconds)
+
+    def served(self, info: QueryInfo) -> None:
+        """The HTTP handler, after it has written a response for `info`:
+        the one that carried the final state ends the root span."""
+        if info.done() and "result_wait" not in info.stages:
+            self._end_stage(info, "query")
 
     def list_queries(self) -> List[QueryInfo]:
         return list(self._queries.values())
@@ -229,6 +265,7 @@ class QueryManager:
                 if info.state != QUEUED:  # canceled while queued
                     return
                 info.state = RUNNING
+            self._end_stage(info, "queued")
             if self.transactions is not None:
                 tx = self.transactions.begin(info.query_id)
                 # conservative join: every registered catalog (hooks are
@@ -247,6 +284,8 @@ class QueryManager:
                     result = runner.execute(info.sql, user=info.user)
                 else:
                     result = runner.execute(info.sql)
+            serialize = trace.Stage("protocol.serialize", info.query_id,
+                                    info.profiled)
             rows = [self._to_json_row(r) for r in result.rows]
             if tx is not None:
                 self.transactions.commit(tx)
@@ -262,10 +301,10 @@ class QueryManager:
                 info.columns = [{"name": n, "type": self._type_name(result, i)}
                                 for i, n in enumerate(result.column_names)]
                 info.state = FINISHED
-                info.end_time = time.time()
-                info.end_mono = time.monotonic()
+                serialize_s = serialize.end()
+                self._finished(info)
             from ..utils import events
-            from ..utils.metrics import METRICS
+            METRICS.histogram("query.serialize_s", serialize_s)
             METRICS.count("query_manager.completed")
             METRICS.count("query_manager.output_rows", len(rows))
             events.emit("query.finished", query_id=info.query_id,
@@ -283,10 +322,8 @@ class QueryManager:
                 info.failure_trace_path = getattr(e, "failure_trace_path",
                                                   None)
                 info.state = FAILED
-                info.end_time = time.time()
-                info.end_mono = time.monotonic()
+                self._finished(info)
             from ..utils import events
-            from ..utils.metrics import METRICS
             METRICS.count("query_manager.failed")
             events.emit("query.failed", severity=events.ERROR,
                         query_id=info.query_id, error=type(e).__name__,
@@ -295,6 +332,7 @@ class QueryManager:
         finally:
             with self._lock:
                 self._run_threads.pop(info.query_id, None)
+            self._end_stage(info, "queued")   # never ran: canceled, refused
             if tx is not None:
                 self.transactions.abort(tx)
             if ticket is not None:
@@ -336,6 +374,9 @@ class QueryManager:
                         base_uri: str) -> Dict:
         """QueryResults wire shape for page `token` (nextUri paging:
         StatementClientV1.java:86 advances until nextUri is absent)."""
+        if info.done():
+            # the first fetch of the final state: the answer stops waiting
+            self._end_stage(info, "result_wait")
         payload: Dict = {
             "id": info.query_id,
             "infoUri": f"{base_uri}/v1/query/{info.query_id}",

@@ -93,13 +93,11 @@ def get_or_build(key: tuple, make: Callable[[], object]):
     METRICS.count("kernel_cache.misses")
     try:
         from . import trace
-        if trace.active() is not None:
-            # flight-recorder compile marker: jit closures build lazily, so
-            # the XLA compile itself lands inside whatever span wraps the
-            # first dispatch — this span marks WHICH kernel was (re)built
-            with trace.span(trace.KERNEL, f"build {key[0] if key else '?'}"):
-                fn = make()
-        else:
+        # flight-recorder compile marker: jit closures build lazily, so
+        # the XLA compile itself lands inside whatever span wraps the
+        # first dispatch — this span marks WHICH kernel was (re)built
+        program = str(key[0]) if key else "?"
+        with trace.span(trace.KERNEL, f"build {program}", program=program):
             fn = make()
         with _LOCK:
             cur = _CACHE.get(key)

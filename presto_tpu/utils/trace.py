@@ -34,9 +34,19 @@ records the timeline itself:
   the exception's ``failure_trace_path`` attribute, and
   ``GET /v1/query/{id}/trace`` — which now answers for FAILED queries).
   A query that succeeds pays only the ring appends and drops the recorder.
+- **One trace, not three**: while a ``jax.profiler`` trace is live in the
+  process, every span site ALSO opens a ``jax.profiler.TraceAnnotation``
+  named ``presto.<category>.<name>`` that carries the client-visible query
+  id (``qid``), so the engine's spans lie in the profiler's own
+  ``.xplane.pb`` host plane, on the clock of the device's ``XLA Ops``.
+  :func:`maybe_recorder` finds that out once a query (and then hands out the
+  full recorder, so operator and segment spans exist); with no profile live
+  a span site builds no annotation. A span opened on a thread the work was
+  handed to (:func:`capture` / :func:`bound`) names, as ``parent``, the span
+  that was open where the work was handed over.
 
 Categories — one per instrumented subsystem:
-  lifecycle  parse / plan / local-plan / execute phases
+  lifecycle  parse / plan / local-plan / execute phases, result fetch
   driver     TaskExecutor quanta (one span per driver slice)
   operator   Operator add_input/get_output (via ops.operator.timed)
   segment    fused-segment page dispatches + compile markers
@@ -45,6 +55,8 @@ Categories — one per instrumented subsystem:
   kernel     kernel-cache misses (jit closure builds)
   http       cluster task create/poll and exchange pulls
   pool       shared-pool generator steps (exec/shared_pools.py)
+  protocol   queued / serialize / result_wait (server/protocol.py; profiler
+             trace and /v1/metrics histograms only: the ring is the runner's)
 """
 from __future__ import annotations
 
@@ -55,6 +67,10 @@ import threading
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
+from .metrics import METRICS
+
 LIFECYCLE = "lifecycle"
 DRIVER = "driver"
 OPERATOR = "operator"
@@ -64,6 +80,10 @@ EXCHANGE = "exchange"
 KERNEL = "kernel"
 HTTP = "http"
 POOL = "pool"
+
+# the runner's phases of a query, each a `lifecycle` span and a
+# `query.<phase>_s` histogram at /v1/metrics (one observation a query)
+PHASES = ("parse", "plan", "local_plan", "execute")
 
 DEFAULT_MAX_EVENTS = 1 << 16
 
@@ -88,8 +108,11 @@ class TraceRecorder:
     """Ring buffer of (category, name, t0_ns, dur_ns, tid, tname, args)."""
 
     def __init__(self, query_id: str = "", max_events: int = 0,
-                 coarse: bool = False):
+                 coarse: bool = False, profiled: bool = False):
         self.query_id = query_id or f"trace-{next(_TRACE_SEQ)}"
+        # profiled = a jax.profiler trace was live when the query began:
+        # its spans also go into the profiler's host plane
+        self.profiled = profiled
         self.max_events = max(int(max_events or DEFAULT_MAX_EVENTS), 16)
         # coarse = the always-on black-box mode: per-page operator/segment
         # spans are dropped before the tuple is even built, so the hot paths
@@ -170,26 +193,82 @@ class TraceRecorder:
 
 
 class _Span:
-    __slots__ = ("rec", "cat", "name", "args", "t0")
+    """One span: opened BEFORE the work (the profiler's events take no
+    explicit stamps), closed after it. `min_ns` is a noise floor: a shorter
+    span is not worth a slot of the ring."""
+
+    __slots__ = ("rec", "cat", "name", "args", "min_ns", "t0", "dur",
+                 "_ann", "_outer")
 
     def __init__(self, rec: Optional[TraceRecorder], cat: str, name: str,
-                 args: Optional[dict]):
+                 args: Optional[dict], min_ns: int = 0):
         self.rec = rec
         self.cat = cat
         self.name = name
         self.args = args
+        self.min_ns = min_ns
+        self._ann = None
 
     def __enter__(self):
+        rec = self.rec
+        if rec is not None and rec.profiled:
+            self._annotate(rec.query_id)
         self.t0 = time.perf_counter_ns()
         return self
 
-    def __exit__(self, *exc):
+    def _annotate(self, qid: str) -> None:
+        outer = getattr(_TLS, "open", None)
+        self._ann = _annotation(
+            f"{self.cat}.{self.name}", qid,
+            None if outer else getattr(_TLS, "parent", None),
+            self.args.get("program") if self.args else None)
+        if not self.min_ns:
+            # a span with a noise floor may end on another thread than it
+            # began on (the scan stalls wait across generator yields) and
+            # may not be kept: it is never the span work is handed over in
+            self._outer = outer
+            _TLS.open = self.name
+
+    def note(self, **args) -> None:
+        """What is known only after the work (a driver's end state)."""
         if self.rec is not None:
-            self.rec.record(self.cat, self.name, self.t0,
-                            time.perf_counter_ns() - self.t0, self.args)
+            self.args = dict(self.args or (), **args)
+
+    def __exit__(self, *exc):
+        self.dur = time.perf_counter_ns() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+            if not self.min_ns:
+                _TLS.open = self._outer
+        if self.rec is not None and self.dur >= self.min_ns:
+            self.rec.record(self.cat, self.name, self.t0, self.dur,
+                            self.args)
         return False
 
 
+def profile_live() -> bool:
+    """Is a jax.profiler trace being taken in this process? Asked once a
+    query (and once a request by the HTTP handler): ~20 ns."""
+    return TraceAnnotation.is_enabled()
+
+
+def _annotation(name: str, qid: str, parent: Optional[str] = None,
+                program: Optional[str] = None):
+    """An ENTERED profiler event `presto.<name>`. The profiler's encoding
+    `name#key=value,...#` gives `#`, `,` and `=` a meaning: they are
+    written as `_`, `;` and `:` inside a name or a value."""
+    meta = {"qid": qid} if qid else {}
+    if parent:
+        meta["parent"] = parent.translate(_META_SAFE)
+    if program:
+        meta["program"] = program.translate(_META_SAFE)
+    ann = TraceAnnotation("presto." + name.replace("#", "_"), **meta)
+    ann.__enter__()
+    return ann
+
+
+_META_SAFE = str.maketrans("#,=", "_;:")
 _NULL_SPAN = _Span(None, "", "", None)
 
 
@@ -242,25 +321,38 @@ class _Bound:
     restoring whatever was bound before). Worker threads stepping another
     query's work wrap each step so spans land on the owning query."""
 
-    __slots__ = ("rec", "prev")
+    __slots__ = ("rec", "parent", "prev")
 
-    def __init__(self, rec: Optional[TraceRecorder]):
+    def __init__(self, rec: Optional[TraceRecorder], parent: Optional[str]):
         self.rec = rec
+        self.parent = parent
 
     def __enter__(self):
-        self.prev = getattr(_TLS, "recorder", None)
-        _TLS.recorder = self.rec
+        tls = _TLS
+        self.prev = (getattr(tls, "recorder", None),
+                     getattr(tls, "parent", None), getattr(tls, "open", None))
+        tls.recorder, tls.parent, tls.open = self.rec, self.parent, None
         return self.rec
 
     def __exit__(self, *exc):
-        _TLS.recorder = self.prev
+        _TLS.recorder, _TLS.parent, _TLS.open = self.prev
         return False
 
 
-def bound(recorder: Optional[TraceRecorder]) -> _Bound:
-    """Bind `recorder` (captured via :func:`active` on the submitting
-    thread) around work executed on a different thread."""
-    return _Bound(recorder)
+def capture() -> tuple:
+    """(recorder, parent) for :func:`bound`, taken on the thread that hands
+    work to another: the recorder in force here and, under a live profile,
+    the name of the span open here (else of the one this thread itself
+    works for), which the other thread's spans will name as `parent`."""
+    return active(), (getattr(_TLS, "open", None)
+                      or getattr(_TLS, "parent", None))
+
+
+def bound(recorder: Optional[TraceRecorder],
+          parent: Optional[str] = None) -> _Bound:
+    """Bind what :func:`capture` took on the submitting thread around work
+    executed on a different thread: `with trace.bound(*captured)`."""
+    return _Bound(recorder, parent)
 
 
 def record(cat: str, name: str, t0_ns: int, dur_ns: int,
@@ -278,11 +370,14 @@ def instant(cat: str, name: str, args: Optional[dict] = None) -> None:
         r.instant(cat, name, args)
 
 
-def span(cat: str, name: str, **args) -> _Span:
+def span(cat: str, name: str, min_ns: int = 0, **args) -> _Span:
+    """The one helper of every span site: `with trace.span(...)` around the
+    work. With no recorder, or a coarse one that drops `cat`, it is the
+    shared no-op span: one thread-local load and two checks."""
     r = active()
-    if r is None:
+    if r is None or cat in r._drop:
         return _NULL_SPAN
-    return _Span(r, cat, name, args or None)
+    return _Span(r, cat, name, args or None, min_ns)
 
 
 # ---------------------------------------------------------------------------
@@ -291,27 +386,146 @@ def span(cat: str, name: str, **args) -> _Span:
 
 def maybe_recorder(session, query_id: str = "") -> Optional[TraceRecorder]:
     """The query's recorder: a FULL one when the session's `query_trace`
-    knob is on, else the always-on coarse black-box ring (disable with
-    `query_blackbox=False` — what the bench's overhead rung compares
-    against). None only when both are off.
+    knob is on or a jax.profiler trace is live in the process (the engine
+    finds that out here, once a query: no knob says so), else the always-on
+    coarse black-box ring (disable with `query_blackbox=False` — what the
+    bench's overhead rung compares against). None only when all are off.
 
     The recorder's query_id defaults to the CANONICAL client-visible id the
     protocol layer bound via exec.progress.query_scope — so forensic dumps,
-    `query.forensic_dumped` events and trace filenames correlate with the
-    id the client knows, instead of a synthetic trace-N counter."""
+    `query.forensic_dumped` events, trace filenames and the profiler's
+    `qid` correlate with the id the client knows, instead of a synthetic
+    trace-N counter."""
     if not query_id:
         from ..exec import progress
         query_id = progress.current_query_id() or ""
-    if session.get("query_trace"):
-        return TraceRecorder(query_id,
-                             int(session.get("query_trace_max_events") or 0))
+    live = profile_live()
+    if live or session.get("query_trace"):
+        return TraceRecorder(query_id, profiled=live)
     if not session.get("query_blackbox", True):
         return None
-    return TraceRecorder(
-        query_id,
-        int(session.get("query_blackbox_max_events") or 0)
-        or BLACKBOX_MAX_EVENTS,
-        coarse=True)
+    return TraceRecorder(query_id, BLACKBOX_MAX_EVENTS, coarse=True)
+
+
+class _Phase:
+    __slots__ = ("span",)
+
+    def __init__(self, name: str):
+        self.span = _Span(active(), LIFECYCLE, name, None)
+
+    def __enter__(self):
+        self.span.__enter__()
+
+    def __exit__(self, *exc):
+        self.span.__exit__(*exc)
+        scope = getattr(_TLS, "scope", None)
+        if scope is not None:
+            name = self.span.name
+            scope.phases[name] = scope.phases.get(name, 0) + self.span.dur
+        return False
+
+
+def phase(name: str) -> _Phase:
+    """`with trace.phase("parse")` on the query's thread: a `lifecycle` span
+    that is ALWAYS timed, recorder or not, because the enclosing
+    :class:`QueryScope` histograms the phase from the same clock reads."""
+    return _Phase(name)
+
+
+class QueryScope:
+    """What every runner tier wraps one statement in, so that they cannot
+    drift: the query's recorder installed on the calling thread, the root
+    `lifecycle.query` span, the forensic dump pinned to a failure, and for a
+    statement that succeeds the histograms `query.wall_s` and
+    `query.<phase>_s` (p50/p95/p99 at /v1/metrics; one observation each, 0
+    for a phase the statement did not have)."""
+
+    def __init__(self, session):
+        self.session = session
+        self.phases: Dict[str, int] = {}
+        self.rec: Optional[TraceRecorder] = None
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        self._outer = getattr(_TLS, "scope", None)
+        _TLS.scope = self
+        self.rec = maybe_recorder(self.session)
+        if self.rec is not None:
+            install(self.rec)
+            # on THIS query's recorder only: an untraced query running
+            # beside a traced one must not write into the other's timeline
+            self._root = self.rec.span(LIFECYCLE, "query")
+            self._root.__enter__()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        _TLS.scope = self._outer
+        rec = self.rec
+        if rec is not None:
+            self._root.__exit__(etype, exc, tb)
+            if exc is not None:
+                attach_failure(exc, rec, self.session)
+            uninstall(rec)
+        if exc is None:
+            METRICS.histogram("query.wall_s",
+                              time.perf_counter() - self._t0)
+            for name in PHASES:
+                METRICS.histogram(f"query.{name}_s",
+                                  self.phases.get(name, 0) / 1e9)
+        return False
+
+    def finish(self, result):
+        """After the scope: the opted-in Chrome export rides the result."""
+        if self.session.get("query_trace"):
+            result.trace_path = export(self.rec, self.session)
+        return result
+
+
+class Stage:
+    """A span of the protocol layer, which may end on another thread than
+    it began on (a request is queued on an HTTP thread and starts running on
+    the query's; an answer is finished on the query's thread and fetched on
+    an HTTP thread). Always timed, for the `query.<stage>_s` histograms; an
+    event of the profiler's trace when `live`."""
+
+    __slots__ = ("t0", "_ann")
+
+    def __init__(self, name: str, qid: str, live: bool):
+        self._ann = _annotation(name, qid) if live else None
+        self.t0 = time.perf_counter_ns()
+
+    def end(self) -> float:
+        """-> seconds since the start. Once: the caller takes the stage out
+        of where it keeps it before it ends it."""
+        dur = time.perf_counter_ns() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        return dur / 1e9
+
+
+class _Request:
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str, qid: str):
+        self._ann = _annotation(f"{HTTP}.{name}", qid)
+
+    def __enter__(self):
+        return self
+
+    def note(self, **meta) -> None:
+        self._ann.set_metadata(**meta)
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(None, None, None)
+        return False
+
+
+def request(name: str, qid: str = ""):
+    """`with trace.request("POST /v1/statement") as r:` in an HTTP handler:
+    the event `presto.http.<name>` of the profiler's trace while a profile
+    is live (`r.note(qid=...)` once the id is known), else the no-op span.
+    The ring holds no such span: no query's recorder is bound there."""
+    return _Request(name, qid) if profile_live() else _NULL_SPAN
 
 
 def export(recorder: TraceRecorder, session, suffix: str = "") -> str:

@@ -348,3 +348,278 @@ def test_full_trace_still_wins_for_failed_queries(tmp_path):
     path = getattr(ei.value, "failure_trace_path", None)
     assert path
     assert _json.load(open(path))["otherData"]["coarse"] is False
+
+
+# ---------------------------------------------------------------------------
+# one trace, not three (PR 27): the engine's spans in the profiler's own
+# trace, the phase histograms beside them, and what stays as it was
+# ---------------------------------------------------------------------------
+
+PHASE_EVENTS = ("presto.protocol.queued", "presto.lifecycle.parse",
+                "presto.lifecycle.plan", "presto.lifecycle.local_plan",
+                "presto.lifecycle.execute", "presto.protocol.serialize",
+                "presto.protocol.result_wait")
+PHASE_HISTOGRAMS = ("query.queued_s", "query.parse_s", "query.plan_s",
+                    "query.local_plan_s", "query.execute_s",
+                    "query.serialize_s", "query.result_wait_s")
+
+
+@pytest.fixture()
+def tiny_server():
+    from presto_tpu.server import PrestoTpuServer
+
+    server = PrestoTpuServer(LocalQueryRunner(
+        session=Session(catalog="tpch", schema="tiny")), port=0)
+    server.start()
+    yield server
+    server.stop()
+
+
+def _ask(server, sql):
+    """One dbapi query (both catalog headers, 50 ms poll) -> (rows, wall)."""
+    import presto_tpu.client.dbapi as dbapi
+
+    with dbapi.connect(host="127.0.0.1", port=server.port, user="t",
+                       catalog="tpch", schema="tiny") as conn:
+        cur = conn.cursor()
+        t0 = time.perf_counter()
+        try:
+            cur.execute(sql)
+            rows = cur.fetchall()
+            return rows, time.perf_counter() - t0
+        finally:
+            # the handler ends the root span AFTER it has written the final
+            # response: the client can be back before that
+            deadline = time.monotonic() + 5.0
+            while any(q.stages for q in server.manager.list_queries()) \
+                    and time.monotonic() < deadline:
+                time.sleep(0.002)
+
+
+def _profiled(tmp_path, work):
+    """Run `work()` under a jax.profiler trace (python tracer off, as the
+    benchmark takes it) -> {qid: [(name, start_ns, end_ns, stats)]} of the
+    host plane's presto.* events."""
+    import glob
+
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        work()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    by_qid = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("presto."):
+                    stats = dict(e.stats)
+                    by_qid.setdefault(stats.get("qid"), []).append(
+                        (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         stats))
+    return by_qid
+
+
+def test_live_profile_holds_the_engines_spans_of_a_served_q6(
+        tmp_path, tiny_server):
+    assert not trace.profile_live()
+    events = _profiled(tmp_path, lambda: _ask(tiny_server, QUERIES[6]))
+    assert not trace.profile_live()
+    assert None not in events          # every presto.* event carries a qid
+    (qid, spans), = events.items()     # ... and it is the one query's
+    assert tiny_server.manager.get(qid) is not None   # the client-visible id
+    by_name = {}
+    for name, s, e, stats in spans:
+        by_name.setdefault(name, []).append((s, e, stats))
+    (r0, r1, _), = by_name["presto.query"]
+    for name in PHASE_EVENTS + ("presto.lifecycle.query",
+                                "presto.http.POST /v1/statement"):
+        assert len(by_name[name]) == 1, name
+    drivers = [n for n in by_name if n.startswith("presto.driver.")]
+    assert drivers
+    # a driver quantum runs on the executor's thread on behalf of `execute`
+    assert all(st.get("parent") == "execute"
+               for n in drivers for _s, _e, st in by_name[n])
+    # the full recorder, not the coarse one: operator spans exist
+    assert any(n.startswith("presto.operator.") for n in by_name)
+    # children lie inside the root in time, and the phases (sequential by
+    # construction) add up to no more than it
+    # (but for the POST's handler, which is what calls submit: it reads the
+    # request before the root begins and ends inside it)
+    (p0, p1, _), = by_name["presto.http.POST /v1/statement"]
+    assert p0 <= r0 <= p1 <= r1
+    for name, s, e, _stats in spans:
+        if name != "presto.http.POST /v1/statement":
+            assert r0 <= s and e <= r1, (name, s - r0, e - r1)
+    assert sum(by_name[n][0][1] - by_name[n][0][0]
+               for n in PHASE_EVENTS) <= r1 - r0
+    (x0, x1, _), = by_name["presto.lifecycle.execute"]
+    assert all(x0 <= s and e <= x1
+               for n in drivers for s, e, _st in by_name[n])
+
+
+def test_no_profile_emits_nothing_and_keeps_the_coarse_ring(
+        monkeypatch, tiny_server):
+    built = []
+
+    class Forbidden:
+        def __init__(self, *a, **kw):
+            built.append(a)
+            raise AssertionError("a TraceMe was built with no profile live")
+
+        is_enabled = staticmethod(lambda: False)
+
+    monkeypatch.setattr(trace, "TraceAnnotation", Forbidden)
+    rec = trace.maybe_recorder(Session(catalog="tpch", schema="tiny"))
+    assert rec.coarse and not rec.profiled
+    assert rec.max_events == trace.BLACKBOX_MAX_EVENTS
+    rows, _wall = _ask(tiny_server, QUERIES[6])
+    assert len(rows) == 1 and built == []
+    info = tiny_server.manager.list_queries()[-1]
+    assert info.state == "FINISHED" and not info.profiled
+    assert info.stages == {}           # every stage was ended, none leaked
+
+
+def test_profiler_metadata_is_escaped(monkeypatch):
+    made = []
+
+    class Fake:
+        def __init__(self, name, **meta):
+            made.append((name, meta))
+
+        def __enter__(self):
+            return self
+
+    monkeypatch.setattr(trace, "TraceAnnotation", Fake)
+    trace._annotation("driver.Scan->Agg#1", "q7", parent="a,b=c#d",
+                      program="seg,x")
+    assert made == [("presto.driver.Scan->Agg_1",
+                     {"qid": "q7", "parent": "a;b:c_d", "program": "seg;x"})]
+
+
+def test_each_phase_histogram_gains_one_observation_a_query(tiny_server):
+    before = METRICS.raw_snapshot("query.")["histograms"]
+    walls = [_ask(tiny_server, QUERIES[6])[1] for _ in range(2)]
+    after = METRICS.raw_snapshot("query.")["histograms"]
+
+    def gained(name, key):
+        return after[name][key] - before.get(name, {"n": 0, "total": 0.0})[key]
+
+    for name in PHASE_HISTOGRAMS + ("query.wall_s",):
+        assert gained(name, "n") == 2, name
+    # the phases follow one another inside what the client waited for
+    assert 0 < sum(gained(n, "total") for n in PHASE_HISTOGRAMS) <= sum(walls)
+    # the runner's four lie inside its own wall
+    assert sum(gained(f"query.{p}_s", "total") for p in trace.PHASES) \
+        <= gained("query.wall_s", "total")
+
+
+def test_failed_query_under_a_live_profile_still_dumps_its_forensic(
+        tmp_path, tiny_server):
+    import urllib.request
+
+    import presto_tpu.client.dbapi as dbapi
+
+    before = METRICS.raw_snapshot("query.")["histograms"]
+
+    def fail():
+        with pytest.raises(dbapi.Error):
+            _ask(tiny_server, "select definitely_missing from lineitem")
+
+    events = _profiled(tmp_path / "profile", fail)
+    (qid, spans), = events.items()
+    names = {n for n, _s, _e, _st in spans}
+    assert {"presto.query", "presto.protocol.queued",
+            "presto.protocol.result_wait", "presto.lifecycle.parse"} <= names
+    assert "presto.protocol.serialize" not in names   # it never got there
+    info = tiny_server.manager.get(qid)
+    assert info.state == "FAILED" and info.failure_trace_path
+    doc = json.loads(urllib.request.urlopen(urllib.request.Request(
+        f"http://127.0.0.1:{tiny_server.port}/v1/query/{qid}/trace",
+        headers={"X-Presto-User": "t"}), timeout=10).read())
+    # the full ring (a profile was live), with the failing phase on it
+    assert doc["otherData"]["coarse"] is False
+    assert "parse" in {e["name"] for e in doc["traceEvents"]}
+    after = METRICS.raw_snapshot("query.")["histograms"]
+    for name in ("query.queued_s", "query.result_wait_s"):
+        assert after[name]["n"] - before.get(name, {"n": 0})["n"] == 1
+    # like query.wall_s, the runner's phases count statements that succeed
+    assert after.get("query.plan_s", {"n": 0})["n"] == \
+        before.get("query.plan_s", {"n": 0})["n"]
+
+
+def test_window_deltas_of_two_queries_give_plan_s_equal_to_the_hand_sum(
+        tmp_path):
+    from benchmark.harness import engine_spans
+
+    runner = LocalQueryRunner(session=Session(
+        catalog="tpch", schema="tiny",
+        properties={"query_trace": True, "query_trace_dir": str(tmp_path)}))
+    window = {"before": METRICS.raw_snapshot()}
+    paths = [runner.execute(QUERIES[6]).trace_path for _ in range(2)]
+    window["after"] = METRICS.raw_snapshot()
+    by_hand = 0.0
+    for path in paths:           # the ring's spans: the same clock reads
+        by_hand += sum(e["dur"] for e in json.load(open(path))["traceEvents"]
+                       if e.get("cat") == "lifecycle"
+                       and e["name"] in ("parse", "plan", "local_plan"))
+    plan_s = engine_spans.histogram_mean_gain(
+        window, ["query.parse_s", "query.plan_s", "query.local_plan_s"])
+    assert plan_s == pytest.approx(by_hand / 1e6 / 2, rel=1e-6)
+    assert plan_s > 0
+    # a histogram that gained nothing is no reading, never a made-up 0
+    assert engine_spans.histogram_mean_gain(
+        {"before": window["after"], "after": window["after"]},
+        ["query.parse_s"]) is None
+    assert engine_spans.histogram_mean_gain(window, ["no.such_s"]) is None
+
+
+def test_ring_size_knobs_are_gone_and_nothing_reads_them():
+    import pathlib
+
+    import presto_tpu
+
+    gone = ("query_trace_max_events", "query_blackbox_max_events")
+    for knob in gone:
+        assert knob not in Session.DEFAULTS
+    package = pathlib.Path(presto_tpu.__file__).parent
+    readers = [str(p) for p in package.rglob("*.py")
+               if any(k in p.read_text() for k in gone)]
+    assert readers == []
+    # the two sizes are the constants they always were
+    full = trace.maybe_recorder(Session(
+        catalog="tpch", schema="tiny", properties={"query_trace": True}))
+    assert full.max_events == trace.DEFAULT_MAX_EVENTS
+
+
+def test_dbapi_headers_are_served_by_the_mesh_runner(eight_devices):
+    """Every dbapi/JDBC client sends X-Presto-Catalog/Schema; the protocol
+    layer scopes a copy of the engine to them, which the mesh runner had no
+    setter for ("property 'session' ... has no setter" on every query)."""
+    from presto_tpu.parallel.mesh import MeshContext
+    from presto_tpu.parallel.runner import DistributedQueryRunner
+    from presto_tpu.server import PrestoTpuServer
+
+    sql = ("select o_orderpriority, count(*) from orders "
+           "group by o_orderpriority order by o_orderpriority")
+    session = Session(catalog="tpch", schema="sf1")   # the headers override
+    mesh = DistributedQueryRunner(MeshContext(eight_devices[:2]),
+                                  session=session)
+    server = PrestoTpuServer(mesh, port=0)
+    server.start()
+    try:
+        rows, _wall = _ask(server, sql)
+    finally:
+        server.stop()
+    want = LocalQueryRunner(
+        session=Session(catalog="tpch", schema="tiny")).execute(sql).rows
+    assert_rows_equal([tuple(r) for r in rows], [tuple(r) for r in want],
+                      ordered=True)
+    assert mesh.session.schema == "sf1"   # the shared engine is as it was
