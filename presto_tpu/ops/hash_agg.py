@@ -44,6 +44,7 @@ from ..block import Block, Dictionary, Page
 from ..types import BIGINT, BOOLEAN, Type, is_string
 from ..utils import kernel_cache
 from ..utils.batching import clamp_capacity
+from ..utils.metrics import METRICS
 from .aggregates import ACARRY, AMAX, AMIN, MAX, MIN, SUM, AggregateCall
 from .operator import Operator, OperatorContext, OperatorFactory, timed
 from .sorting import lexsort_fast
@@ -74,14 +75,49 @@ def _builder_key(tag, b, page: "Page" = None, input_dicts=None) -> tuple:
             tuple(kernel_cache.agg_call_key(c) for c in b.calls))
 
 
-def _segment_reduce(kind: str, values, seg_ids, num_segments: int):
+# Largest segment count whose scalar segment reduce runs as one masked
+# reduction per column instead of a scatter. XLA:TPU lowers
+# jax.ops.segment_* to a serial scatter (64-85 ms per 2^20-row 64-bit column
+# whatever the segment count); the masked form is one fused pass whose work
+# grows as rows x segments. Set from tools/dense_reduce_sweep.py on a v5e
+# (PERF.md section 6, PR 28): the largest S of the sweep at which the masked
+# form is at least 2x faster for int64 and float64 alike (7.3x and 3.3x at
+# 4096; 98x and 96x at Q1's 13).
+DENSE_REDUCE_MAX_SEGMENTS = 4096
+
+_SCATTER = {SUM: jax.ops.segment_sum, MIN: jax.ops.segment_min,
+            MAX: jax.ops.segment_max}
+_COMBINE = {SUM: jax.lax.add, MIN: jax.lax.min, MAX: jax.lax.max}
+
+
+def _reduce_identity(kind: str, dtype):
+    """What jax.ops.segment_sum/min/max leave in a segment no row maps to."""
     if kind == SUM:
-        return jax.ops.segment_sum(values, seg_ids, num_segments=num_segments)
-    if kind == MIN:
-        return jax.ops.segment_min(values, seg_ids, num_segments=num_segments)
-    if kind == MAX:
-        return jax.ops.segment_max(values, seg_ids, num_segments=num_segments)
-    raise AssertionError(kind)
+        return np.zeros((), dtype)
+    if jnp.issubdtype(dtype, jnp.floating):
+        return np.asarray(np.inf if kind == MIN else -np.inf, dtype)
+    info = jnp.iinfo(dtype)
+    return np.asarray(info.max if kind == MIN else info.min, dtype)
+
+
+def _masked_segment_reduce(kind: str, values, seg_ids, num_segments: int):
+    """out[g] = reduce(where(seg_ids == g, values, identity)) for every g, in
+    the dtype of `values`: a compare-select over a broadcast (segments, rows)
+    view that XLA fuses into the reduction (no such array reaches HBM)."""
+    ident = _reduce_identity(kind, values.dtype)
+    segs = jnp.arange(num_segments, dtype=seg_ids.dtype)
+    hit = seg_ids[None, :] == segs[:, None]
+    return jax.lax.reduce(jnp.where(hit, values[None, :], ident), ident,
+                          _COMBINE[kind], (1,))
+
+
+def _segment_reduce(kind: str, values, seg_ids, num_segments: int):
+    """Scalar segment reduce; the form follows the (static) segment count.
+    Ids outside [0, num_segments) are dropped in both forms."""
+    if (num_segments <= DENSE_REDUCE_MAX_SEGMENTS and values.ndim == 1
+            and values.dtype != jnp.bool_):
+        return _masked_segment_reduce(kind, values, seg_ids, num_segments)
+    return _SCATTER[kind](values, seg_ids, num_segments=num_segments)
 
 
 WIDE_STATE_MAX_GROUPS = 1 << 13  # scatter-table bound for sketch aggregates
@@ -126,12 +162,11 @@ def _reduce_all(contribs, kinds, identities, widths, gid, out_groups):
         kind = kinds[i]
         if kind in (AMIN, AMAX):
             y = contribs[i]
-            seg = jax.ops.segment_min if kind == AMIN else jax.ops.segment_max
-            m = seg(y, gid, num_segments=n_seg)
+            m = _segment_reduce(MIN if kind == AMIN else MAX, y, gid, n_seg)
             nr = y.shape[0]
             idx = jnp.arange(nr, dtype=jnp.int32)
             best = jnp.where(y == m[gid], idx, nr)
-            first = jax.ops.segment_min(best, gid, num_segments=n_seg)
+            first = _segment_reduce(MIN, best, gid, n_seg)
             win = jnp.clip(first, 0, max(nr - 1, 0))
             states.append(m[:out_groups])
             i += 1
@@ -286,8 +321,8 @@ def sort_group_reduce(keys: Tuple[jnp.ndarray, ...], mask: jnp.ndarray,
     # gather per key column (the old per-key scatter into an out_groups
     # table cost a full scatter pass per key — the dominant fold cost on
     # multi-key aggregations). Empty slots gather garbage; gvalid masks them.
-    first = jax.ops.segment_min(jnp.arange(n, dtype=jnp.int32), gid,
-                                num_segments=out_groups + 1)[:out_groups]
+    first = _segment_reduce(MIN, jnp.arange(n, dtype=jnp.int32), gid,
+                            out_groups + 1)[:out_groups]
     safe = jnp.clip(first, 0, max(n - 1, 0))
     gkeys = [k[safe] for k in sk]
     gvalid = jnp.arange(out_groups, dtype=jnp.int32) < jnp.minimum(num_groups, out_groups)
@@ -611,11 +646,9 @@ class GroupedAggregationBuilder:
         self._install_hash_kernel(page, slots)
         gkeys, states, used, stats = self._hash_kernel(page, slots=slots)
         if int(np.asarray(stats)[0]):
-            from ..utils.metrics import METRICS
             METRICS.count("pallas.agg_fallbacks")
             return False
         self.hash_pages += 1
-        from ..utils.metrics import METRICS
         METRICS.count("pallas.agg_pages")
         self._pending.append((gkeys, states, used))
         self._pending_rows += int(used.shape[0])
@@ -1064,8 +1097,8 @@ class DirectAggregationBuilder:
         parts = _reduce_all(contribs, self.kinds, self.identities,
                             self.widths, gid, self.D)
         new_table = _merge_tables(self.kinds, table, parts)
-        new_seen = seen | (jax.ops.segment_sum(
-            mask.astype(jnp.int32), gid, num_segments=self.D + 1)[: self.D] > 0)
+        new_seen = seen | (_segment_reduce(
+            SUM, mask.astype(jnp.int32), gid, self.D + 1)[: self.D] > 0)
         return tuple(new_table), new_seen
 
     def init_state(self):
@@ -1081,7 +1114,12 @@ class DirectAggregationBuilder:
         return self._table, self._seen
 
     def absorb_state(self, state) -> None:
+        """Take the accumulator one page kernel returned (add_page's or a
+        fused segment's): the one place a direct page is counted."""
         self._table, self._seen = state
+        dense = self.D + 1 <= DENSE_REDUCE_MAX_SEGMENTS
+        METRICS.count_many({"pages": 1, "dense_pages": int(dense)},
+                           prefix="agg.direct.")
 
     def add_page(self, page: Page) -> None:
         if self._kernel is None:
