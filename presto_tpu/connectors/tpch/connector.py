@@ -2,7 +2,8 @@
 
 Analogue of presto-tpch (tpch/TpchConnectorFactory.java:32, TpchMetadata,
 TpchSplitManager.java:45, TpchRecordSet). Schemas are scale factors: `tiny` (0.01),
-`sf1`, `sf10`, `sf100`, ... Splits are contiguous row ranges (order ranges for
+`sf1`, `sf10`, `sf100`, ... and, as TpchMetadata.schemaNameToScaleFactor has it, any
+`sf<number>` (`sf4`, `sf0.5`, `sf1.0`). Splits are contiguous row ranges (order ranges for
 lineitem) so every worker/chip generates its shard locally — the TPU analogue of
 split-at-the-data scheduling (SOURCE_DISTRIBUTION).
 
@@ -12,6 +13,7 @@ TpchNodePartitioningProvider, which lets co-partitioned scans skip the mesh exch
 from __future__ import annotations
 
 import math
+import re
 import threading
 from typing import Dict, Iterator, List, Optional, Sequence
 
@@ -31,6 +33,19 @@ from . import generator as g
 
 SCHEMAS = {"tiny": 0.01, "sf1": 1.0, "sf10": 10.0, "sf100": 100.0, "sf300": 300.0,
            "sf1000": 1000.0}
+_SF_SCHEMA = re.compile(r"sf(\d+(?:\.\d+)?)")
+
+
+def schema_scale_factor(schema: str) -> Optional[float]:
+    """The scale factor a schema name stands for, None where it names none: the listed
+    schemas, and any `sf<number>` with a positive finite number (listed or not, as
+    presto-tpch's schemaNameToScaleFactor reads them)."""
+    if schema in SCHEMAS:
+        return SCHEMAS[schema]
+    number = _SF_SCHEMA.fullmatch(schema)
+    sf = float(number.group(1)) if number else 0.0
+    return sf if 0.0 < sf < math.inf else None
+
 
 _TABLE_NAMES = ["region", "nation", "supplier", "part", "partsupp", "customer",
                 "orders", "lineitem"]
@@ -55,8 +70,9 @@ class TpchMetadata(ConnectorMetadata):
         return [SchemaTableName(s, t) for s in schemas for t in _TABLE_NAMES]
 
     def get_table_handle(self, name: SchemaTableName) -> Optional[TableHandle]:
-        if name.schema in SCHEMAS and name.table in _TABLE_NAMES:
-            return TableHandle(self.connector_id, name, extra=(SCHEMAS[name.schema],))
+        sf = schema_scale_factor(name.schema)
+        if sf is not None and name.table in _TABLE_NAMES:
+            return TableHandle(self.connector_id, name, extra=(sf,))
         return None
 
     def get_table_metadata(self, table: TableHandle) -> TableMetadata:

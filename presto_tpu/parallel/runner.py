@@ -60,7 +60,8 @@ from .mesh import MeshContext, WORKER_AXIS
 from .streaming_exchange import (EXCHANGE_STATS, ExchangeSinkOperatorFactory,  # noqa: F401
                                  ExchangeStatsBook, StreamingExchange,
                                  _compact_pad_jit, _range_key_for,
-                                 _zeros_shard, record_exchange_stat)
+                                 _zeros_shard, exchange_row_bytes,
+                                 record_exchange_stat)
 
 # (pages for each worker, shared column dictionaries)
 RemoteInput = Tuple[List[Page], List[Optional[Dictionary]]]
@@ -867,6 +868,10 @@ def run_exchange(mesh: MeshContext, kind: str, key_idx: Optional[List[int]],
             mask_shards[w], out_len))
     out_live = jax.device_get(
         [jnp.sum(m.astype(jnp.int32)) for _, _, m in out_compact])
+    if book is not None:
+        rows = sum(int(n) for n in out_live)
+        book.bump("rows", rows)
+        book.bump("live_bytes", rows * exchange_row_bytes(types, has_nulls))
     routed: List[List[Page]] = []
     for w in range(W):
         out_d, out_n, out_m = out_compact[w]

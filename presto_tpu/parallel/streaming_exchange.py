@@ -22,6 +22,10 @@ in flight. This module is that data plane, TPU-shaped:
 - REPARTITION/MERGE overflow rows (what `repartition_by_pid` would drop)
   come back as same-shape CARRY buffers, re-fed into the next chunk — skewed
   keys are correct by construction, not by worst-case capacity sizing;
+- received rows are PACKED per consumer into pages of the shard's length,
+  handed over when full (the last one at the stream's end): a consumer
+  sees ceil(rows / length) pages however the pumps' timing cut the chunks,
+  so the fragments above an exchange trace the same shapes in every run;
 - in-flight bytes are bounded on both sides: producers park (BLOCKED, the
   task executor's poll-able future) when staged + undelivered bytes exceed
   `exchange_inflight_bytes`, mirroring the scan pipeline's byte budget; no
@@ -94,6 +98,16 @@ class ExchangeStatsBook:
             if self.per_exchange:
                 out["per_exchange"] = [dict(e) for e in self.per_exchange]
             return out
+
+
+def exchange_row_bytes(types: Sequence[Type], has_nulls=()) -> int:
+    """Logical bytes one live row of an exchange carries: every column's
+    value at its type's declared width (not the padded chunk, not a widened
+    device dtype) plus one byte of null mask where the column carries one.
+    `exchange.live_bytes` counts delivered rows times this, so it reads the
+    same whatever implements the collective."""
+    return sum(np.dtype(t.np_dtype).itemsize for t in types) + \
+        sum(1 for h in has_nulls if h)
 
 
 def record_exchange_stat(name: str, delta: int = 1,
@@ -498,13 +512,16 @@ class _ChunkState:
     plus the host-tracked fill count (rows are packed densely at the front,
     so `count` fully describes the live prefix)."""
 
-    __slots__ = ("datas", "nulls", "mask", "count")
+    __slots__ = ("datas", "nulls", "mask", "count", "has_nulls")
 
-    def __init__(self, datas, nulls, mask):
+    def __init__(self, datas, nulls, mask, count=0, has_nulls=None):
         self.datas = datas
         self.nulls = nulls
         self.mask = mask
-        self.count = 0
+        self.count = count
+        # receive side only: which columns carried a null in any chunk
+        # packed into these buffers
+        self.has_nulls = has_nulls
 
 
 class _QueuedPage:
@@ -580,6 +597,9 @@ class StreamingExchange:
         self._out = [LocalExchangeBuffer(n_producers=1,
                                          max_bytes=per_worker_bytes)
                      for _ in range(W)]
+        # receive side: per shard length, each consumer's page being packed
+        # (pump-thread private, see _deliver_part)
+        self._recv: Dict[int, List[Optional[_ChunkState]]] = {}
         self._pump: Optional[threading.Thread] = None
         # pool_key set: the pump runs as generator steps on the process-wide
         # EXCHANGE_POOL under the query's fairness slot; None = a dedicated
@@ -608,7 +628,8 @@ class StreamingExchange:
         self.stats = {"fragment": fragment_id, "kind": kind,
                       "chunk_rows": self.chunk_rows, "out_cap": self.out_cap,
                       "chunks": 0, "overlap_chunks": 0, "rows_in": 0,
-                      "rows_out": 0, "carry_rows": 0, "compiles": 0,
+                      "rows_out": 0, "pages_out": 0, "live_bytes": 0,
+                      "carry_rows": 0, "compiles": 0,
                       "dispatch_s": 0.0, "overlap_s": 0.0, "stall_s": 0.0,
                       "partition_rows": [0] * W, "hot_keys": 0,
                       "replicated_rows": 0}
@@ -843,6 +864,12 @@ class StreamingExchange:
                 break
         if pending_delivery is not None:
             yield from self._deliver_gen(pending_delivery)
+        # the stream is complete: hand over each consumer's partial page
+        for L, packing in self._recv.items():
+            for w, acc in enumerate(packing):
+                if acc is not None and acc.count:
+                    yield from self._emit_gen(w, acc, L)
+                packing[w] = None
 
     # ------------------------------------------------------------ page intake
 
@@ -893,9 +920,9 @@ class StreamingExchange:
             if e.is_carry:  # a re-queued carry buffer, not a producer page
                 self.stats["carry_rows"] += int(n)
 
-    def _fresh_chunk(self, w: int) -> _ChunkState:
+    def _fresh_chunk(self, w: int, C: int = 0) -> _ChunkState:
         dev = self.mesh.devices[w]
-        C = self.chunk_rows
+        C = C or self.chunk_rows
         datas = tuple(_zeros_shard(dev, t.np_dtype, C, self.book)
                       for t in self.types)
         nulls = tuple(_zeros_shard(dev, bool, C, self.book)
@@ -1007,7 +1034,8 @@ class StreamingExchange:
         ncols = len(self.types)
         t0 = time.perf_counter_ns()
         span = trace.span(trace.EXCHANGE,
-                          f"chunk_dispatch f{self.fragment_id}", kind=self.kind)
+                          f"chunk_dispatch f{self.fragment_id}", kind=self.kind,
+                          program="exchange-stream")
         span.__enter__()
         range_keys = None
         if self.kind == MERGE:
@@ -1214,56 +1242,91 @@ class StreamingExchange:
     def _deliver_part(self, out_arrays, out_mask):
         """One output lane (regular or hot) -> consumer queues. Returns the
         total live rows delivered; per-partition counts accumulate into
-        stats["partition_rows"] (the skew-spread observable)."""
+        stats["partition_rows"] (the skew-spread observable).
+
+        Each consumer's received rows are PACKED into a page of the shard's
+        length and handed over only when it is full (the stream's last page
+        at its end): how many rows a chunk carries for a consumer depends on
+        when the pump dispatched it, and pages cut at chunk boundaries gave
+        the consumer fragment a page count that changed from run to run --
+        every join build and aggregation fold above an exchange then traced
+        a new shape and compiled again, in a warm query. Packed, a consumer
+        sees ceil(rows / length) pages whatever the timing was."""
         import jax
         import jax.numpy as jnp
 
         W, ncols = self.W, len(self.types)
         out_len = out_mask.shape[0] // W
-        compact = _compact_pad_jit()
+        fill = _fill_chunk_jit(ncols, out_len)
         data_shards = [self._shards_by_worker(out_arrays[c], out_len)
                        for c in range(ncols)]
         null_shards = [self._shards_by_worker(out_arrays[ncols + c], out_len)
                        for c in range(ncols)]
         mask_shards = self._shards_by_worker(out_mask, out_len)
-        compacted = []
-        for w in range(W):
-            compacted.append(compact(
-                tuple(data_shards[c][w] for c in range(ncols)),
-                tuple(null_shards[c][w] for c in range(ncols)),
-                mask_shards[w], out_len))
         # ONE host sync for all workers' live counts + null-mask presence
-        live_devs = [jnp.sum(m.astype(jnp.int32)) for _, _, m in compacted]
-        null_devs = [jnp.stack([jnp.any(n) for n in nn]) if ncols else None
-                     for _, nn, _ in compacted]
-        synced = jax.device_get(live_devs + [x for x in null_devs
-                                             if x is not None])
+        live_devs = [jnp.sum(m.astype(jnp.int32)) for m in mask_shards]
+        null_devs = [jnp.stack([jnp.any(null_shards[c][w])
+                                for c in range(ncols)])
+                     for w in range(W)] if ncols else []
+        synced = jax.device_get(live_devs + null_devs)
         lives = [int(x) for x in synced[:W]]
         has_nulls = synced[W:]
-        cap = min(max(self.page_capacity, 1 << 9), out_len)
+        packing = self._recv.setdefault(out_len, [None] * W)
         for w in range(W):
             live_w = lives[w]
             if not live_w:
                 continue
-            out_d, out_n, out_m = compacted[w]
-            hn = has_nulls[w] if ncols else ()
-            n_pages = -(-live_w // cap)
-            for off in range(0, n_pages * cap, cap):
-                blocks = []
-                for c in range(ncols):
-                    nm = out_n[c][off:off + cap] if hn[c] else None
-                    blocks.append(Block(self.types[c],
-                                        out_d[c][off:off + cap], nm,
-                                        self.dicts[c]))
-                page = Page(tuple(blocks), out_m[off:off + cap])
-                while not self._out[w].try_put(page, wait_s=STEP_WAIT_S):
-                    self._check_live()
-                    yield WAIT  # consumer backpressure: park the step
+            hn = np.asarray(has_nulls[w]) if ncols else \
+                np.zeros(0, dtype=bool)
+            acc = packing[w]
+            if acc is None:
+                acc = packing[w] = self._fresh_chunk(w, out_len)
+                acc.has_nulls = np.zeros(ncols, dtype=bool)
+            nd, nn, nm, ld, ln, lm = fill(
+                acc.datas, acc.nulls, acc.mask, acc.count,
+                tuple(data_shards[c][w] for c in range(ncols)),
+                tuple(null_shards[c][w] for c in range(ncols)),
+                mask_shards[w])
+            acc.datas, acc.nulls, acc.mask = nd, nn, nm
+            absorbed = min(out_len - acc.count, live_w)
+            acc.count += absorbed
+            acc.has_nulls = acc.has_nulls | hn
+            if acc.count >= out_len:
+                yield from self._emit_gen(w, acc, out_len)
+                # what did not fit opens the next page (same shape, live
+                # rows packed at the front)
+                packing[w] = _ChunkState(ld, ln, lm, live_w - absorbed, hn) \
+                    if live_w > absorbed else None
+            live_bytes = live_w * exchange_row_bytes(self.types, hn)
             self.stats["rows_out"] += live_w
+            self.stats["live_bytes"] += live_bytes
             self.stats["partition_rows"][w] += live_w
             if self.book is not None:
                 self.book.bump("rows", live_w)
+                self.book.bump("live_bytes", live_bytes)
         return sum(lives)
+
+    def _emit_gen(self, w: int, acc: _ChunkState, out_len: int):
+        """Enqueue one packed receive buffer for consumer `w` as standard
+        pow2 pages (parking on the queue's byte bound: a full queue parks
+        the pump STEP, never a pool worker)."""
+        cap = min(max(self.page_capacity, 1 << 9), out_len)
+        n_pages = -(-acc.count // cap)
+        self.stats["pages_out"] += n_pages
+        whole = cap == out_len   # the usual case: no slice, no program
+        for off in range(0, n_pages * cap, cap):
+            blocks = []
+            for c, (t, d) in enumerate(zip(self.types, self.dicts)):
+                nm = None
+                if acc.has_nulls[c]:
+                    nm = acc.nulls[c] if whole else acc.nulls[c][off:off + cap]
+                data = acc.datas[c] if whole else acc.datas[c][off:off + cap]
+                blocks.append(Block(t, data, nm, d))
+            page = Page(tuple(blocks),
+                        acc.mask if whole else acc.mask[off:off + cap])
+            while not self._out[w].try_put(page, wait_s=STEP_WAIT_S):
+                self._check_live()
+                yield WAIT  # consumer backpressure: park the step
 
     def _publish_stats(self) -> None:
         if self.book is not None:

@@ -1,8 +1,9 @@
 """TPC-H generator/connector tests (reference: presto-tpch TestTpchMetadata etc.)."""
 import numpy as np
+import pytest
 
 from presto_tpu.connectors.tpch import generator as g
-from presto_tpu.connectors.tpch.connector import TpchConnector
+from presto_tpu.connectors.tpch.connector import TpchConnector, schema_scale_factor
 from presto_tpu.spi.connector import Constraint, SchemaTableName
 
 
@@ -91,3 +92,27 @@ def test_statistics():
     th = conn.metadata().get_table_handle(SchemaTableName("sf1", "orders"))
     stats = conn.metadata().get_table_statistics(th, Constraint.all())
     assert stats.row_count == 1_500_000.0
+
+
+@pytest.mark.parametrize("schema,sf", [
+    ("tiny", 0.01), ("sf1", 1.0), ("sf1.0", 1.0), ("sf4", 4.0), ("sf0.5", 0.5),
+    ("sf40", 40.0)])
+def test_schema_names_a_scale_factor(schema, sf):
+    """`sf<number>` names a scale factor whether listed or not, as presto-tpch's
+    schemaNameToScaleFactor reads it: the table is the listed schema's own."""
+    assert schema_scale_factor(schema) == sf
+    meta = TpchConnector("tpch").metadata()
+    th = meta.get_table_handle(SchemaTableName(schema, "orders"))
+    assert th.extra == (sf,)
+    stats = meta.get_table_statistics(th, Constraint.all())
+    assert stats.row_count == g.table_row_count("orders", sf)
+
+
+@pytest.mark.parametrize("schema", [
+    "sf", "sfx", "sf0", "sf0.0", "sf-1", "sf1.", "sf.5", "sf1e2", "sf 1", "sf1_0",
+    "SF1", "sfnan", "sfinf", "sf" + "9" * 400, "s1", ""])
+def test_schema_that_names_no_scale_factor(schema):
+    assert schema_scale_factor(schema) is None
+    meta = TpchConnector("tpch").metadata()
+    assert meta.get_table_handle(SchemaTableName(schema, "orders")) is None
+    assert schema not in meta.list_schemas()
