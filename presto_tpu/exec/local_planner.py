@@ -40,7 +40,8 @@ from ..spi.connector import ConnectorPageSource, Constraint
 from ..sql.planner.optimizer import and_all, split_and, substitute
 from ..sql.planner.plan import (AggregationNode, EnforceSingleRowNode, FilterNode,
                                 JoinNode, LimitNode, OutputNode, PlanNode,
-                                ProjectNode, SemiJoinNode, SortNode, Symbol,
+                                ProjectNode, RemoteSourceNode, SemiJoinNode,
+                                SortNode, Symbol,
                                 TableScanNode, TopNNode, UnionNode, ValuesNode)
 from ..types import BIGINT, BOOLEAN, Type, is_string
 from ..utils.testing import PageConsumerFactory
@@ -333,7 +334,8 @@ class LocalExecutionPlanner:
                  n_workers: int = 1,
                  remote_dicts: Optional[Dict[int, List[Optional[Dictionary]]]] = None,
                  devices=None, bucket_filter: Optional[int] = None,
-                 pool_key: Optional[str] = None):
+                 pool_key: Optional[str] = None,
+                 remote_roots: Optional[Dict[int, PlanNode]] = None):
         self.metadata = metadata
         self.session = session
         from ..metadata import default_page_capacity
@@ -374,6 +376,9 @@ class LocalExecutionPlanner:
         # the runner plans fragments bottom-up and feeds each consumer the dicts
         # of its already-planned producers)
         self.remote_dicts = remote_dicts or {}
+        # producer fragment id -> its plan: lets _keys_unique carry a build
+        # side's key uniqueness across the exchange that feeds it
+        self.remote_roots = remote_roots or {}
         self.remote_slots: Dict[int, RemoteSourceSlot] = {}
         self._ids = itertools.count()
         self.pipelines: List[List[object]] = []
@@ -905,7 +910,9 @@ class LocalExecutionPlanner:
         """Build-strategy pick for the `hash_kernels` session property:
         'pallas' routes eligible builds (unique single-key INNER/LEFT) onto
         the open-addressing Pallas table; everything else — and the
-        'sorted' default — keeps the sort + binary-search build. The
+        'sorted' default — keeps the sort + binary-search build, beneath
+        which the build operator picks the direct-address table by itself
+        from the keys it sees (hash_join.JoinBuildOperator._dense_plan). The
         fallback is silent by contract (never an error): `pallas` must
         degrade to `sorted` for duplicate-key / multi-key / FULL builds
         (ops/hash_join.pallas_join_eligible)."""
@@ -927,8 +934,12 @@ class LocalExecutionPlanner:
             return FULL
         raise NotImplementedError(f"{node.type} join")
 
-    def _keys_unique(self, node: PlanNode, keys: List[Symbol]) -> bool:
-        """Conservative uniqueness proof for the build keys."""
+    def _keys_unique(self, node: PlanNode, keys: List[Symbol],
+                     remote: bool = False) -> bool:
+        """Conservative uniqueness proof for the build keys. `remote`: the
+        walk has crossed an exchange, and only what holds over ALL of the
+        producing fragment's tasks together still counts (a scan's splits
+        are disjoint; a partial aggregation's groups repeat across tasks)."""
         names = {k.name for k in keys}
         if isinstance(node, TableScanNode):
             by_symbol = {s.name: c.name for s, c in node.assignments}
@@ -942,7 +953,7 @@ class LocalExecutionPlanner:
                     return True
             return False
         if isinstance(node, FilterNode):
-            return self._keys_unique(node.source, keys)
+            return self._keys_unique(node.source, keys, remote)
         if isinstance(node, ProjectNode):
             inner = []
             for k in keys:
@@ -950,9 +961,15 @@ class LocalExecutionPlanner:
                 if not isinstance(e, SymbolRef):
                     return False
                 inner.append(Symbol(e.name, e.type))
-            return self._keys_unique(node.source, inner)
+            return self._keys_unique(node.source, inner, remote)
         if isinstance(node, SemiJoinNode):
-            return self._keys_unique(node.source, keys)
+            return self._keys_unique(node.source, keys, remote)
+        if isinstance(node, RemoteSourceNode):
+            # an exchange moves rows, and hands a worker no row twice
+            root = self.remote_roots.get(node.fragment_id)
+            return root is not None and self._keys_unique(root, keys, True)
+        if remote:
+            return False
         if isinstance(node, AggregationNode):
             return {k.name for k in node.keys} <= names
         if isinstance(node, EnforceSingleRowNode):

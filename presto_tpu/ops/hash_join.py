@@ -9,10 +9,12 @@ TPU re-design: per-row open addressing is scatter-chasing and serial, so the loo
 structure is one of:
 
 1. DENSE — build keys scattered into a dense int32 row-index table over the key
-   domain [min,max]; probing is ONE gather. Every TPC-H dimension join (custkey,
-   orderkey, partkey, suppkey) is a dense-PK join, so this is the common fast path —
-   think of it as the TPU's answer to the reference's BigintGroupByHash-style
-   specialization.
+   domain [min, min + pow2 bucket of the range); probing is ONE gather. Every
+   TPC-H dimension join (custkey, orderkey, partkey, suppkey) is a dense-PK join,
+   so this is the common fast path. Chosen at BUILD time, from the data (since
+   PR 30): a unique single-key integer build whose table stays within
+   DENSE_JOIN_MAX_TABLE_BYTES takes it (`JoinBuildOperator._dense_plan` reads
+   the live keys' min, max and count in one host sync); nothing sets it.
 2. SORTED — build rows sorted by 64-bit key; probe via vectorized binary search
    (jnp.searchsorted over the sorted key array). Handles duplicate build keys via
    [lo,hi) ranges and arbitrary key domains; multi-column keys go through a 64-bit
@@ -20,7 +22,8 @@ structure is one of:
    rows, never corrupt results).
 3. PALLAS — a masked open-addressing table built and probed by the Pallas
    kernels in ops/pallas_hash.py (the reference's PagesHash shape, fixed-trip
-   linear probing). Selected by the `hash_kernels` session property for
+   linear probing). Selected by the `hash_kernels` session property (which
+   decides only this: DENSE is chosen beneath its `sorted` value) for
    unique single-key INNER/LEFT builds; anything else — duplicate keys,
    multi-key, FULL joins, an oversized or overflowing table — falls back to
    SORTED at build time (the differential oracle), never errs.
@@ -47,10 +50,23 @@ import numpy as np
 from ..block import Block, Dictionary, Page
 from ..exec.spill import storage_type_for
 from ..types import BIGINT, Type
+from ..utils.metrics import METRICS
 from .operator import Operator, OperatorContext, OperatorFactory, timed
 from .sorting import lexsort_fast
 
 INNER, LEFT, RIGHT, FULL, SEMI, ANTI = "inner", "left", "right", "full", "semi", "anti"
+
+# Largest direct-address table (bytes: 4 a key slot) a join build may take in
+# place of the sorted form; JoinBuildOperator._dense_plan reads it. Set from
+# tools/dense_join_sweep.py on a v5e (PERF.md section 6, PR 30): speed sets no
+# bound inside the sweep. Probing one 2^20-row page takes 8.5 ms from every
+# table up to 64 MB and 15 ms from 128 MB to 2 GB, against the binary
+# search's 284-627 ms (33-74x); the table's build is 1-7x faster than the sort
+# it replaces (58 against 426 ms at 2^23 rows). So the bound is memory: 2^28 =
+# 1/64 of a v5e's HBM, which admits SF10's orderkey range on one chip (2^26
+# slots) and refuses SF100's (2^30 slots, 4.3 GB), and sixteen of which stay
+# within the default query_max_memory_bytes the table is charged against.
+DENSE_JOIN_MAX_TABLE_BYTES = 1 << 28
 
 
 def _mix64(x: jnp.ndarray) -> jnp.ndarray:
@@ -332,15 +348,21 @@ class JoinBuildOperator(Operator):
             zp = Page(zb, np.zeros((0,), dtype=np.bool_))
             pages.extend([zp] * (want - len(pages)))
         pages = tuple(pages)
-        if self.f.strategy == "dense" and kc == 1:
+        dense = self._dense_plan(pages) if kc == 1 else None
+        if dense is not None:
+            base, domain = dense
+            # charged before the table exists, so a query over its limit
+            # fails here and not in the allocator; close() releases it
+            self.context.user_memory.set_bytes(4 * domain)
+            self.context.stats.peak_memory_bytes = max(
+                self.context.stats.peak_memory_bytes,
+                self.context.revocable_memory.get_bytes() + 4 * domain)
             keys, payload, pnulls, mask, n_dev, table = _fused_build_dense(
-                pages, kc, null_cols, self.f.dense_min,
-                int(self.f.dense_max - self.f.dense_min + 1))
+                pages, kc, null_cols, np.int64(base), domain)
             src = LookupSource(
                 kind="dense", key_arrays=keys, payload=payload,
                 payload_meta=self.f.payload_meta,
-                build_count=n_dev, unique=self.f.unique,
-                table=table, base=self.f.dense_min)
+                build_count=n_dev, unique=self.f.unique, table=table, base=base)
         elif self.f.strategy == "pallas" and kc == 1:
             src, pnulls = self._build_pallas(pages, kc, null_cols)
         elif kc == 1:
@@ -381,13 +403,32 @@ class JoinBuildOperator(Operator):
             src.null_key_count = len(keep)
         return src
 
+    def _dense_plan(self, pages) -> Optional[Tuple[int, int]]:
+        """(base, domain) of a direct-address table for this build, or None
+        to keep the sorted form. The table stores ONE row per key slot and
+        has no sorted_row order, so only a unique single-key integer build
+        of an INNER/LEFT join takes it (FULL reads sorted_row for its
+        unmatched rows), and only while its bytes stay within
+        DENSE_JOIN_MAX_TABLE_BYTES. ONE host sync per build reads the live
+        keys' [min, max, count]; `domain` is the pow2 bucket of the range,
+        so builds whose ranges share a bucket share a compiled program."""
+        f = self.f
+        dtype = np.dtype(pages[0].blocks[0].data.dtype)
+        if f.strategy != "sorted" or not f.unique or f.track_unmatched \
+                or not (np.issubdtype(dtype, np.integer)
+                        or dtype == np.bool_):
+            return None
+        base, domain = dense_table_range(pages)
+        if 4 * domain > DENSE_JOIN_MAX_TABLE_BYTES:
+            return None
+        return base, domain
+
     def _build_pallas(self, pages, kc: int, null_cols):
         """Open-addressing build (ops/pallas_hash.py). ONE host sync per
         build reads the kernel's [overflow, max_run, distinct] stats — the
         price buys the static probe trip count; an oversized table, an
         insert overflow or an excessive probe bound falls back to the sorted
         build (row-identical by the differential contract, never an error)."""
-        from ..utils.metrics import METRICS
         from . import pallas_hash as ph
 
         keys, payload, pnulls, mask, n_dev = _concat_parts(
@@ -498,9 +539,38 @@ _concat_parts = functools.partial(jax.jit, static_argnames=(
     "kc", "null_cols"))(_concat_parts_impl)
 
 
-@functools.partial(jax.jit, static_argnames=("kc", "null_cols", "base",
-                                             "domain"))
+@jax.jit
+def _live_key_range(parts):
+    """-> int64 [min, max, count] of the live keys of compacted build pages
+    ((key, mask) pairs): what the host reads, once a build, to choose
+    between the direct-address table and the sorted form."""
+    i64 = np.iinfo(np.int64)
+    lo = [jnp.min(k.astype(jnp.int64), where=m, initial=i64.max)
+          for k, m in parts]
+    hi = [jnp.max(k.astype(jnp.int64), where=m, initial=i64.min)
+          for k, m in parts]
+    n = [jnp.sum(m, dtype=jnp.int64) for _, m in parts]
+    return jnp.stack([jnp.min(jnp.stack(lo)), jnp.max(jnp.stack(hi)),
+                      jnp.sum(jnp.stack(n))])
+
+
+def dense_table_range(pages) -> Tuple[int, int]:
+    """(base, domain) of the direct-address table over single-key build
+    pages: the smallest live key and the pow2 bucket of the live range
+    (0, 1 when no row is live). The build's one host sync."""
+    lo, hi, n = (int(x) for x in np.asarray(_live_key_range(
+        tuple((p.blocks[0].data, p.mask) for p in pages))))
+    if n == 0:
+        return 0, 1
+    return lo, 1 << (hi - lo).bit_length()
+
+
+@functools.partial(jax.jit, static_argnames=("kc", "null_cols", "domain"))
 def _fused_build_dense(pages, kc, null_cols, base, domain):
+    """`base` is traced and `domain` a pow2 bucket: the trace signature is
+    (page count bucket, capacity, domain bucket), whatever the smallest live
+    key. The host has checked that every live key lies in
+    [base, base + domain), so the int32 cast is exact."""
     keys, payload, pnulls, mask, n = _concat_parts_impl(pages, kc, null_cols)
     key = keys[0]
     idx = (key.astype(jnp.int64) - base).astype(jnp.int32)
@@ -528,25 +598,6 @@ def _fused_build_sorted(pages, kc, null_cols):
     keys, payload, pnulls, mask, n = _concat_parts_impl(pages, kc, null_cols)
     return (keys, payload, pnulls, mask, n) + \
         _sort_build_keys(combined_key(keys), mask)
-
-
-@functools.partial(jax.jit, static_argnames=("domain",))
-def _dense_kernel(key, payload, mask, base, domain):
-    idx = (key.astype(jnp.int64) - base).astype(jnp.int32)
-    idx = jnp.where(mask, idx, domain)  # dropped
-    table = jnp.full(domain, -1, dtype=jnp.int32)
-    rows = jnp.arange(key.shape[0], dtype=jnp.int32)
-    table = table.at[idx].set(rows, mode="drop")
-    return table
-
-
-def _build_dense(key, payload, mask, n, kmin, kmax, payload_meta, unique) -> LookupSource:
-    domain = int(kmax - kmin + 1)
-    table = _dense_kernel(key, payload, mask, kmin, domain)
-    return LookupSource(kind="dense", key_arrays=(key,), payload=payload,
-                        payload_meta=payload_meta,
-                        build_count=jnp.asarray(n, jnp.int32), unique=unique,
-                        table=table, base=kmin)
 
 
 _sorted_kernel_ck = jax.jit(_sort_build_keys)
@@ -610,28 +661,16 @@ class JoinBuildOperatorFactory(OperatorFactory):
                  payload_channels: List[int],
                  payload_meta: List[Tuple[Type, Optional[Dictionary]]],
                  strategy: str = "sorted", unique: bool = False,
-                 dense_min: int = 0, dense_max: int = 0,
                  track_unmatched: bool = False):
         super().__init__(operator_id, "JoinBuild")
         # FULL joins need the NULL-key build rows preserved for unmatched output
         self.track_unmatched = track_unmatched
-        if strategy not in ("dense", "sorted", "pallas"):
+        if strategy not in ("sorted", "pallas"):
             raise ValueError(
                 f"unknown join build strategy {strategy!r}; the planner "
                 "selects it from the `hash_kernels` session property "
-                "(sorted | pallas | auto)")
-        if strategy == "dense" and not unique:
-            # the dense table stores ONE row index per key slot — a duplicate build
-            # key would silently keep only the last row; refuse at plan time
-            raise ValueError(
-                "dense join strategy requires unique build keys; use "
-                "strategy='sorted' (the `hash_kernels=sorted` session "
-                "default) for duplicate-key builds")
-        if strategy == "dense" and len(key_channels) != 1:
-            raise ValueError(
-                "dense join strategy requires a single key channel; the "
-                "`hash_kernels` session property only routes single-key "
-                "builds off the sorted path")
+                "(sorted | pallas | auto); the direct-address table is "
+                "chosen beneath 'sorted' at build time, from the keys")
         if strategy == "pallas" and (not unique or len(key_channels) != 1
                                      or track_unmatched):
             # the open-addressing table stores one row per key slot and has
@@ -649,8 +688,6 @@ class JoinBuildOperatorFactory(OperatorFactory):
         self.payload_meta = payload_meta
         self.strategy = strategy
         self.unique = unique
-        self.dense_min = dense_min
-        self.dense_max = dense_max
         self.lookup_factory = LookupSourceFactory()
         self._builders_lock = threading.Lock()
         self._created = {}   # worker -> [JoinBuildOperator]
@@ -684,7 +721,11 @@ class JoinBuildOperatorFactory(OperatorFactory):
                     else (op._saw_null_key | o._saw_null_key)
             o._pages, o._host_pages, o._null_key_pages = [], [], []
             o._disk_runs = []
-        self.lookup_factory.set(op._build(), w)
+        src = op._build()
+        METRICS.count_many({"builds": 1,
+                            "builds.dense": int(src.kind == "dense")},
+                           prefix="join.")
+        self.lookup_factory.set(src, w)
         op._pages = []  # consumed into the lookup source
 
 
@@ -842,12 +883,13 @@ def probe_stage_kernel(cfg: ProbeStageConfig):
 
 def probe_match_dense(source_table, base, probe_keys, probe_mask):
     """DENSE unique build: one gather -> build row per probe row (-1 = no
-    match). Pure body — the standalone kernel below and the fused stage
-    both call it."""
+    match). The offset and its range test are 64-bit and the cast comes
+    after: a key 2^32 beyond a live slot must not wrap onto it. Pure body —
+    the standalone kernel below and the fused stage both call it."""
     domain = source_table.shape[0]
-    idx = (probe_keys.astype(jnp.int64) - base).astype(jnp.int32)
-    in_range = (idx >= 0) & (idx < domain) & probe_mask
-    idx = jnp.where(in_range, idx, 0)
+    off = probe_keys.astype(jnp.int64) - base
+    in_range = (off >= 0) & (off < domain) & probe_mask
+    idx = jnp.where(in_range, off, 0).astype(jnp.int32)
     row = jnp.where(in_range, source_table[idx], jnp.int32(-1))
     return row
 
@@ -985,7 +1027,8 @@ class LookupJoinOperator(Operator):
 
     def _match_rows(self, src, probe_keys, probe_mask):
         if src.kind == "dense":
-            return _probe_match_unique(src.table, src.base, probe_keys[0], probe_mask)
+            return _probe_match_unique(src.table, np.int64(src.base),
+                                       probe_keys[0], probe_mask)
         if src.kind == "pallas":
             return _probe_match_pallas(src.ph_keys, src.ph_rows,
                                        probe_keys[0], probe_mask,

@@ -209,6 +209,7 @@ class DistributedQueryRunner:
         drivers = []
         root_ep = None
         planned = []  # (fragment, local plan) — skew wiring scans consumers
+        remote_roots = {f.id: f.root for f in sub.fragments}
         try:
             with trace.phase("local_plan"):
                 for frag in sub.fragments:
@@ -220,7 +221,8 @@ class DistributedQueryRunner:
                                                n_workers=W,
                                                remote_dicts=frag_dicts,
                                                devices=self.mesh.devices,
-                                               pool_key=pool_key)
+                                               pool_key=pool_key,
+                                               remote_roots=remote_roots)
                     lp.attach_memory(mem_ctx, over_target)
                     if is_root:
                         ep = lp.plan(root)
@@ -343,6 +345,7 @@ class DistributedQueryRunner:
         # same invariant the streaming path and the cluster tier keep
         pool_key = next_query_key("mesh-q") \
             if bool(self.session.get("shared_pools", True)) else None
+        remote_roots = {f.id: f.root for f in sub.fragments}
         for frag in sub.fragments:
             is_root = frag is sub.root_fragment
             root = self._fragment_root(sub, frag)
@@ -352,7 +355,8 @@ class DistributedQueryRunner:
             lp = LocalExecutionPlanner(self.metadata, self.session,
                                        n_workers=W, remote_dicts=frag_dicts,
                                        devices=self.mesh.devices,
-                                       pool_key=pool_key)
+                                       pool_key=pool_key,
+                                       remote_roots=remote_roots)
             lp.attach_memory(*query_memory)
             with trace.phase("local_plan"):
                 ep = lp.plan(root)

@@ -103,7 +103,8 @@ def programs(one_chip):
     # jax.default_backend() for its page capacity and driver parallelism
     mp.setattr(jax, "default_backend", lambda: "tpu")
     mp.setattr(kernel_cache, "get_or_build", recording_build)
-    for mod, name in ((hash_join, "_fused_build_sorted"),
+    for mod, name in ((hash_join, "_fused_build_dense"),
+                      (hash_join, "_live_key_range"),
                       (topn, "_topn_merge")):
         mp.setattr(mod, name, rec.wrap(getattr(mod, name)))
     try:
@@ -148,21 +149,28 @@ def test_scan_and_aggregate_compile(one_chip, programs, name):
         _compile(one_chip, fn, args, kwargs)
 
 
-def test_join_build_compiles(one_chip, programs):
-    """Q3's sort-based build over customer (orders' is the same program at
-    eight times the rows and twice the compile time)."""
-    fn, args, kwargs = min(programs.named("_fused_build_sorted"),
-                           key=lambda p: _rows(p[1], p[2]))
-    _compile(one_chip, fn, args, kwargs)
+@pytest.mark.parametrize("name", ["_live_key_range", "_fused_build_dense"])
+def test_join_build_compiles(one_chip, programs, name):
+    """Q3's two builds take the direct-address table (PR 30): the range read
+    and the scatter into a table of the key range's pow2 bucket, 2^18 slots
+    for customer and 2^23 for orders. No sort program is left in a build."""
+    found = programs.named(name)
+    assert len(found) == 2
+    for fn, args, kwargs in found:
+        _compile(one_chip, fn, args, kwargs)
 
 
 def test_probe_and_partial_agg_segment_compiles(one_chip, programs):
-    """Q3's fused segment: two sorted-build probes, the filter/project and
-    the sort-based per-page partial aggregation in one program."""
+    """Q3's fused segment: two direct-address probes (one gather each from
+    the s32 tables), the filter/project and the sort-based per-page partial
+    aggregation in one program."""
     segments = programs.named("_compose")
     assert len(segments) == 1
     fn, args, kwargs = segments[0]
-    assert _rows(args, kwargs) == 1 << 20
+    assert _rows(args[:1], {}) == 1 << 20          # the probe page
+    int32_lengths = {x.shape[0] for x in jax.tree_util.tree_leaves(args[1])
+                     if x.dtype == np.int32 and x.shape}
+    assert {1 << 18, 1 << 23} <= int32_lengths     # the two tables
     _compile(one_chip, fn, args, kwargs)
 
 

@@ -4,6 +4,7 @@ import pytest
 
 from presto_tpu.types import BIGINT, DOUBLE, VARCHAR
 from presto_tpu.block import Block, Page, page_from_arrays
+from presto_tpu.ops import hash_join
 from presto_tpu.ops.hash_join import (ANTI, INNER, LEFT, SEMI, JoinBuildOperatorFactory,
                                       LookupJoinOperatorFactory)
 from presto_tpu.utils.testing import assert_rows_equal
@@ -32,8 +33,12 @@ def run_join(build_pages, probe_pages, build_fac, probe_fac):
     return rows
 
 
-@pytest.mark.parametrize("strategy", ["dense", "sorted"])
-def test_inner_unique_join(strategy):
+@pytest.mark.parametrize("form", ["dense", "sorted"])
+def test_inner_unique_join(form, monkeypatch):
+    # the build picks the direct-address table from the keys it sees; a byte
+    # bound of 0 refuses every table and keeps the sorted form
+    if form == "sorted":
+        monkeypatch.setattr(hash_join, "DENSE_JOIN_MAX_TABLE_BYTES", 0)
     # build: (key, value); probe: (key, weight)
     bkeys = np.asarray([1, 3, 5, 7], dtype=np.int64)
     bvals = np.asarray([10, 30, 50, 70], dtype=np.int64)
@@ -42,12 +47,12 @@ def test_inner_unique_join(strategy):
     pw = np.asarray([1.0, 2.0, 3.0, 4.0, 5.0, 6.0])
     probe = page_from_arrays([BIGINT, DOUBLE], [pkeys, pw], count=6, capacity=8)
     bf = JoinBuildOperatorFactory(0, [0], [1], [(BIGINT, None)],
-                                  strategy=strategy, unique=True,
-                                  dense_min=1, dense_max=7)
+                                  strategy="sorted", unique=True)
     pf = LookupJoinOperatorFactory(1, bf.lookup_factory, [0], [0, 1],
                                    [(BIGINT, None), (DOUBLE, None)],
                                    [0], [(BIGINT, None)], INNER)
     rows = run_join([build], [probe], bf, pf)
+    assert bf.lookup_factory.get().kind == form
     exp = [[5, 1.0, 50], [1, 2.0, 10], [7, 4.0, 70], [7, 5.0, 70]]
     assert_rows_equal(rows, exp)
 

@@ -37,12 +37,12 @@ def _masked(values, ids, segments):
 
 
 def _median_seconds(fn, *args, budget_s: float = 1.0, most: int = 30):
-    fn(*args).block_until_ready()  # compile + warm
+    jax.block_until_ready(fn(*args))  # compile + warm
     times = []
     spent = 0.0
     while len(times) < 3 or (spent < budget_s and len(times) < most):
         t0 = time.perf_counter()
-        fn(*args).block_until_ready()
+        jax.block_until_ready(fn(*args))
         times.append(time.perf_counter() - t0)
         spent += times[-1]
     return statistics.median(times)
