@@ -132,7 +132,7 @@ def columns_to_pages(datas: Sequence[np.ndarray],
                      page_capacity: int) -> List[Page]:
     """Re-page received columns at standard capacities so downstream operators
     reuse kernels compiled for scan pages (same policy as the mesh exchange,
-    parallel/runner.py run_exchange)."""
+    parallel/streaming_exchange.py)."""
     if nrows == 0:
         return []
     cap = min(page_capacity, 1 << (nrows - 1).bit_length())

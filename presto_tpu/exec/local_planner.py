@@ -236,37 +236,23 @@ class Chain:
 
 
 class RemoteSourceSlot:
-    """Per-fragment exchange endpoint: the runner deposits each worker's routed
-    pages + shared dictionaries here after the collective runs (the consumer
-    half of the reference's OutputBuffer -> ExchangeClient pair)."""
+    """Per-fragment exchange endpoint: the runner wires the producer's
+    stream in here after planning (the consumer half of the reference's
+    OutputBuffer -> ExchangeClient pair)."""
 
     def __init__(self, fragment_id: int):
         self.fragment_id = fragment_id
-        self._pages_by_worker: Dict[int, List[Page]] = {}
         # cluster mode plugs a streaming HTTP source in here (callable
-        # worker -> ConnectorPageSource); default is the deposited-pages replay
+        # worker -> ConnectorPageSource)
         self.source_factory = None
         # set by plan_subplan for MERGE inputs: [(channel, desc, nulls_first)]
         # — the cluster task wires a MergingRemoteSource instead of the
         # interleaving StreamingRemoteSource
         self.merge_orderings = None
-        # STREAMING mode (the mesh runner's default): a
-        # parallel/streaming_exchange.StreamingExchange attached after
-        # planning and before driver creation — consumers then block on
-        # chunk arrival instead of replaying preloaded page lists
+        # the mesh runner: a parallel/streaming_exchange.StreamingExchange
+        # attached after planning and before driver creation — consumers
+        # block on chunk arrival
         self.stream = None
-
-    def set_pages(self, worker: int, pages: List[Page]) -> None:
-        self._pages_by_worker[worker] = list(pages)
-
-    def pages(self, worker: int) -> List[Page]:
-        return self._pages_by_worker.get(worker, [])
-
-    def make_source(self, worker: int):
-        from ..spi.connector import FixedPageSource
-        if self.source_factory is not None:
-            return self.source_factory(worker)
-        return FixedPageSource(self.pages(worker))
 
 
 class RemoteSourceOperatorFactory(TableScanOperatorFactory):
@@ -276,13 +262,13 @@ class RemoteSourceOperatorFactory(TableScanOperatorFactory):
     the slot: with a StreamingExchange attached, consumers are
     LocalExchangeSources over the exchange's per-worker chunk queue —
     blocking on chunk arrival while the producer fragment still runs; the
-    barrier/cluster modes keep the inherited TableScanOperator replay of
-    deposited pages (or the cluster's streaming HTTP source_factory)."""
+    cluster keeps the inherited TableScanOperator over its streaming HTTP
+    source_factory."""
 
     def __init__(self, operator_id: int, slot: RemoteSourceSlot,
                  types: List[Type]):
-        super().__init__(operator_id, lambda w: [slot.make_source(w)], types,
-                         None)
+        super().__init__(operator_id, lambda w: [slot.source_factory(w)],
+                         types, None)
         self.name = "RemoteSource"
         self.slot = slot
 
@@ -748,7 +734,7 @@ class LocalExecutionPlanner:
         survivors instead of the scanned capacity. The operator itself
         adapts at runtime — an unselective filter switches it to permanent
         pass-through after the first page (ops/coalesce.py)."""
-        if not self.session.get("coalesce_pages") or not chain.factories:
+        if not chain.factories:
             return chain
         last = chain.factories[-1]
         if not getattr(last, "has_filter", False):
@@ -783,7 +769,6 @@ class LocalExecutionPlanner:
         unique = self._keys_unique(node.right, right_keys)
         build_fac = JoinBuildOperatorFactory(
             next(self._ids), build_key_ch, payload_ch, payload_meta,
-            strategy=self._join_strategy(node, build_key_ch, unique),
             unique=unique,
             track_unmatched=node.type == "full")
         self._add_pipeline(build_chain.factories + [build_fac])
@@ -823,7 +808,7 @@ class LocalExecutionPlanner:
         payload_meta = right.meta([s.name for s in build_out])
         build_fac = JoinBuildOperatorFactory(
             next(self._ids), [right.channel(ck_r.name)], payload_ch,
-            payload_meta, strategy="sorted",
+            payload_meta,
             unique=isinstance(node.right, EnforceSingleRowNode))
         self._add_pipeline(right.factories + [build_fac])
         probe_out_ch = [left.channel(s.name) for s in probe_out]
@@ -879,7 +864,7 @@ class LocalExecutionPlanner:
 
         build_fac = JoinBuildOperatorFactory(
             next(self._ids), [filt.channel(node.filtering_key.name)],
-            payload_ch, payload_meta, strategy="sorted", unique=False)
+            payload_ch, payload_meta, unique=False)
         self._add_pipeline(filt.factories + [build_fac])
         out_ch = list(range(len(src.symbols)))
         meta = src.meta([s.name for s in src.symbols])
@@ -895,34 +880,6 @@ class LocalExecutionPlanner:
             filter_fn=filter_fn, filter_probe_channels=filter_probe_ch,
             filter_build_channels=filter_build_ch, filter_key=filter_key)
         return Chain(src.factories + [fac], list(src.symbols), list(src.dicts))
-
-    def _hash_kernels(self) -> str:
-        """The `hash_kernels` session property as the planner acts on it.
-        The v5e compiler refuses both Pallas kernels (64-bit types;
-        tests/test_chip_compile.py holds the strict xfail), so until one
-        compiles `auto` is `sorted` on every backend. An explicit `pallas`
-        stays what was asked for and raises the compiler's own error on a
-        TPU."""
-        hk = str(self.session.get("hash_kernels", "sorted"))
-        return "sorted" if hk == "auto" else hk
-
-    def _join_strategy(self, node: JoinNode, build_key_ch, unique: bool) -> str:
-        """Build-strategy pick for the `hash_kernels` session property:
-        'pallas' routes eligible builds (unique single-key INNER/LEFT) onto
-        the open-addressing Pallas table; everything else — and the
-        'sorted' default — keeps the sort + binary-search build, beneath
-        which the build operator picks the direct-address table by itself
-        from the keys it sees (hash_join.JoinBuildOperator._dense_plan). The
-        fallback is silent by contract (never an error): `pallas` must
-        degrade to `sorted` for duplicate-key / multi-key / FULL builds
-        (ops/hash_join.pallas_join_eligible)."""
-        from ..ops.hash_join import pallas_join_eligible
-
-        if self._hash_kernels() == "pallas" and \
-                pallas_join_eligible(self._join_type(node), build_key_ch,
-                                     unique):
-            return "pallas"
-        return "sorted"
 
     @staticmethod
     def _join_type(node: JoinNode) -> str:
@@ -1043,15 +1000,10 @@ class LocalExecutionPlanner:
                 out_dicts.append(out_dict)
 
         op_step = {P_PARTIAL: OP_PARTIAL, P_FINAL: OP_FINAL}.get(step, SINGLE)
-        # hash_kernels session property -> the sort-grouping builder's
-        # Pallas insert-or-accumulate mode ("force" = wherever correct,
-        # default off)
         fac = HashAggregationOperatorFactory(
             next(self._ids), key_ch, key_types, key_dicts, key_domains, calls,
             op_step, self.page_capacity,
-            max_groups=int(self.session.get("max_groups")),
-            hash_grouping="force" if self._hash_kernels() == "pallas"
-            else "off")
+            max_groups=int(self.session.get("max_groups")))
         return Chain(src.factories + [fac], out_syms, out_dicts)
 
     def visit_WindowNode(self, node) -> Chain:

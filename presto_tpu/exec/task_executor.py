@@ -33,53 +33,14 @@ class TaskExecutor:
     execute(drivers) blocks until every driver finishes or any driver raises
     (first exception propagates, remaining drivers are abandoned). Driver
     ownership is exclusive: a driver is held by at most one runner thread at
-    a time (the heap hands it out, the thread returns it).
-
-    ``persistent=True`` keeps the runner threads alive between execute()
-    calls (the reference's TaskExecutor keeps one long-lived runner pool) —
-    the barrier-mode mesh runner re-enters once per STAGE and reuses them;
-    callers own the lifetime and must close(). The default spawns threads
-    per call, which is right for one-shot users (one query = one execute —
-    the streaming runner's shape, where every fragment's drivers go through
-    a single call anyway) and leaks nothing when the executor is ephemeral."""
+    a time (the heap hands it out, the thread returns it). Threads are
+    spawned per call: one query = one execute (every fragment's drivers go
+    through a single call), so nothing outlives the executor."""
 
     def __init__(self, n_threads: int = 4,
-                 quantum_ns: int = _DEFAULT_QUANTUM_NS,
-                 persistent: bool = False):
+                 quantum_ns: int = _DEFAULT_QUANTUM_NS):
         self.n_threads = max(1, int(n_threads))
         self.quantum_ns = quantum_ns
-        self.persistent = persistent
-        self._pool_lock = threading.Lock()
-        self._threads: list = []
-        import queue as _queue
-        self._inbox: "_queue.SimpleQueue" = _queue.SimpleQueue()
-
-    def _ensure_threads(self, n: int) -> None:
-        with self._pool_lock:
-            while len(self._threads) < n:
-                t = threading.Thread(
-                    target=self._worker,
-                    name=f"task-runner-{len(self._threads)}", daemon=True)
-                self._threads.append(t)
-                t.start()
-
-    def _worker(self) -> None:
-        while True:
-            run = self._inbox.get()
-            if run is None:
-                return
-            try:
-                run.runner_loop()
-            finally:
-                run.worker_exited()
-
-    def close(self) -> None:
-        """Stop persistent runner threads. Required (in a finally) for
-        ``persistent=True`` executors; a no-op otherwise."""
-        with self._pool_lock:
-            for _ in self._threads:
-                self._inbox.put(None)
-            self._threads = []
 
     def execute(self, drivers: Sequence[Driver]) -> None:
         if not drivers:
@@ -90,7 +51,7 @@ class TaskExecutor:
             # single runner: same parking scheduler, on the calling thread
             # (a blocked driver must still defer to later drivers in the list)
             run.runner_loop()
-        elif not self.persistent:
+        else:
             threads = [threading.Thread(target=run.runner_loop,
                                         name=f"task-runner-{i}", daemon=True)
                        for i in range(n)]
@@ -98,14 +59,6 @@ class TaskExecutor:
                 t.start()
             for t in threads:
                 t.join()
-        else:
-            self._ensure_threads(n)
-            for _ in range(n):
-                self._inbox.put(run)
-            # all results are recorded by the time the last runner leaves the
-            # run; waiting for that also guarantees no thread still holds a
-            # driver when the caller starts tearing state down
-            run.wait_workers(n)
         if run.error is not None:
             raise run.error
         if run.outstanding:
@@ -126,23 +79,12 @@ class _Run:
         self.outstanding = len(drivers)  # unfinished drivers
         self.error: Optional[BaseException] = None
         self._seq = itertools.count()
-        self._exited_workers = 0         # pool threads done with this run
         # the submitting (query) thread's flight recorder rides with the run
         # so runner threads attribute driver spans to the right query even
         # when several traced queries share the process
         self.traced = trace.capture()
         for d in drivers:
             heapq.heappush(self.ready, (0, next(self._seq), d))
-
-    def worker_exited(self) -> None:
-        with self.cv:
-            self._exited_workers += 1
-            self.cv.notify_all()
-
-    def wait_workers(self, n: int) -> None:
-        with self.cv:
-            while self._exited_workers < n:
-                self.cv.wait()
 
     # ------------------------------------------------------------- scheduling
 

@@ -39,7 +39,6 @@ class Session:
         # this (join-distribution CBO; the reference bounds replicated size via
         # join_max_broadcast_table_size)
         "broadcast_join_threshold_rows": 1 << 15,
-        "join_reordering_strategy": "AUTOMATIC",  # NONE | AUTOMATIC
         "max_groups": 1 << 20,
         # memory/spill (advisory accounting over XLA's allocator). Under
         # pressure, revocation walks the full ladder: device HBM -> host RAM
@@ -62,35 +61,11 @@ class Session:
         # (one sink file each) when the source is at least K * this many rows
         "scaled_writers": True,
         "writer_min_rows_per_driver": 1 << 20,
-        # pack filtered scans' surviving rows into full pages before the
-        # stateful operators (ops/coalesce.py) — downstream kernel work and
-        # per-page dispatches then scale with selectivity
-        "coalesce_pages": True,
         # fuse maximal runs of page-local operators (filter/project -> join
         # probe -> partial hash-agg / TopN contribution) into ONE jitted
         # dispatch per page (ops/fused_segment.py). False = per-operator
         # dispatches — the differential-testing oracle
         "segment_fusion": True,
-        # --- Pallas hash kernels (ops/pallas_hash.py) ---
-        # join build/probe + aggregation grouping strategy:
-        #   sorted — the sort + binary-search / segment-reduce paths (the
-        #            differential oracle, today's default);
-        #   pallas — open-addressing hash tables built and probed by the
-        #            Pallas kernels wherever they are CORRECT (unique
-        #            single-key INNER/LEFT builds; table-friendly group
-        #            counts) — ineligible shapes fall back to sorted, never
-        #            raise;
-        #   auto   — sorted on every backend until a kernel compiles for
-        #            the chip (local_planner._hash_kernels).
-        # The kernels run only under the Pallas interpreter (every backend
-        # but a TPU), where all three values are row-identical
-        # (tests/test_pallas_hash.py is the contract). The v5e compiler
-        # refuses both ("64-bit types are not supported";
-        # tests/test_chip_compile.py), so `pallas` on a TPU raises that.
-        # Note: aggregations whose partials run inside FUSED segments keep
-        # the sort kernel (the segment compiles the sort partial config at
-        # plan time); the agg half engages on unfused pipelines.
-        "hash_kernels": "sorted",
         # --- streaming scan pipeline (ops/scan_pipeline.py) ---
         # staged host->HBM ingest: split-parallel readers -> ordered
         # re-batch into device-shaped pages -> async upload. False =
@@ -108,12 +83,9 @@ class Session:
         # 0 = engine default (scan_pipeline.DEFAULT_PREFETCH_BYTES, 256MB)
         "scan_prefetch_bytes": 0,
         # --- streaming mesh exchange (parallel/streaming_exchange.py) ---
-        # stream fixed-capacity chunks through the inter-fragment collectives
+        # fixed-capacity chunks stream through the inter-fragment collectives
         # while producer drivers still run (producer/consumer fragments share
-        # one task executor). False = the stage-barrier exchange — each
-        # fragment drains fully before one variable-shape collective — kept
-        # as the differential oracle, exactly like segment_fusion
-        "streaming_exchange": True,
+        # one task executor)
         # per-worker chunk capacity in rows (pow2-rounded); 0 = engine
         # default (streaming_exchange.DEFAULT_CHUNK_ROWS, 4096). The chunk
         # shape is FIXED per query, so each exchange kind compiles ONE
@@ -124,9 +96,9 @@ class Session:
         # full intermediate result; 0 = engine default
         # (streaming_exchange.DEFAULT_INFLIGHT_BYTES, 256MB)
         "exchange_inflight_bytes": 0,
-        # skew-aware repartitioning for partitioned INNER joins (streaming
-        # mode): the build-side exchange samples its first chunk for heavy-
-        # hitter keys, SPLITS hot build rows round-robin across partitions
+        # skew-aware repartitioning for partitioned INNER joins: the
+        # build-side exchange samples its first chunk for heavy-hitter
+        # keys, SPLITS hot build rows round-robin across partitions
         # and the probe-side exchange REPLICATES matching probe rows to all
         # partitions — a 99%-one-key join spreads across the mesh instead of
         # landing on one chip (carry-over already made it *correct*; this
@@ -156,7 +128,7 @@ class Session:
         # dropped at the source) so a FAILED / OOM-killed / retry-exhausted
         # query dumps a forensic Chrome trace it never opted into
         # (QueryInfo.failure_trace_path, GET /v1/query/{id}/trace). False =
-        # recorder compiled out — the bench's overhead comparison point
+        # recorder compiled out
         "query_blackbox": True,
         # --- cluster fault tolerance (cluster/retry.py) ---
         # NONE fails fast; QUERY re-plans + re-runs the whole query on

@@ -142,7 +142,7 @@ def build_q3(schema: str = "sf1", page_capacity: int = 1 << 20):
     cpred = call("equal", BOOLEAN, input_ref(1, VARCHAR), constant("BUILDING", VARCHAR))
     cproc = PageProcessor(clayout, cpred, [input_ref(0, BIGINT)])
     cscan = TableScanOperatorFactory(0, [csrc], cproc.output_types, cproc)
-    cbuild = JoinBuildOperatorFactory(1, [0], [], [], strategy="sorted", unique=False)
+    cbuild = JoinBuildOperatorFactory(1, [0], [], [], unique=False)
     d1 = Driver([cscan.create_operator(), cbuild.create_operator()])
 
     # pipeline 2: orders filtered + semi-joined, then built as lookup source
@@ -160,7 +160,7 @@ def build_q3(schema: str = "sf1", page_capacity: int = 1 << 20):
         [], [], SEMI)
     obuild = JoinBuildOperatorFactory(4, [0], [2, 3],
                                       [(DATE, None), (olayout.types[3], None)],
-                                      strategy="sorted", unique=True)
+                                      unique=True)
     d2 = Driver([oscan.create_operator(), osemi.create_operator(),
                  obuild.create_operator()])
 

@@ -15,15 +15,7 @@ hostile to the VPU, so grouping is done with the two strategies that vectorize:
    group boundaries, segment-reduce. Exact (no hash collisions), static shapes,
    O(n log n) on the TPU's bitonic sorter. Research on TPU databases reaches the same
    conclusion: sort + segment-reduce beats scatter hash tables on this hardware.
-3. PALLAS (the `hash_kernels` session property): the SORT builder swaps its
-   per-page sort+reduce for insert-or-accumulate through the open-addressing
-   Pallas table (ops/pallas_hash.py) once the first page proves the group
-   count is table-friendly — each row's key claims/finds a slot, and
-   contributions segment-reduce straight into the slot table. Slot-indexed
-   partials feed the existing fold unchanged; an insert overflow falls back
-   to the sort kernel permanently (never a wrong result). This is the
-   measured answer to "does a scatter hash table ever beat sort here" —
-   differential-tested row-identical either way.
+Those two are all there is; the planner picks by the key domains (make_builder).
 
 Cross-page accumulation keeps a compact state table (<= max_groups) plus a pending
 buffer of per-page partials; when the buffer fills it is folded into the table by the
@@ -371,8 +363,7 @@ class GroupedAggregationBuilder:
 
     def __init__(self, key_types: Sequence[Type], key_dicts: Sequence[Optional[Dictionary]],
                  calls: Sequence[AggregateCall], page_capacity: int,
-                 max_groups: int = 1 << 20, from_intermediate: bool = False,
-                 hash_grouping: str = "off"):
+                 max_groups: int = 1 << 20, from_intermediate: bool = False):
         self.user_key_types = list(key_types)
         # internal key signature interleaves a BOOLEAN null-flag column per key
         # (_null_safe_keys): every internal loop over key arrays (fold, spill
@@ -436,17 +427,6 @@ class GroupedAggregationBuilder:
         self._defer: Optional[bool] = None
         self._out_groups: Optional[int] = None
         self._raw_kernel = None
-        # Pallas insert-or-accumulate grouping (ops/pallas_hash.py), the
-        # `hash_kernels` session property's agg half: "force" engages
-        # wherever CORRECT (integer-comparable keys, scalar states, grouping
-        # that reduces), "off" (default) keeps pure
-        # sort+segment-reduce. Decided once from the first page's true group
-        # count (_decide_strategy); an insert overflow at any later page
-        # falls back to the sort kernel permanently — never a wrong result.
-        self._hash_grouping = hash_grouping
-        self._hash_slots: Optional[int] = None
-        self._hash_kernel = None
-        self.hash_pages = 0  # pages grouped by the Pallas kernel (telemetry)
 
     # --- per page ---------------------------------------------------------
 
@@ -503,13 +483,6 @@ class GroupedAggregationBuilder:
             self._install_raw_kernel(page)
             self.absorb_raw(self._raw_kernel(page), page.capacity)
             return
-        if self._hash_slots is not None:
-            if self._absorb_hash_page(page):
-                return
-            # insert overflow (more distinct keys than the table holds, or
-            # pathological clustering): permanent fallback to the sort
-            # kernel — the page recomputes below, results stay exact
-            self._hash_slots = None
         self._install_page_kernel(page)
         out_groups = self.page_out_groups(page.capacity)
         if not self.absorb_partial(self._page_kernel(page, out_groups),
@@ -567,9 +540,7 @@ class GroupedAggregationBuilder:
         scalar sync, same price a fold pays). Groups ~ rows: per-page
         sort+reduce buys nothing — defer pages as raw rows into the fold.
         Groups << rows: shrink later partials' tables to the observed count
-        (CPU backend only: the overflow guard syncs per page). With the
-        `hash_kernels` knob on, the same observed count also decides the
-        Pallas insert-or-accumulate table size."""
+        (CPU backend only: the overflow guard syncs per page)."""
         self._defer = first_ng > capacity // 2
         if self._defer:
             return
@@ -578,83 +549,6 @@ class GroupedAggregationBuilder:
         on_cpu = _jax.default_backend() == "cpu"
         if on_cpu and first_ng <= capacity // 8:
             self._out_groups = max(1024, _pow2(int(first_ng * 1.5) + 1))
-        if self._hash_grouping == "force" and self._keys_hashable():
-            # engages wherever the table is merely CORRECT — grouping
-            # reduces at all and the keys compare as int64. Decline upfront
-            # when the capped table provably cannot hold the observed count
-            # at load <= 0.5 — otherwise the first hash page would pay a
-            # full insert just to overflow
-            slot_cap = 1 << 16
-            if first_ng <= min(capacity // 2, slot_cap // 4):
-                self._hash_slots = max(1 << 10, _pow2(4 * first_ng))
-
-    def _keys_hashable(self) -> bool:
-        """Pallas grouping compares keys as int64 slot components: floats
-        (bit-pattern equality != SQL equality on -0.0) and vector (sketch)
-        states stay on the sort path."""
-        if self._wide_cap is not None:
-            return False
-        return all(np.issubdtype(np.dtype(t.np_dtype), np.integer)
-                   or np.dtype(t.np_dtype) == np.bool_
-                   for t in self.key_types)
-
-    # --- pallas insert-or-accumulate (ops/pallas_hash.py) ------------------
-
-    def _page_hash_partial(self, page: Page, slots: int):
-        """One page -> a SLOT-INDEXED partial (gkeys, states, used, stats):
-        the open-addressing insert assigns every live row its key's slot as
-        the group id, contributions segment-reduce straight into the slot
-        table (insert-or-accumulate — no sort), and the slot key components
-        decode back to the builder's interleaved (value, null-flag) key
-        signature. Holes (unclaimed slots) are masked by `used`; the fold
-        consumes holey partials exactly like compact ones (invalid rows
-        route to its trash segment)."""
-        from . import pallas_hash as ph
-
-        mask = page.mask
-        keys = _null_safe_keys(page, self._key_channels)
-        contribs = _call_contributions(self.calls, page,
-                                       self.from_intermediate)
-        comps = tuple(k.astype(jnp.int64) for k in keys)
-        slot_comps, slot_rows, gid, stats = ph.insert_table(
-            comps, mask, slots)
-        # masked / overflowed rows -> the trash segment (overflow also
-        # raises the stats flag: the caller discards this partial entirely)
-        gid = jnp.where(mask & (gid >= 0), gid, slots)
-        states = _reduce_all(tuple(contribs), self.kinds, self.identities,
-                             self.widths, gid, slots)
-        used = slot_rows >= 0
-        gkeys = tuple(sc.astype(np.dtype(t.np_dtype))
-                      for sc, t in zip(slot_comps, self.key_types))
-        fixed = tuple(_where_valid(used, s, ident)
-                      for s, ident in zip(states, self.identities))
-        return gkeys, fixed, used, stats
-
-    def _install_hash_kernel(self, page: Page, slots: int) -> None:
-        if self._hash_kernel is None:
-            self._hash_kernel = kernel_cache.get_or_install(
-                _builder_key(("pallas-hash", slots), self, page),
-                lambda: jax.jit(self._page_hash_partial,
-                                static_argnames=("slots",)))
-
-    def _absorb_hash_page(self, page: Page) -> bool:
-        """Group one page through the Pallas table. Returns False on insert
-        overflow (one scalar sync per page — the same price the shrunken
-        sort path pays; both engage only where _decide_strategy accepted
-        that cost): the caller re-runs the page through the sort kernel."""
-        slots = self._hash_slots
-        self._install_hash_kernel(page, slots)
-        gkeys, states, used, stats = self._hash_kernel(page, slots=slots)
-        if int(np.asarray(stats)[0]):
-            METRICS.count("pallas.agg_fallbacks")
-            return False
-        self.hash_pages += 1
-        METRICS.count("pallas.agg_pages")
-        self._pending.append((gkeys, states, used))
-        self._pending_rows += int(used.shape[0])
-        if self._pending_rows >= 4 * self.max_groups:
-            self._fold()
-        return True
 
     # --- combine ----------------------------------------------------------
 
@@ -1410,7 +1304,7 @@ class HashAggregationOperator(Operator):
 
 def make_builder(key_types, key_dicts, key_domains, calls, page_capacity,
                  max_groups=1 << 20, from_intermediate=False,
-                 direct_domain_limit=1 << 16, hash_grouping="off"):
+                 direct_domain_limit=1 << 16):
     """Strategy pick (LocalExecutionPlanner's group-by-hash choice analogue)."""
     from .collect_agg import COLLECT_NAMES, CollectAggregationBuilder
     if any(c.function.name in COLLECT_NAMES for c in calls):
@@ -1430,14 +1324,13 @@ def make_builder(key_types, key_dicts, key_domains, calls, page_capacity,
             return DirectAggregationBuilder(key_types, key_dicts, key_domains, calls,
                                             from_intermediate)
     return GroupedAggregationBuilder(key_types, key_dicts, calls, page_capacity,
-                                     max_groups, from_intermediate,
-                                     hash_grouping=hash_grouping)
+                                     max_groups, from_intermediate)
 
 
 class HashAggregationOperatorFactory(OperatorFactory):
     def __init__(self, operator_id: int, key_channels, key_types, key_dicts,
                  key_domains, calls, step: str, page_capacity: int,
-                 max_groups: int = 1 << 20, hash_grouping: str = "off"):
+                 max_groups: int = 1 << 20):
         super().__init__(operator_id, f"HashAggregation({step})")
         self.key_channels = list(key_channels)
         self.key_types = list(key_types)
@@ -1447,17 +1340,13 @@ class HashAggregationOperatorFactory(OperatorFactory):
         self.step = step
         self.page_capacity = page_capacity
         self.max_groups = max_groups
-        # "hash_kernels" session property -> the sort builder's Pallas
-        # insert-or-accumulate mode (off | force)
-        self.hash_grouping = hash_grouping
         self._kernel_donor = None
 
     def create_operator(self, worker: int = 0) -> Operator:
         from_intermediate = self.step == FINAL
         builder = make_builder(self.key_types, self.key_dicts, self.key_domains,
                                self.calls, self.page_capacity, self.max_groups,
-                               from_intermediate,
-                               hash_grouping=self.hash_grouping)
+                               from_intermediate)
         # all builders of this factory share one jitted kernel: instance state
         # (tables, pending buffers) is per-builder, the traced computation is
         # pure factory config — workers must not each pay the trace+compile

@@ -20,13 +20,8 @@ structure is one of:
    [lo,hi) ranges and arbitrary key domains; multi-column keys go through a 64-bit
    mix with post-match verification on the true key columns (collisions only mask
    rows, never corrupt results).
-3. PALLAS — a masked open-addressing table built and probed by the Pallas
-   kernels in ops/pallas_hash.py (the reference's PagesHash shape, fixed-trip
-   linear probing). Selected by the `hash_kernels` session property (which
-   decides only this: DENSE is chosen beneath its `sorted` value) for
-   unique single-key INNER/LEFT builds; anything else — duplicate keys,
-   multi-key, FULL joins, an oversized or overflowing table — falls back to
-   SORTED at build time (the differential oracle), never errs.
+
+Those two are all there is, and no option selects between them.
 
 Join row expansion (output cardinality > input) is the two-pass count-then-emit the
 reference's LookupJoinPageBuilder does with position lists: cumsum of match counts,
@@ -88,7 +83,7 @@ def combined_key(keys: Sequence[jnp.ndarray]) -> jnp.ndarray:
 
 @dataclasses.dataclass
 class LookupSource:
-    kind: str                          # "dense" | "sorted" | "pallas"
+    kind: str                          # "dense" | "sorted"
     key_arrays: Tuple[jnp.ndarray, ...]  # true build key columns (compacted)
     payload: Tuple[jnp.ndarray, ...]   # build output columns (compacted)
     payload_meta: List[Tuple[Type, Optional[Dictionary]]]
@@ -100,10 +95,6 @@ class LookupSource:
     # sorted:
     sorted_key: Optional[jnp.ndarray] = None  # (n,) int64 combined keys, invalid rows +inf
     sorted_row: Optional[jnp.ndarray] = None  # (n,) int32 original row index
-    # pallas (ops/pallas_hash.py open-addressing table):
-    ph_keys: Optional[jnp.ndarray] = None  # (slots,) int64 stored keys
-    ph_rows: Optional[jnp.ndarray] = None  # (slots,) int32 row idx, -1 empty
-    ph_trips: int = 0                      # STATIC probe trip count (pow2)
     # exact multi-key packing (offsets/shifts/widths per key column): when the
     # build key ranges fit 63 bits, the combined key is a bijective pack — no
     # mixed-hash collisions, so every multi-key path gets the exact fast paths
@@ -363,8 +354,6 @@ class JoinBuildOperator(Operator):
                 kind="dense", key_arrays=keys, payload=payload,
                 payload_meta=self.f.payload_meta,
                 build_count=n_dev, unique=self.f.unique, table=table, base=base)
-        elif self.f.strategy == "pallas" and kc == 1:
-            src, pnulls = self._build_pallas(pages, kc, null_cols)
         elif kc == 1:
             keys, payload, pnulls, mask, n_dev, sorted_key, sorted_row = \
                 _fused_build_sorted(pages, kc, null_cols)
@@ -414,7 +403,7 @@ class JoinBuildOperator(Operator):
         so builds whose ranges share a bucket share a compiled program."""
         f = self.f
         dtype = np.dtype(pages[0].blocks[0].data.dtype)
-        if f.strategy != "sorted" or not f.unique or f.track_unmatched \
+        if not f.unique or f.track_unmatched \
                 or not (np.issubdtype(dtype, np.integer)
                         or dtype == np.bool_):
             return None
@@ -422,47 +411,6 @@ class JoinBuildOperator(Operator):
         if 4 * domain > DENSE_JOIN_MAX_TABLE_BYTES:
             return None
         return base, domain
-
-    def _build_pallas(self, pages, kc: int, null_cols):
-        """Open-addressing build (ops/pallas_hash.py). ONE host sync per
-        build reads the kernel's [overflow, max_run, distinct] stats — the
-        price buys the static probe trip count; an oversized table, an
-        insert overflow or an excessive probe bound falls back to the sorted
-        build (row-identical by the differential contract, never an error)."""
-        from . import pallas_hash as ph
-
-        keys, payload, pnulls, mask, n_dev = _concat_parts(
-            pages, kc, null_cols)
-        n = int(keys[0].shape[0])
-        slots = ph.table_slots(n)
-        # float keys are ineligible: the table stores astype(int64) values
-        # and the probe has NO true-key verify (the sorted path's
-        # searchsorted also truncates, but its `bv == pk` re-check on the
-        # original arrays rejects the false matches this would create)
-        if not (np.issubdtype(np.dtype(keys[0].dtype), np.integer)
-                or np.dtype(keys[0].dtype) == np.bool_):
-            slots = None
-        if slots is not None:
-            insert = ph.insert_table_jit(1, n, slots)
-            (slot_keys,), slot_rows, _gid, stats = insert(
-                (keys[0],), mask)
-            overflow, max_run, _ng = [int(x) for x in np.asarray(stats)]
-            trips = ph.probe_trips_for(max_run)
-            if not overflow and trips <= ph.PROBE_TRIPS_CAP:
-                METRICS.count("pallas.join_builds")
-                src = LookupSource(
-                    kind="pallas", key_arrays=keys, payload=payload,
-                    payload_meta=self.f.payload_meta, build_count=n_dev,
-                    unique=self.f.unique, ph_keys=slot_keys,
-                    ph_rows=slot_rows, ph_trips=trips)
-                return src, pnulls
-        METRICS.count("pallas.join_fallbacks")
-        sorted_key, sorted_row = _sorted_kernel_ck(combined_key(keys), mask)
-        return LookupSource(
-            kind="sorted", key_arrays=keys, payload=payload,
-            payload_meta=self.f.payload_meta, build_count=n_dev,
-            unique=self.f.unique, sorted_key=sorted_key,
-            sorted_row=sorted_row), pnulls
 
     def is_finished(self) -> bool:
         return self._finishing
@@ -660,33 +608,14 @@ class JoinBuildOperatorFactory(OperatorFactory):
     def __init__(self, operator_id: int, key_channels: List[int],
                  payload_channels: List[int],
                  payload_meta: List[Tuple[Type, Optional[Dictionary]]],
-                 strategy: str = "sorted", unique: bool = False,
+                 unique: bool = False,
                  track_unmatched: bool = False):
         super().__init__(operator_id, "JoinBuild")
         # FULL joins need the NULL-key build rows preserved for unmatched output
         self.track_unmatched = track_unmatched
-        if strategy not in ("sorted", "pallas"):
-            raise ValueError(
-                f"unknown join build strategy {strategy!r}; the planner "
-                "selects it from the `hash_kernels` session property "
-                "(sorted | pallas | auto); the direct-address table is "
-                "chosen beneath 'sorted' at build time, from the keys")
-        if strategy == "pallas" and (not unique or len(key_channels) != 1
-                                     or track_unmatched):
-            # the open-addressing table stores one row per key slot and has
-            # no sorted_row ordering for the FULL-join unmatched epilogue;
-            # the planner (and `hash_kernels=auto`) must fall back to
-            # 'sorted' for duplicate-key / multi-key / FULL builds rather
-            # than construct this
-            raise ValueError(
-                "pallas join strategy requires a unique single-key "
-                "INNER/LEFT build; set the `hash_kernels` session property "
-                "to 'auto' (or 'sorted') so ineligible builds fall back to "
-                "the sorted strategy instead of raising")
         self.key_channels = key_channels
         self.payload_channels = payload_channels
         self.payload_meta = payload_meta
-        self.strategy = strategy
         self.unique = unique
         self.lookup_factory = LookupSourceFactory()
         self._builders_lock = threading.Lock()
@@ -746,16 +675,13 @@ class ProbeStageConfig:
     traced function branches on lives here; everything data lives in the aux
     pytree from :func:`probe_stage_aux`."""
 
-    kind: str                              # "dense" | "sorted" | "pallas"
+    kind: str                              # "dense" | "sorted"
     join_type: str                         # INNER | LEFT | SEMI | ANTI
     probe_key_channels: Tuple[int, ...]
     probe_output_channels: Tuple[int, ...]
     build_output_channels: Tuple[int, ...]
     payload_meta: Tuple                    # ((type, dict), ...) per SELECTED build col
     null_aware: bool = False
-    # pallas probes unroll a FIXED trip count (ops/pallas_hash.py): the
-    # bound is static kernel config, so it lives here, not in the aux
-    pallas_trips: int = 0
 
 
 def probe_plan_fusible(join_type: str, key_channels, unique: bool,
@@ -774,16 +700,6 @@ def probe_plan_fusible(join_type: str, key_channels, unique: bool,
     return False
 
 
-def pallas_join_eligible(join_type: str, key_channels, unique: bool) -> bool:
-    """Plan-time test shared by the local planner and the differential
-    tests: may this join's build use the Pallas open-addressing strategy?
-    Unique single-key INNER/LEFT only — duplicate-key, multi-key, FULL and
-    semi builds keep the sorted strategy (the `hash_kernels=auto` fallback
-    contract: ineligible shapes NEVER raise, they fall back)."""
-    return (unique and len(key_channels) == 1
-            and join_type in (INNER, LEFT))
-
-
 def probe_stage_cfg(f: "LookupJoinOperatorFactory",
                     src: LookupSource) -> ProbeStageConfig:
     return ProbeStageConfig(
@@ -792,8 +708,7 @@ def probe_stage_cfg(f: "LookupJoinOperatorFactory",
         probe_output_channels=tuple(f.probe_output_channels),
         build_output_channels=tuple(f.build_output_channels),
         payload_meta=tuple(_payload_meta_selected(src, f)),
-        null_aware=f.null_aware,
-        pallas_trips=src.ph_trips)
+        null_aware=f.null_aware)
 
 
 def probe_stage_aux(src: LookupSource):
@@ -802,8 +717,6 @@ def probe_stage_aux(src: LookupSource):
     convert kernel per query); they device_put at the jit call."""
     if src.kind == "dense":
         match = (src.table, np.asarray(src.base, np.int64))
-    elif src.kind == "pallas":
-        match = (src.ph_keys, src.ph_rows)
     else:
         match = (src.sorted_key, src.sorted_row, tuple(src.key_arrays))
     return (match, tuple(src.payload), tuple(src.payload_nulls),
@@ -818,7 +731,7 @@ def probe_stage_key(cfg: ProbeStageConfig) -> tuple:
     return ("probe-stage", cfg.kind, cfg.join_type, cfg.probe_key_channels,
             cfg.probe_output_channels, cfg.build_output_channels,
             tuple((t.name, kc.dict_key(d)) for t, d in cfg.payload_meta),
-            cfg.null_aware, cfg.pallas_trips)
+            cfg.null_aware)
 
 
 def apply_probe_stage(page: Page, aux, cfg: ProbeStageConfig) -> Page:
@@ -838,10 +751,6 @@ def apply_probe_stage(page: Page, aux, cfg: ProbeStageConfig) -> Page:
     if cfg.kind == "dense":
         table, base = match
         row = probe_match_dense(table, base, probe_keys[0], probe_mask)
-    elif cfg.kind == "pallas":
-        ph_keys, ph_rows = match
-        row = probe_match_pallas(ph_keys, ph_rows, probe_keys[0], probe_mask,
-                                 cfg.pallas_trips)
     else:
         sorted_key, sorted_row, key_arrays = match
         row = probe_match_sorted(sorted_key, sorted_row,
@@ -895,20 +804,6 @@ def probe_match_dense(source_table, base, probe_keys, probe_mask):
 
 
 _probe_match_unique = jax.jit(probe_match_dense)
-
-
-def probe_match_pallas(ph_keys, ph_rows, probe_keys, probe_mask, trips: int):
-    """PALLAS unique build: fixed-trip open-addressing scan (one Pallas
-    kernel dispatch; ops/pallas_hash.py). Pure body — the standalone kernel
-    and the fused stage both call it; `trips` is static config."""
-    from .pallas_hash import probe_table
-
-    return probe_table(ph_keys, ph_rows, probe_keys.astype(jnp.int64),
-                       probe_mask, trips)
-
-
-_probe_match_pallas = functools.partial(
-    jax.jit, static_argnames=("trips",))(probe_match_pallas)
 
 
 def probe_match_sorted(sorted_key, sorted_row, ck, probe_keys_list,
@@ -1029,10 +924,6 @@ class LookupJoinOperator(Operator):
         if src.kind == "dense":
             return _probe_match_unique(src.table, np.int64(src.base),
                                        probe_keys[0], probe_mask)
-        if src.kind == "pallas":
-            return _probe_match_pallas(src.ph_keys, src.ph_rows,
-                                       probe_keys[0], probe_mask,
-                                       trips=src.ph_trips)
         return _probe_match_sorted_unique(src.sorted_key, src.sorted_row,
                                           src.combine_probe(tuple(probe_keys)),
                                           tuple(probe_keys), probe_mask,

@@ -1,9 +1,8 @@
 """Staged host->HBM streaming scan pipeline.
 
-The streaming-scan wall, rebuilt as a pipeline of independent stages
-(BENCH_TPU.json: the device kernel sustains ~44M rows/s resident while the
-out-of-core pcol stream delivered 1.28M rows/s — the host side, not the
-chip, was the bottleneck; `hostgen_stall_s` dominated the wall):
+The streaming-scan wall, rebuilt as a pipeline of independent stages (the
+host side, not the chip, bounds an out-of-core stream; no cell of
+benchmark/ streams yet, PERF.md section 7):
 
     split readers (pool) -> ordered staging -> re-batch -> upload -> compute
     mmap + slice + remap    bytes-bounded      take_rows    async     driver

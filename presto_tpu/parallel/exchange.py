@@ -160,7 +160,3 @@ def gather_to_single(arrays: Sequence[jnp.ndarray], mask: jnp.ndarray,
     outs, m = broadcast_gather(arrays, mask, axis_name)
     widx = lax.axis_index(axis_name)
     return outs, m & (widx == 0)
-
-
-def psum_scalar(x: jnp.ndarray, axis_name: str = WORKER_AXIS) -> jnp.ndarray:
-    return lax.psum(x, axis_name)

@@ -1,10 +1,8 @@
 """Streaming mesh exchange: chunked, overlapped inter-fragment collectives.
 
-The barrier exchange (parallel/runner.run_exchange) drains a whole fragment,
-materializes ALL of its output, and only then lets the consumer fragment
-start — the device idles at every stage boundary and an entire intermediate
-result is resident at once. The reference never works that way: its
-ExchangeClient pulls pages over HTTP while producers still run
+The mesh runner's ONE data plane between fragments (parallel/runner.py). No
+fragment drains before its consumer starts: the reference's ExchangeClient
+pulls pages over HTTP while producers still run
 (operator/ExchangeClient.java), and OutputBuffer backpressure bounds what is
 in flight. This module is that data plane, TPU-shaped:
 
@@ -13,8 +11,7 @@ in flight. This module is that data plane, TPU-shaped:
   accumulating pages;
 - an exchange pump thread dispatches ONE compiled shard_map collective per
   chunk; the shape is static per query, so the repartition/broadcast/merge
-  program compiles once per (kind, shape) and is reused for every chunk —
-  unlike the barrier path's per-exchange pow2-volume recompiles;
+  program compiles once per (kind, shape) and is reused for every chunk;
 - dispatch is double-buffered: the collective for chunk k is issued async
   (XLA dispatch returns futures) and its delivery sync is deferred until
   chunk k+1 has been absorbed and dispatched, so host-side compaction of the
@@ -59,8 +56,7 @@ from ..utils.metrics import METRICS
 from .mesh import MeshContext, WORKER_AXIS
 
 # ---------------------------------------------------------------------------
-# shared exchange observability + device helpers (the barrier path in
-# parallel/runner.py imports these — one accounting, two data planes)
+# exchange observability + device helpers
 # ---------------------------------------------------------------------------
 
 # process-wide aggregate for the multichip dryrun's "no host copies between
@@ -134,8 +130,8 @@ _ZEROS_LOCK = threading.Lock()
 
 def _zeros_shard(dev, dtype, L: int, book: Optional[ExchangeStatsBook] = None):
     """Cached all-zero device array (immutable, safely shared as a read-only
-    collective input). Pump threads and the barrier path hit this
-    concurrently — LRU bookkeeping is not atomic, hence the lock."""
+    collective input). Pump threads hit this concurrently — LRU
+    bookkeeping is not atomic, hence the lock."""
     import jax
 
     key = (dev, np.dtype(dtype).str, L)
@@ -154,27 +150,6 @@ def _zeros_shard(dev, dtype, L: int, book: Optional[ExchangeStatsBook] = None):
             _ZEROS_CACHE.popitem(last=False)
         _ZEROS_CACHE[key] = z
     return z
-
-
-@functools.lru_cache(maxsize=1)
-def _compact_pad_jit():
-    """(R,) columns + mask -> (L,) prefix-compacted columns + mask, on the
-    inputs' device. The reference materializes selected positions the same
-    way before serializing (PartitionedOutputOperator.java:380); here it is
-    one fused scatter and the result never leaves the worker's chip."""
-    import jax
-    import jax.numpy as jnp
-
-    def fn(datas, nulls, mask, L):
-        pos = jnp.cumsum(mask.astype(jnp.int32)) - 1
-        tgt = jnp.where(mask, pos, L)  # dead rows scatter out of bounds
-        out_mask = jnp.zeros(L, dtype=jnp.bool_).at[tgt].set(mask, mode="drop")
-        out_d = tuple(jnp.zeros(L, dtype=a.dtype).at[tgt].set(a, mode="drop")
-                      for a in datas)
-        out_n = tuple(jnp.zeros(L, dtype=jnp.bool_).at[tgt].set(n, mode="drop")
-                      for n in nulls)
-        return out_d, out_n, out_mask
-    return jax.jit(fn, static_argnames=("L",))
 
 
 def _range_key_for(data, nulls, type_, dictionary, descending: bool,
@@ -222,10 +197,10 @@ def _pow2(n: int, floor: int = 1) -> int:
 DEFAULT_CHUNK_ROWS = 1 << 12
 DEFAULT_INFLIGHT_BYTES = 1 << 28
 
-# per-peer receive floor for the streaming repartition: smaller than the
-# barrier path's _MIN_EXCHANGE_CAP because the chunk shape is FIXED per
-# query anyway (no compile-diversity concern) and carry-over makes small
-# capacities correct; tiny floors only cost extra dispatches under skew
+# per-peer receive floor for the repartition: small, because the chunk shape
+# is FIXED per query anyway (no compile-diversity concern) and carry-over
+# makes small capacities correct; tiny floors only cost extra dispatches
+# under skew
 _MIN_STREAM_OUT_CAP = 1 << 6
 
 # ---------------------------------------------------------------------------
@@ -348,11 +323,11 @@ def _fill_chunk_jit(ncols: int, C: int):
 # ---------------------------------------------------------------------------
 
 # Collective LAUNCH order must be identical on every device: two pump
-# threads (or a pump and a barrier exchange) each dispatching an SPMD
-# program could otherwise enqueue their collectives in different orders on
-# different devices — the classic concurrent-collective deadlock. Dispatch
-# is async (returns futures), so serializing the launch keeps all the
-# overlap while guaranteeing one global enqueue order.
+# threads each dispatching an SPMD program could otherwise enqueue their
+# collectives in different orders on different devices — the classic
+# concurrent-collective deadlock. Dispatch is async (returns futures), so
+# serializing the launch keeps all the overlap while guaranteeing one global
+# enqueue order.
 COLLECTIVE_DISPATCH_LOCK = threading.Lock()
 
 
@@ -360,8 +335,7 @@ def _streaming_program(mesh, kind: str, key_idx: Optional[Tuple[int, ...]],
                        ncols: int, W: int, C: int, out_cap: int,
                        range_dtype: Optional[str],
                        skew: Optional[str] = None):
-    """-> (program, compiled_now). Carry-aware analogue of the barrier
-    path's _exchange_program: REPARTITION/MERGE return
+    """-> (program, compiled_now). REPARTITION/MERGE return
     (out_arrays, out_mask, carry_arrays, carry_mask); BROADCAST/GATHER
     return (out_arrays, out_mask) — an all_gather has full capacity, so
     nothing can ever overflow. `skew` selects the REPARTITION heavy-hitter
@@ -877,8 +851,7 @@ class StreamingExchange:
         """Page -> [datas tuple, nulls tuple, mask, live_count(None=unknown)]
         on the worker's device, widened to the exchange's declared types.
         Host-sourced (numpy) pages are uploads the multichip dryrun's
-        device-residency assertion exists to catch — counted exactly like
-        the barrier path does."""
+        device-residency assertion exists to catch, so they are counted."""
         import jax
         import jax.numpy as jnp
 

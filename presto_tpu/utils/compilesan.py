@@ -61,11 +61,8 @@ _STORM_MULT = 3
 _THIS_FILE = os.path.abspath(__file__)
 _FUNNEL_FILE = os.path.join(os.path.dirname(_THIS_FILE), "kernel_cache.py")
 
-# exchange program keys carry two prefixes ("exchange-barrier" for the
-# barrier path, "exchange-stream" for the streaming path) but reconcile
-# against ONE engine counter (collective_compiles) — one family
-_FAMILIES = {"fused-segment": "fused-segment",
-             "exchange-barrier": "exchange", "exchange-stream": "exchange"}
+# exchange program keys reconcile against the engine's collective_compiles
+_FAMILIES = {"fused-segment": "fused-segment", "exchange-stream": "exchange"}
 
 
 def _stack(skip: int = 2, limit: int = _MAX_STACK) -> List[str]:
@@ -266,7 +263,7 @@ class CompileSanitizer:
 
     def family_totals(self) -> Dict[str, int]:
         """Builds per reconciliation family: 'fused-segment' (the segment
-        compiler), 'exchange' (barrier + streaming collective programs)
+        compiler), 'exchange' (the exchange's collective programs)
         and 'other' (every remaining kernel-cache build)."""
         out = {"fused-segment": 0, "exchange": 0, "other": 0}
         with self._meta:

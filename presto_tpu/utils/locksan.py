@@ -26,9 +26,8 @@ code is replaced by an instrumented wrapper that records:
 
 Only locks allocated from files under this repository are instrumented —
 stdlib internals (queue mutexes, Event conditions) pass through untouched,
-so the overhead and the graph stay scoped to engine locking. Uninstrumented
-benchmarking is guarded the other way around: ``bench.py`` refuses to run
-with the sanitizer installed.
+so the overhead and the graph stay scoped to engine locking. Never
+benchmark with the sanitizer installed.
 
 Locks are named by their allocation site (``presto_tpu/ops/scan.py:52``);
 tests can name them explicitly via the always-instrumenting module

@@ -13,12 +13,10 @@ Everything that touches libtpu lives in module-scoped fixtures of this one
 file: one process at a time may load the library, and under xdist every
 worker imports every test file.
 """
-import functools
 import pathlib
 import threading
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
@@ -187,37 +185,6 @@ def test_hand_q1_step_compiles(one_chip):
     fn, args = entry()
     _compile(one_chip, jax.jit(fn),
              tuple(jax.ShapeDtypeStruct(a.shape, a.dtype) for a in args), {})
-
-
-# The two Pallas hash kernels: the chip's compiler refuses both. Strict, so
-# the day one compiles the suite says so (and `hash_kernels=auto` can mean
-# something other than `sorted` again — exec/local_planner._hash_kernels).
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="Mosaic: 64-bit types are not supported")
-def test_pallas_insert_compiles(one_chip, monkeypatch):
-    from presto_tpu.ops import pallas_hash as ph
-
-    monkeypatch.setattr(ph, "interpret_mode", lambda: False)
-    n, slots = 1 << 15, 1 << 16
-    _compile(one_chip,
-             jax.jit(functools.partial(ph.insert_table, slots=slots)),
-             ((jax.ShapeDtypeStruct((n,), jnp.int64),),
-              jax.ShapeDtypeStruct((n,), jnp.bool_)), {})
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError,
-                   reason="Mosaic: 64-bit types are not supported")
-def test_pallas_probe_compiles(one_chip, monkeypatch):
-    from presto_tpu.ops import pallas_hash as ph
-
-    monkeypatch.setattr(ph, "interpret_mode", lambda: False)
-    n, slots = 1 << 15, 1 << 16
-    _compile(one_chip, jax.jit(functools.partial(ph.probe_table, trips=8)),
-             (jax.ShapeDtypeStruct((slots,), jnp.int64),
-              jax.ShapeDtypeStruct((slots,), jnp.int32),
-              jax.ShapeDtypeStruct((n,), jnp.int64),
-              jax.ShapeDtypeStruct((n,), jnp.bool_)), {})
 
 
 # where the persistent compile cache lives (no chip, no child process)

@@ -43,7 +43,7 @@ def _join(build_rows, probe_keys, join_type=INNER, key_type=BIGINT,
     -> (the published lookup source, output rows [probe key, build value])"""
     build_options.setdefault("unique", True)
     bf = JoinBuildOperatorFactory(0, [0], [1], [(BIGINT, None)],
-                                  strategy="sorted", **build_options)
+                                  **build_options)
     pf = LookupJoinOperatorFactory(1, bf.lookup_factory, [0], [0],
                                    [(key_type, None)], [0], [(BIGINT, None)],
                                    join_type,

@@ -217,19 +217,6 @@ SMALL_SQL = ("select o_custkey, count(*), sum(o_totalprice) from orders "
              "group by o_custkey")
 
 
-def test_barrier_path_counts_the_same_rows_and_bytes(eight_devices):
-    """`run_exchange` shares the book: the same rows cross, so the same
-    `rows` and `live_bytes`, whichever data plane moved them."""
-    streamed = _mesh_runner(eight_devices).execute(SMALL_SQL)
-    barrier = _mesh_runner(eight_devices,
-                           streaming_exchange=False).execute(SMALL_SQL)
-    assert sorted(map(tuple, barrier.rows)) == sorted(map(tuple, streamed.rows))
-    a, b = streamed.stats["exchange"], barrier.stats["exchange"]
-    assert (a["mode"], b["mode"]) == ("streaming", "barrier")
-    assert a["rows"] == b["rows"] > 0
-    assert a["live_bytes"] == b["live_bytes"] > 0
-
-
 def test_a_consumer_sees_a_page_count_that_timing_cannot_move(eight_devices):
     """Received rows are packed: an exchange hands each consumer
     ceil(rows / page) pages however many chunks carried them (pages cut at

@@ -406,3 +406,20 @@ def test_register_connector_factory(tmp_path):
         assert calls == [("c1", {"my.flag": "on"})]
     finally:
         C.FACTORIES.pop("custom", None)
+
+
+def test_every_session_default_has_a_reader():
+    """A property nothing reads is a switch wired to nothing: every key of
+    Session.DEFAULTS is named, quoted, somewhere in the engine besides the
+    table that declares it."""
+    import pathlib
+    import re
+
+    import presto_tpu
+    from presto_tpu.metadata import Session
+
+    root = pathlib.Path(presto_tpu.__file__).parent
+    source = "\n".join(p.read_text() for p in sorted(root.rglob("*.py"))
+                       if p.name != "metadata.py")
+    quoted = set(re.findall(r"""["']([a-z][a-z0-9_]*)["']""", source))
+    assert sorted(set(Session.DEFAULTS) - quoted) == []
