@@ -304,11 +304,12 @@ class Page:
         return Page(self.blocks, mask)
 
     def compact(self) -> "Page":
-        """Pack live rows to the front (cumsum-scatter; no dynamic shapes).
+        """Pack live rows to the front (cumsum, then scatter or gather by the
+        column's width; no dynamic shapes).
 
         Returns a page of the same capacity whose mask is a prefix. This is the moment
         the reference would materialize selected positions into a new Page
-        (PageProcessor output); here it is one fused scatter.
+        (PageProcessor output); here it is one program a schema.
         """
         return _compact(self)
 
@@ -336,19 +337,28 @@ class Page:
 def _compact(page: Page) -> Page:
     mask = page.mask
     cap = mask.shape[0]
+    rows = jnp.arange(cap, dtype=jnp.int32)
     pos = jnp.cumsum(mask.astype(jnp.int32)) - 1  # target slot per live row
     n = pos[-1] + 1
+    new_mask = rows < n
     tgt = jnp.where(mask, pos, cap)  # dead rows target out-of-bounds -> dropped
-    new_blocks = []
-    for b in page.blocks:
-        out = jnp.zeros_like(b.data)
-        out = out.at[tgt].set(b.data, mode="drop")
-        nulls = None
-        if b.nulls is not None:
-            nulls = jnp.zeros(cap, dtype=jnp.bool_).at[tgt].set(b.nulls, mode="drop")
-        new_blocks.append(Block(b.type, out, nulls, b.dictionary))
-    new_mask = jnp.arange(cap, dtype=jnp.int32) < n
-    return Page(tuple(new_blocks), new_mask)
+    # src[j] = the row that lands in slot j. Built for the 64-bit columns
+    # alone (XLA drops it from a page that has none): the chip emulates a
+    # 64-bit scatter as a u32 pair at 13 times a 32-bit scatter's cost and
+    # gathers it at 3 times, while a narrow column's own scatter is cheaper
+    # than its gather (tools/compact_sweep.py, PERF.md section 6, PR 32).
+    src = jnp.zeros_like(rows).at[tgt].set(rows, mode="drop")
+
+    def move(a):
+        if a.dtype.itemsize < 8:
+            return jnp.zeros_like(a).at[tgt].set(a, mode="drop")
+        packed = a.at[src].get(mode="promise_in_bounds")
+        return jnp.where(new_mask, packed, 0)
+
+    return Page(tuple(
+        Block(b.type, move(b.data),
+              None if b.nulls is None else move(b.nulls), b.dictionary)
+        for b in page.blocks), new_mask)
 
 
 def page_from_arrays(types: Sequence[Type], arrays: Sequence[Array],

@@ -9,7 +9,8 @@ accumulator, emitting only FULL pages (plus one tail) — the reference's
 PageProcessor output coalescing / MergePages.java, re-shaped for static
 XLA shapes:
 
-- compact: one scatter per page (block._compact), XLA-fused;
+- compact: block._compact, one program a schema (a 64-bit column moves by
+  a gather through one 32-bit row index, a narrower one by its own scatter);
 - pack: `lax.dynamic_update_slice` at the accumulator's live count — a
   dynamic OFFSET is fine under jit (shapes stay static);
 - overflow: concat(acc, incoming)[:C] emits, [C:] is the new accumulator —
@@ -26,9 +27,11 @@ from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..block import Block, Page, _compact
 from ..types import Type
+from ..utils.metrics import METRICS
 from .operator import Operator, OperatorContext, OperatorFactory, timed
 
 
@@ -105,24 +108,26 @@ class CoalesceOperator(Operator):
     @timed("add_input_ns")
     def add_input(self, page: Page) -> None:
         self.context.record_input(page, page.capacity)
-        if self._mode == "pass":
-            self._pending.append(page)
-            return
         if self._mode is None:
             # adapt on the FIRST page: an unselective filter makes packing
             # pure overhead, so switch to permanent pass-through (per-scan
             # selectivity is stationary — one decision suffices). The sync
             # below runs once per stream, not per page — and through numpy,
             # so the decision compiles no throwaway XLA kernels.
-            import numpy as np
-
             mask_np = np.asarray(page.mask)  # prestocheck: ignore[host-sync]
             if mask_np.mean() > self.PASSTHROUGH_SELECTIVITY:
                 self._mode = "pass"
-                self._pending.append(page)
-                return
-            self._mode = "pack"
-            self._first_count = int(mask_np.sum())
+            else:
+                self._mode = "pack"
+                self._first_count = int(mask_np.sum())
+        pack = self._mode == "pack"
+        # the one place a page is counted, and whether it went through
+        # block._compact (pack) or straight on (pass)
+        METRICS.count_many({"pages": 1, "packed_pages": int(pack)},
+                           prefix="coalesce.")
+        if not pack:
+            self._pending.append(page)
+            return
         compacted = _compact(page)
         if self._acc is not None and \
                 self._acc.capacity != compacted.capacity:
@@ -136,8 +141,6 @@ class CoalesceOperator(Operator):
             # host int (counted during the mode decision) — _pack takes it
             # as a traced argument either way, and the eager jnp.sum here
             # compiled two throwaway kernels per schema
-            import numpy as np
-
             count = getattr(self, "_first_count", None)
             if count is None:  # capacity-change restart mid-stream
                 count = int(np.asarray(  # prestocheck: ignore[host-sync]

@@ -67,3 +67,58 @@ def test_compact_full_capacity():
     c = page.compact()
     assert c.size() == 8
     assert [r[0] for r in c.to_pylists()] == list(range(8))
+
+
+def _compact_case(kind, nulls, live, cap):
+    """A page of one `kind` column whose every slot holds something a leak
+    would show (no zero, no False at row 0), and the mask `live` names."""
+    rng = np.random.default_rng(cap * 31 + len(kind) + len(live))
+    rows = np.arange(cap)
+    dictionary = None
+    if kind == "dictionary":
+        dictionary = Dictionary([f"v{i}" for i in range(8)])
+        type_, data = VARCHAR, (rows % 7 + 1).astype(np.int32)
+    elif kind == "bool":
+        type_, data = BOOLEAN, rows % 3 != 1
+    else:
+        type_ = {"int32": INTEGER, "int64": BIGINT, "float64": DOUBLE}[kind]
+        data = ((rows + 1) * 7 * (2 ** 33 if kind == "int64" else 1) *
+                (0.37 if kind == "float64" else 1)).astype(type_.np_dtype)
+    null_mask = None
+    if nulls:
+        null_mask = rng.random(cap) < 0.3
+        null_mask[0] = True
+    mask = {"all": np.ones(cap, dtype=bool), "none": np.zeros(cap, dtype=bool),
+            "one": rows == cap // 2, "random": rng.random(cap) < 0.4}[live]
+    return Page((Block(type_, data, null_mask, dictionary),), mask)
+
+
+@pytest.mark.parametrize("cap", [1, 16, 4096])
+@pytest.mark.parametrize("live", ["all", "none", "one", "random"])
+@pytest.mark.parametrize("nulls", [False, True], ids=["no_nulls", "nulls"])
+@pytest.mark.parametrize("kind", ["int32", "int64", "float64", "bool",
+                                  "dictionary"])
+def test_compact_equals_numpy_reference(kind, nulls, live, cap):
+    """Page.compact() against plain numpy: live rows in order, then zeros /
+    False, mask a prefix — bit for bit, with type and dictionary kept."""
+    page = _compact_case(kind, nulls, live, cap)
+    block = page.blocks[0]
+    keep = np.flatnonzero(page.mask)
+
+    def packed(a):
+        out = np.zeros_like(np.asarray(a))
+        out[:len(keep)] = np.asarray(a)[keep]
+        return out
+
+    got = page.compact()
+    out = got.blocks[0]
+    assert out.type is block.type and out.dictionary is block.dictionary
+    assert np.asarray(out.data).dtype == np.asarray(block.data).dtype
+    # bytes, not values: -0.0 or a NaN payload would not slip through
+    assert np.asarray(out.data).tobytes() == packed(block.data).tobytes()
+    if nulls:
+        assert np.array_equal(np.asarray(out.nulls), packed(block.nulls))
+    else:
+        assert out.nulls is None
+    assert np.array_equal(np.asarray(got.mask), np.arange(cap) < len(keep))
+    assert got.size() == len(keep)
