@@ -6,8 +6,8 @@ metric out of the line. A share of a peak is never reported as 0.
 window: {"walls", "completed", "before"/"after" (the program's raw
 counters), "xla_compiles", "trace" (trace_reduce.reduce or None),
 "memory_peak_bytes", "least_s" (sum over completed queries of bytes their SQL
-must read once over the chip's peak bytes/s; the traced queries alone where
-there is a trace)}
+must read once over the peak bytes/s of the cell's chips together,
+run.least_seconds; the traced queries alone where there is a trace)}
 """
 
 

@@ -1,4 +1,5 @@
-"""The benchmark's own copy of the TPC-H column formulas the three queries read.
+"""The benchmark's own copy of the TPC-H column formulas its queries read:
+what Q1, Q3 and Q6 name, and what Q5 and Q9 will (all eight tables).
 
 The plain reference computes over THESE columns and imports nothing of the
 program: every value is a pure function of (table, column, row index) through a
@@ -9,13 +10,33 @@ matching the reference, which is the point of keeping the copy here.
 `benchmark/tests/test_yardstick.py` holds the two equal at schema `tiny`.
 
 Decimals are integers scaled by 100 (cents); dates are days since 1970-01-01;
-dictionary columns are codes into the lists below.
+dictionary columns are codes into the lists below (`n_name` and `r_name` are
+the row's own key). `p_name` is five words of COLORS: an (n, 5) array of their
+indices, so a reference decides `like '%green%'` from the words themselves.
 """
 import numpy as np
 
 RETURNFLAGS = ["A", "N", "R"]
 LINESTATUSES = ["F", "O"]
 SEGMENTS = ["AUTOMOBILE", "BUILDING", "FURNITURE", "MACHINERY", "HOUSEHOLD"]
+REGIONS = ["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"]
+NATIONS = [  # (n_name, n_regionkey), clause 4.2.3
+    ("ALGERIA", 0), ("ARGENTINA", 1), ("BRAZIL", 1), ("CANADA", 1), ("EGYPT", 4),
+    ("ETHIOPIA", 0), ("FRANCE", 3), ("GERMANY", 3), ("INDIA", 2), ("INDONESIA", 2),
+    ("IRAN", 4), ("IRAQ", 4), ("JAPAN", 2), ("JORDAN", 4), ("KENYA", 0),
+    ("MOROCCO", 0), ("MOZAMBIQUE", 0), ("PERU", 1), ("CHINA", 2), ("ROMANIA", 3),
+    ("SAUDI ARABIA", 4), ("VIETNAM", 2), ("RUSSIA", 3), ("UNITED KINGDOM", 3),
+    ("UNITED STATES", 1)]
+COLORS = (  # the 92 words of P_NAME, clause 4.2.3
+    "almond antique aquamarine azure beige bisque black blanched blue blush brown "
+    "burlywood burnished chartreuse chiffon chocolate coral cornflower cornsilk cream "
+    "cyan dark deep dim dodger drab firebrick floral forest frosted gainsboro ghost "
+    "goldenrod green grey honeydew hot indian ivory khaki lace lavender lawn lemon "
+    "light lime linen magenta maroon medium metallic midnight mint misty moccasin "
+    "navajo navy olive orange orchid pale papaya peach peru pink plum powder puff "
+    "purple red rose rosy royal saddle salmon sandy seashell sienna sky slate smoke "
+    "snow spring steel tan thistle tomato turquoise violet wheat white yellow").split()
+P_NAME_WORDS = 5
 
 MIN_DATE = 8035          # 1992-01-01
 MAX_ORDER_DATE = 10440   # 1998-08-02
@@ -53,15 +74,24 @@ def _orderdate(order_idx):
 
 
 def orders_count(sf):
-    return int(sf * 1_500_000)
+    return row_count("orders", sf)
+
+
+# clause 4.2.5: rows at SF1 (partsupp is four suppliers a part); nation and
+# region do not scale; lineitem is the sum of its orders' line counts
+ROWS_AT_SF1 = {"part": 200_000, "supplier": 10_000, "customer": 150_000,
+               "orders": 1_500_000}
+FIXED_ROWS = {"nation": 25, "region": 5}
 
 
 def row_count(table, sf):
     """Rows of `table` at scale `sf` (lineitem: the exact sum of the lines)."""
-    if table == "orders":
-        return orders_count(sf)
-    if table == "customer":
-        return int(sf * 150_000)
+    if table in FIXED_ROWS:
+        return FIXED_ROWS[table]
+    if table in ROWS_AT_SF1:
+        return int(sf * ROWS_AT_SF1[table])
+    if table == "partsupp":
+        return 4 * row_count("part", sf)
     if table == "lineitem":
         total, step = 0, 4_000_000
         for lo in range(0, orders_count(sf), step):
@@ -69,6 +99,12 @@ def row_count(table, sf):
             total += int(_line_count(np.arange(lo, hi, dtype=np.int64)).sum())
         return total
     raise KeyError(table)
+
+
+def _supplier_for(partkey, j, sf):
+    """The j-th (0-3) of a part's four suppliers, clause 4.2.3."""
+    s = row_count("supplier", sf)
+    return (partkey + j * (s // 4 + (partkey - 1) // s)) % s + 1
 
 
 def lineitem(order_lo, order_hi, sf, columns):
@@ -84,14 +120,20 @@ def lineitem(order_lo, order_hi, sf, columns):
         shipdate = _orderdate(o_rep) + _uniform(7, 10, lkey, 1, 121)
     if "l_returnflag" in columns:
         receipt = shipdate + _uniform(7, 9, lkey, 1, 30)
+    pk = None
+    if {"l_partkey", "l_suppkey", "l_extendedprice"} & set(columns):
+        pk = _uniform(7, 2, lkey, 1, row_count("part", sf))
     out = {}
     for name in columns:
         if name == "l_orderkey":
             out[name] = _order_key(o_rep)
+        elif name == "l_partkey":
+            out[name] = pk
+        elif name == "l_suppkey":
+            out[name] = _supplier_for(pk, _uniform(7, 3, lkey, 0, 3), sf)
         elif name == "l_quantity":
             out[name] = _uniform(7, 4, lkey, 1, 50) * 100
         elif name == "l_extendedprice":
-            pk = _uniform(7, 2, lkey, 1, int(sf * 200_000))
             retail = 90000 + ((pk // 10) % 20001) + 100 * (pk % 1000)
             out[name] = _uniform(7, 4, lkey, 1, 50) * retail
         elif name == "l_discount":
@@ -111,36 +153,64 @@ def lineitem(order_lo, order_hi, sf, columns):
     return out
 
 
-def orders(lo, hi, sf, columns):
+def _o_custkey(i, sf):
+    c = row_count("customer", sf)
+    k = _uniform(6, 1, i, 0, max(c - c // 3, 1) - 1)
+    return k // 2 * 3 + k % 2 + 1
+
+
+def _p_name(i, sf):
+    return np.stack([_uniform(3, 16 + f, i, 0, len(COLORS) - 1)
+                     for f in range(P_NAME_WORDS)], axis=1)
+
+
+_NATION_REGION = np.asarray([r for _n, r in NATIONS], dtype=np.int64)
+
+# {table: {column: (row index i from 0, sf) -> int64 array}} of every table
+# whose rows are a function of their index alone (lineitem hangs on its order)
+COLUMNS = {
+    "orders": {
+        "o_orderkey": lambda i, sf: _order_key(i),
+        "o_custkey": _o_custkey,
+        "o_orderdate": lambda i, sf: _orderdate(i),
+        "o_shippriority": lambda i, sf: np.zeros(len(i), dtype=np.int64)},
+    "customer": {
+        "c_custkey": lambda i, sf: i + 1,
+        "c_nationkey": lambda i, sf: _uniform(5, 3, i, 0, 24),
+        "c_mktsegment": lambda i, sf: _uniform(5, 6, i, 0, 4)},
+    "part": {
+        "p_partkey": lambda i, sf: i + 1,
+        "p_name": _p_name},
+    "supplier": {
+        "s_suppkey": lambda i, sf: i + 1,
+        "s_nationkey": lambda i, sf: _uniform(2, 3, i, 0, 24)},
+    "partsupp": {
+        "ps_partkey": lambda i, sf: i // 4 + 1,
+        "ps_suppkey": lambda i, sf: _supplier_for(i // 4 + 1, i % 4, sf),
+        "ps_supplycost": lambda i, sf: _uniform(4, 3, i, 100, 100000)},
+    "nation": {
+        "n_nationkey": lambda i, sf: i,
+        "n_name": lambda i, sf: i,
+        "n_regionkey": lambda i, sf: _NATION_REGION[i]},
+    "region": {
+        "r_regionkey": lambda i, sf: i,
+        "r_name": lambda i, sf: i},
+}
+
+
+def rows(table, lo, hi, sf, columns):
+    """Rows [lo, hi) of a table of COLUMNS: {column: int64 array}. A column
+    with no formula here is a KeyError: copy it from the generator first."""
     i = np.arange(lo, hi, dtype=np.int64)
-    out = {}
-    for name in columns:
-        if name == "o_orderkey":
-            out[name] = _order_key(i)
-        elif name == "o_custkey":
-            c = int(sf * 150_000)
-            k = _uniform(6, 1, i, 0, max(c - c // 3, 1) - 1)
-            out[name] = k // 2 * 3 + k % 2 + 1
-        elif name == "o_orderdate":
-            out[name] = _orderdate(i)
-        elif name == "o_shippriority":
-            out[name] = np.zeros(len(i), dtype=np.int64)
-        else:
-            raise KeyError(name)
-    return out
+    return {name: COLUMNS[table][name](i, sf) for name in columns}
+
+
+def orders(lo, hi, sf, columns):
+    return rows("orders", lo, hi, sf, columns)
 
 
 def customer(lo, hi, sf, columns):
-    i = np.arange(lo, hi, dtype=np.int64)
-    out = {}
-    for name in columns:
-        if name == "c_custkey":
-            out[name] = i + 1
-        elif name == "c_mktsegment":
-            out[name] = _uniform(5, 6, i, 0, 4)
-        else:
-            raise KeyError(name)
-    return out
+    return rows("customer", lo, hi, sf, columns)
 
 
 def order_blocks(sf, block=500_000):

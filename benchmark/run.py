@@ -57,6 +57,13 @@ def peaks_for(kind):
     return table[kind]
 
 
+def least_seconds(nbytes, kind, chips):
+    """The least time the cell's chips need to read `nbytes` once: the tables
+    are dealt over the chips, so the peak is `chips` times one chip's. It
+    stands over `busy_s`, a chip's mean (trace_reduce.reduce)."""
+    return nbytes / (chips * peaks_for(kind)["hbm_bytes_per_s"])
+
+
 def scanned(queries, sf):
     """{query: (rows, logical bytes) its SQL must read once at this scale}"""
     widths = cells.load_json(cells.BENCH_DIR, "harness", "logical_widths.json")
@@ -75,7 +82,8 @@ def scanned(queries, sf):
 
 def warm_up(served, plan, watch):
     """Each query of the mix until a run builds nothing (tables resident,
-    every program compiled or fetched from the cache)."""
+    every program compiled or fetched from the cache), WARMUP_RUNS[1] runs at
+    the most: run_cell refuses to measure a query that never got there."""
     from benchmark.harness.served import ask, counters
 
     def built_so_far():
@@ -96,6 +104,12 @@ def warm_up(served, plan, watch):
     return log
 
 
+def never_warmed(log):
+    """The queries whose last warm-up run still built a program."""
+    last = {w["query"]: w["built"] for w in log}
+    return sorted(q for q, built in last.items() if built)
+
+
 def run_cell(workload, seed, seconds, trace, t0=None, need_chips=True,
              scale=None):
     """-> the result object. `scale` ({"schema", "scale_factor"}) and
@@ -114,6 +128,11 @@ def run_cell(workload, seed, seconds, trace, t0=None, need_chips=True,
     served = sv.Served(config)
     try:
         warm = warm_up(served, plan, watch)
+        stuck = never_warmed(warm)
+        if stuck:   # a window opened now would be timed with compiles in it
+            sys.exit(f"benchmark: {stuck} still built programs "
+                     f"in warm-up run {WARMUP_RUNS[1]}, the last - nothing "
+                     f"was measured. The warm-up's log: {json.dumps(warm)}")
         min_queries = 0
         if trace:
             seconds = min(seconds, cell.traffic.get("trace_seconds", 5))
@@ -159,7 +178,7 @@ def run_cell(workload, seed, seconds, trace, t0=None, need_chips=True,
               "before": before, "after": after,
               "xla_compiles": xla_in_window, "trace": reduced,
               "memory_peak_bytes": peak,
-              "least_s": least_bytes / peaks_for(kind)["hbm_bytes_per_s"]
+              "least_s": least_seconds(least_bytes, kind, cell.chips)
               if need_chips else None}
 
     values = {"setup_s": setup_s}

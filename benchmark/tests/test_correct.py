@@ -80,6 +80,30 @@ def test_a_part_of_the_table_left_out_is_not_correct(workload, monkeypatch):
     assert not r["correct"] and r["failed"] == r["attempted"] >= 1
 
 
+def test_a_cell_that_never_warms_is_not_measured(monkeypatch, capsys):
+    """The last warm-up run still built a program: the window would be timed
+    with compiles in it, so the run ends non-zero and prints no result."""
+    from benchmark import run
+    from benchmark.harness import served
+
+    log = [{"query": "q6", "wall_s": 1.0, "built": 3},
+           {"query": "q6", "wall_s": 0.9, "built": 1}]
+    assert run.never_warmed(log) == ["q6"]
+    assert run.never_warmed(log + [{"query": "q6", "wall_s": 0.1, "built": 0}]) == []
+    assert run.never_warmed([]) == []
+    monkeypatch.setattr(run, "warm_up", lambda served, plan, watch: log)
+
+    def no_window(*a, **kw):
+        raise AssertionError("the window was opened")
+
+    monkeypatch.setattr(served, "run_window", no_window)
+    with pytest.raises(SystemExit) as e:
+        run.run_cell("q6_sf1", 5, 0.5, False, need_chips=False, scale=TINY)
+    assert e.value.code not in (0, None) and "still built" in str(e.value.code)
+    assert '"built": 1' in str(e.value.code)       # the log goes with it
+    assert capsys.readouterr().out == ""
+
+
 def test_a_query_that_raises_counts_as_failed(monkeypatch):
     from presto_tpu.client import dbapi
 

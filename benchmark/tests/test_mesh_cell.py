@@ -2,6 +2,8 @@
 `"reader": "file"` metrics of the layer "mesh exchange" against hand-built
 event lists, and whole runs of the harness at schema `tiny` on the virtual
 devices conftest.py asks for, sound and with one worker's split dropped.
+Beside them, which per-layer metrics each cell reads: the shared ones name no
+cell, so `q1_sf10` and every later cell read them without an edit.
 
     JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q
 """
@@ -20,6 +22,13 @@ from benchmark.run import run_cell  # noqa: E402
 
 CELL = "q3_sf1_mesh4"
 MS = 1_000_000
+EXCHANGE = {"exchange_chunks_per_query", "exchange_stall_s",
+            "exchange_dispatch_s", "collective_s_per_query",
+            "exchange_ici_roofline"}
+SHARED = {"device_idle_pct", "window_compiles", "device_programs_per_query",
+          "driver_idle_s", "peak_hbm_bytes", "scan_roofline",
+          "idle_unattributed_pct", "protocol_overhead_s", "queue_wait_s",
+          "result_wait_s", "plan_s"}
 
 # what a v5e trace names its `XLA Ops` events: the HLO instruction's text
 ALL_TO_ALL = ("%all_to_all.73 = u32[4,1,2048]{2,1,0:T(1,128)S(1)} all-to-all("
@@ -163,9 +172,57 @@ def test_the_mesh_cell_resolves_to_the_distributed_runner_on_four_chips():
     assert cell.config["guarantees"] == cells.Cell("q3_sf1").config["guarantees"]
     assert sorted(m["name"] for m in cell.metrics("end_to_end")) == \
         ["rows_per_s", "setup_s"]
-    assert {m["name"] for m in cell.metrics("per_layer")} == {
-        "exchange_chunks_per_query", "exchange_stall_s", "exchange_dispatch_s",
-        "collective_s_per_query", "exchange_ici_roofline"}
+    assert {m["name"] for m in cell.metrics("per_layer")} == EXCHANGE | SHARED | {
+        "segment_dispatches_per_query", "dense_join_builds_per_query"}
+    # not `coalesce_packed_pages_per_query`: a PARTITIONED join's children are
+    # exchange sources, so no CoalesceOperator is planned and nothing counts
+
+
+def test_the_shared_metrics_name_no_cell_so_a_later_cell_edits_no_entry():
+    """A per-layer entry without a `workloads` key is every cell's
+    (`cells.Cell.metrics`), those of later PRs too: a new cell brings its own
+    `workloads` entry and touches none that is there."""
+    bench = cells.load_json(cells.ROOT, "BENCHMARK.json")
+    by_name = {m["name"]: m for m in bench["per_layer"]}
+    for name in SHARED:
+        assert "workloads" not in by_name[name], name
+    known = {w["name"] for w in bench["workloads"]}
+    for name in set(by_name) - SHARED:     # the others say where they read
+        assert set(by_name[name]["workloads"]) <= known and \
+            by_name[name]["workloads"], name
+    for w in known:
+        assert SHARED <= {m["name"] for m in
+                          cells.Cell(w).metrics("per_layer")}, w
+
+
+def test_q1_sf10_is_q1_sf1s_traffic_under_the_sf10_deployment():
+    cell, pair = cells.Cell("q1_sf10"), cells.Cell("q1_sf1")
+    assert cell.chips == 1 and cell.config["name"] == "tpch-sf10-1chip"
+    assert cell.config["scale_factor"] == 10.0
+    assert cell.traffic == pair.traffic and set(cell.queries) == {"q1"}
+    # the deployment's file names the part of the source that defines the cell
+    assert "2.4.1.3" in cell.config["source"]
+    assert sorted(m["name"] for m in cell.metrics("end_to_end")) == \
+        ["rows_per_s", "setup_s"]
+    assert {m["name"] for m in cell.metrics("per_layer")} == \
+        SHARED | {"dense_reduce_pages_per_query"} == \
+        {m["name"] for m in pair.metrics("per_layer")}
+
+
+def test_the_scan_roofline_of_four_chips_stands_on_four_chips_peak():
+    from benchmark import run
+    from benchmark.harness import readers
+
+    one = run.least_seconds(819e9, "TPU v5 lite", 1)
+    assert one == pytest.approx(1.0)
+    assert run.least_seconds(819e9, "TPU v5 lite", 4) == pytest.approx(one / 4)
+    with pytest.raises(KeyError):
+        run.least_seconds(1e9, "cpu", 4)
+    # busy_s is a chip's mean: four chips that each read their quarter at the
+    # peak are at 100%, not at 400
+    window = {"trace": {"busy_s": one / 4}, "least_s": run.least_seconds(
+        819e9, "TPU v5 lite", 4)}
+    assert readers.scan_roofline({}, window) == pytest.approx(100.0)
 
 
 def test_a_sound_mesh_run_is_correct_and_prints_the_exchange_metrics():
@@ -181,6 +238,15 @@ def test_a_sound_mesh_run_is_correct_and_prints_the_exchange_metrics():
     assert m["exchange_stall_s"] > 0 and m["exchange_dispatch_s"] > 0
     assert m["collective_s_per_query"] > 0     # CPU stand-in events: a path
     assert "exchange_ici_roofline" not in m    # no ICI on a CPU
+    # the shared readings and the operators' counters, since PR 33; a CPU has
+    # no peak to stand on, no `XLA Modules` line and no memory_stats
+    on_chip_only = {"scan_roofline", "device_programs_per_query",
+                    "peak_hbm_bytes"}
+    assert SHARED - on_chip_only <= set(m) and not on_chip_only & set(m)
+    assert m["window_compiles"] == 0
+    assert m["dense_join_builds_per_query"] == 8     # 2 joins x 4 workers
+    assert m["segment_dispatches_per_query"] >= 4
+    assert m["driver_idle_s"] > 0 and m["plan_s"] > 0
     assert any(collectives.opcode(name)
                for name, _s in r["breakdown"]["device_ops"])
     e2e = run_cell(CELL, 2**31 + 99, 0.5, False, need_chips=False, scale=TINY)
