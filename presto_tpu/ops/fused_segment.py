@@ -57,8 +57,8 @@ from .hash_agg import (DirectAggregationBuilder, GlobalAggregationBuilder,
                        GroupedAggregationBuilder,
                        HashAggregationOperatorFactory, _builder_key)
 from .hash_join import (LookupJoinOperatorFactory, apply_probe_stage,
-                        probe_plan_fusible, probe_stage_aux, probe_stage_cfg,
-                        probe_stage_key)
+                        count_probe_pages, probe_plan_fusible,
+                        probe_stage_aux, probe_stage_cfg, probe_stage_key)
 from .operator import Operator, OperatorContext, OperatorFactory, timed
 from .topn import TopNOperatorFactory, topn_merge_stage
 
@@ -435,6 +435,10 @@ class FusedSegmentOperator(Operator):
     def close(self) -> None:
         if self._pages:
             self.f.note_pages(self._pages)
+            # every page went through every probe stage, whose `kind` is
+            # that of the live build
+            count_probe_pages([st["source"] for st in self._stages
+                               if st["source"] is not None], self._pages)
             self._pages = 0
         if self._terminal is not None:
             self._terminal.op.close()

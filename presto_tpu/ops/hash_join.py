@@ -10,16 +10,26 @@ structure is one of:
 
 1. DENSE — build keys scattered into a dense int32 row-index table over the key
    domain [min, min + pow2 bucket of the range); probing is ONE gather. Every
-   TPC-H dimension join (custkey, orderkey, partkey, suppkey) is a dense-PK join,
-   so this is the common fast path. Chosen at BUILD time, from the data (since
-   PR 30): a unique single-key integer build whose table stays within
-   DENSE_JOIN_MAX_TABLE_BYTES takes it (`JoinBuildOperator._dense_plan` reads
-   the live keys' min, max and count in one host sync); nothing sets it.
+   single-column TPC-H dimension join (custkey, orderkey, partkey, suppkey,
+   nationkey) is a dense-PK join, so this is the common fast path. Chosen at
+   BUILD time, from the data (since PR 30): a unique single-key integer build
+   whose table stays within DENSE_JOIN_MAX_TABLE_BYTES takes it
+   (`JoinBuildOperator._dense_plan` reads the live keys' min, max and count in
+   one host sync); nothing sets it.
 2. SORTED — build rows sorted by 64-bit key; probe via vectorized binary search
    (jnp.searchsorted over the sorted key array). Handles duplicate build keys via
-   [lo,hi) ranges and arbitrary key domains; multi-column keys go through a 64-bit
-   mix with post-match verification on the true key columns (collisions only mask
-   rows, never corrupt results).
+   [lo,hi) ranges and arbitrary key domains; multi-column keys are packed
+   bijectively into the 64 bits where their ranges fit (`_plan_packing`), else go
+   through a 64-bit mix with post-match verification on the true key columns
+   (collisions only mask rows, never corrupt results). The one TPC-H join that
+   takes this form is the two-column one: partsupp on (ps_partkey, ps_suppkey),
+   probed by lineitem in Q9 - a direct-address table over the packed pair
+   would have 2^32 slots at SF1.
+
+What a build became and what a probe page searched is counted: `join.builds`
+with `.dense`, `.sorted` and `.multikey` beside it, `join.probe.pages` with
+`.sorted_pages`, and the build's host seconds as the span `join.build` and the
+histogram `join.build_s` (`/v1/metrics`).
 
 Those two are all there is, and no option selects between them.
 
@@ -36,6 +46,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import threading
+import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
 import jax
@@ -45,6 +56,7 @@ import numpy as np
 from ..block import Block, Dictionary, Page
 from ..exec.spill import storage_type_for
 from ..types import BIGINT, Type
+from ..utils import trace
 from ..utils.metrics import METRICS
 from .operator import Operator, OperatorContext, OperatorFactory, timed
 from .sorting import lexsort_fast
@@ -650,9 +662,18 @@ class JoinBuildOperatorFactory(OperatorFactory):
                     else (op._saw_null_key | o._saw_null_key)
             o._pages, o._host_pages, o._null_key_pages = [], [], []
             o._disk_runs = []
-        src = op._build()
+        keys = len(self.key_channels)
+        t0 = time.perf_counter()
+        # the build's host reads (dense_table_range, _plan_packing) lie inside
+        with trace.span(trace.JOIN, "build", keys=keys,
+                        pages=len(op._pages) + len(op._host_pages)) as built:
+            src = op._build()
+            built.note(kind=src.kind)
+        METRICS.histogram("join.build_s", time.perf_counter() - t0)
         METRICS.count_many({"builds": 1,
-                            "builds.dense": int(src.kind == "dense")},
+                            "builds.dense": int(src.kind == "dense"),
+                            "builds.sorted": int(src.kind == "sorted"),
+                            "builds.multikey": int(keys > 1)},
                            prefix="join.")
         self.lookup_factory.set(src, w)
         op._pages = []  # consumed into the lookup source
@@ -790,6 +811,15 @@ def probe_stage_kernel(cfg: ProbeStageConfig):
 # probe
 # ---------------------------------------------------------------------------
 
+def count_probe_pages(sources: Sequence[LookupSource], pages: int = 1) -> None:
+    """`pages` probe pages went through each of `sources`: once a page and a
+    probe, whether it ran in LookupJoinOperator or as a fused segment's stage."""
+    METRICS.count_many(
+        {"pages": pages * len(sources),
+         "sorted_pages": pages * sum(s.kind == "sorted" for s in sources)},
+        prefix="join.probe.")
+
+
 def probe_match_dense(source_table, base, probe_keys, probe_mask):
     """DENSE unique build: one gather -> build row per probe row (-1 = no
     match). The offset and its range test are 64-bit and the cast comes
@@ -868,6 +898,7 @@ class LookupJoinOperator(Operator):
                 "probe received input before build finished"
             self._source = self.f.lookup_factory.get(w)
         src = self._source
+        count_probe_pages((src,))
         probe_keys = [page.blocks[c].data for c in self.f.probe_key_channels]
         probe_mask = page.mask
         for c in self.f.probe_key_channels:

@@ -53,6 +53,7 @@ Categories — one per instrumented subsystem:
   scan       scan-pipeline read/decode/upload stage work + compute stalls
   exchange   streaming-exchange chunk dispatch/delivery + pump stalls
   kernel     kernel-cache misses (jit closure builds)
+  join       a join build publishing its lookup source (ops/hash_join.py)
   http       cluster task create/poll and exchange pulls
   pool       shared-pool generator steps (exec/shared_pools.py)
   protocol   queued / serialize / result_wait (server/protocol.py; profiler
@@ -78,6 +79,7 @@ SEGMENT = "segment"
 SCAN = "scan"
 EXCHANGE = "exchange"
 KERNEL = "kernel"
+JOIN = "join"
 HTTP = "http"
 POOL = "pool"
 

@@ -172,6 +172,23 @@ def test_probe_and_partial_agg_segment_compiles(one_chip, programs):
     _compile(one_chip, fn, args, kwargs)
 
 
+def test_sorted_two_key_probe_compiles(one_chip):
+    """Q9's partsupp probe (PR 34), the one join no direct-address table
+    serves: lineitem's two key columns packed into the build's 64-bit key
+    space, then a binary search of a 2^20-row page in the build's 2^20 sorted
+    keys and the check against the true key columns. Shapes as the cell
+    `q9_sf1` dispatches them; the programs are the operator's own."""
+    from presto_tpu.ops import hash_join
+
+    n = 1 << 20
+    i64 = jax.ShapeDtypeStruct((n,), np.int64)
+    plan = jax.ShapeDtypeStruct((2,), np.int64)
+    _compile(one_chip, hash_join._pack_key, ((i64, i64), plan, plan, plan), {})
+    _compile(one_chip, hash_join._probe_match_sorted_unique,
+             (i64, jax.ShapeDtypeStruct((n,), np.int32), i64, (i64, i64),
+              jax.ShapeDtypeStruct((n,), np.bool_), (i64, i64)), {})
+
+
 def test_topn_compiles(one_chip, programs):
     merges = programs.named("topn_merge_stage")
     assert merges
