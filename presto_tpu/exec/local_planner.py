@@ -31,7 +31,7 @@ from ..ops.expressions import (Constant, InputLayout, RowExpression, SymbolRef,
 from ..ops.filter_project import FilterProjectOperatorFactory, PageProcessor
 from ..ops.hash_agg import SINGLE, HashAggregationOperatorFactory
 from ..ops.hash_join import (ANTI, FULL, INNER, LEFT, SEMI, JoinBuildOperatorFactory,
-                             LookupJoinOperatorFactory)
+                             LookupJoinOperatorFactory, probe_plan_fusible)
 from ..ops.scan import TableScanOperatorFactory
 from ..ops.single_row import EnforceSingleRowOperatorFactory
 from ..ops.topn import (LimitOperatorFactory, OrderByOperatorFactory, SortOrder,
@@ -727,17 +727,26 @@ class LocalExecutionPlanner:
 
     # ------------------------------------------------------------- joins
 
-    def _maybe_coalesce(self, chain: Chain) -> Chain:
-        """Insert a page-coalescing stage when the chain ends in a FILTERED
-        scan feeding a join: the join's per-page kernel work (and its
-        per-page dispatches) then scales with the filter's
-        survivors instead of the scanned capacity. The operator itself
-        adapts at runtime — an unselective filter switches it to permanent
-        pass-through after the first page (ops/coalesce.py)."""
+    def _maybe_coalesce(self, chain: Chain,
+                        feeds_unfused_probe: bool = False) -> Chain:
+        """Insert a page-coalescing stage where a chain that feeds a join
+        DROPS rows at its end: the join's per-page kernel work (and its
+        per-page dispatches) then scales with the survivors instead of the
+        scanned capacity. Two endings drop rows: a FILTERED scan, on either
+        side of the join; and, with `feeds_unfused_probe` (the join being
+        planned probes alone, so it is a segment barrier anyway), an
+        INNER/SEMI/ANTI probe. A fusible probe gets none behind another
+        probe: it would cut their fused segment in two. The operator itself
+        adapts at runtime — a stream whose first page is mostly live
+        switches it to permanent pass-through (ops/coalesce.py)."""
         if not chain.factories:
             return chain
         last = chain.factories[-1]
-        if not getattr(last, "has_filter", False):
+        drops_rows = getattr(last, "has_filter", False) or (
+            feeds_unfused_probe
+            and isinstance(last, LookupJoinOperatorFactory)
+            and last.join_type in (INNER, SEMI, ANTI))
+        if not drops_rows:
             return chain
         from ..ops.coalesce import CoalesceOperatorFactory
 
@@ -749,11 +758,15 @@ class LocalExecutionPlanner:
     def visit_JoinNode(self, node: JoinNode) -> Chain:
         if not node.criteria:
             return self._plan_cross_join(node)
-        probe_chain = self._maybe_coalesce(self.visit(node.left))
-        build_chain = self._maybe_coalesce(self.visit(node.right))
-
         left_keys = [l for l, _ in node.criteria]
         right_keys = [r for _, r in node.criteria]
+        jt = self._join_type(node)
+        unique = self._keys_unique(node.right, right_keys)
+        probe_chain = self._maybe_coalesce(
+            self.visit(node.left),
+            feeds_unfused_probe=not probe_plan_fusible(jt, left_keys, unique))
+        build_chain = self._maybe_coalesce(self.visit(node.right))
+
         build_key_ch = [build_chain.channel(r.name) for r in right_keys]
         probe_key_ch = [probe_chain.channel(l.name) for l in left_keys]
 
@@ -766,7 +779,6 @@ class LocalExecutionPlanner:
         payload_ch = [build_chain.channel(n) for n in payload_names]
         payload_meta = build_chain.meta(payload_names)
 
-        unique = self._keys_unique(node.right, right_keys)
         build_fac = JoinBuildOperatorFactory(
             next(self._ids), build_key_ch, payload_ch, payload_meta,
             unique=unique,
@@ -775,7 +787,6 @@ class LocalExecutionPlanner:
 
         probe_out_ch = [probe_chain.channel(s.name) for s in probe_out]
         probe_meta = probe_chain.meta([s.name for s in probe_out])
-        jt = self._join_type(node)
         probe_fac = LookupJoinOperatorFactory(
             next(self._ids), build_fac.lookup_factory, probe_key_ch,
             probe_out_ch, probe_meta, list(range(len(payload_ch))),

@@ -1,6 +1,7 @@
 """CoalesceOperator against a plain reference: what comes out is the stream's
 live rows in order, full pages then one tail in pack mode, the pages as they
-came in pass mode, and `coalesce.pages` / `coalesce.packed_pages` say which.
+came in pass mode, and `coalesce.pages` / `coalesce.packed_pages` say which;
+the same decision where the planner puts it behind a join (PR 35).
 """
 import itertools
 
@@ -145,3 +146,21 @@ def test_empty_pages_and_empty_stream():
     out, counts = _counted(lambda: _run(pages))
     assert _live_rows(out) == _live_rows(pages)
     assert len(out) == 1 and counts == (3, 3)
+
+
+def test_behind_a_join_that_keeps_its_rows_it_passes_every_page():
+    """The planner packs an INNER join's survivors ahead of a probe that
+    cannot fuse (two key columns). Whether that pays is the operator's to see:
+    every line has its part, the first page arrives more than half live, and
+    the stream goes through untouched."""
+    from presto_tpu.metadata import Session
+    from presto_tpu.runner import LocalQueryRunner
+
+    runner = LocalQueryRunner(session=Session(catalog="tpch", schema="tiny"))
+    lines = runner.execute("select count(*) from lineitem").rows[0][0]
+    result, (seen, packed) = _counted(lambda: runner.execute(
+        "select count(*) from lineitem, part, partsupp "
+        "where l_partkey = p_partkey "
+        "and ps_partkey = l_partkey and ps_suppkey = l_suppkey"))
+    assert result.rows == [[lines]]
+    assert seen >= 1 and packed == 0

@@ -124,6 +124,34 @@ def test_cbo_broadcasts_small_builds(dist):
     assert "RemoteSource" in lineitem_frag  # joins happen at the probe
 
 
+def test_broadcast_joins_pack_survivors_ahead_of_a_two_column_probe(local):
+    """Both builds replicated, so one fragment holds the whole probe chain:
+    the s1 join keeps a third of partsupp's rows (INNER, one unique key) and
+    feeds a probe on two columns, which cannot fuse, and the local planner
+    puts a Coalesce between them (PR 35). Every worker's stream packs."""
+    from presto_tpu.metadata import Session
+    from presto_tpu.utils.metrics import METRICS
+
+    bcast = DistributedQueryRunner(
+        session=Session(catalog="tpch", schema="tiny",
+                        properties={"join_distribution_type": "BROADCAST"}))
+    sql = ("select count(*), sum(ps_supplycost), max(s2.s_acctbal) "
+           "from partsupp, supplier s1, supplier s2 "
+           "where ps_suppkey = s1.s_suppkey and s1.s_nationkey < 8 "
+           "and s2.s_suppkey = ps_suppkey "
+           "and s2.s_nationkey = s1.s_nationkey")
+    d = check(bcast, local, sql)
+    assert d.rows[0][0] > 0
+    # again on the mesh alone: its one Coalesce is the new one (s1's filtered
+    # scan is a fragment of its own and feeds an exchange, not a join)
+    names = ("coalesce.pages", "coalesce.packed_pages")
+    before = [METRICS.counter_value(n) for n in names]
+    bcast.execute(sql)
+    seen, packed = (METRICS.counter_value(n) - b
+                    for n, b in zip(names, before))
+    assert seen == packed >= 1
+
+
 @pytest.mark.slow
 def test_forced_partitioned_matches_broadcast(local):
     from presto_tpu.metadata import Session

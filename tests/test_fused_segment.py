@@ -136,6 +136,66 @@ def test_single_operator_runs_stay_unfused():
     assert any(d["reason"] == "single-operator run" for d in reasons)
 
 
+# ------------------------------------------- where a Coalesce is placed
+
+# lineitem probes the filtered part (INNER, single unique key: it drops the
+# lines of every other part), then one more table
+_GREEN_LINES = ("select count(*), sum({value}) from lineitem, part, {table} "
+                "where l_partkey = p_partkey and p_name like '%green%' "
+                "and {on}")
+_THEN_PARTSUPP = _GREEN_LINES.format(
+    value="ps_supplycost", table="partsupp",
+    on="ps_partkey = l_partkey and ps_suppkey = l_suppkey")
+_THEN_ORDERS = _GREEN_LINES.format(
+    value="o_totalprice", table="orders", on="o_orderkey = l_orderkey")
+
+
+def _pipelines(runner, sql):
+    _segs, exec_plan = _segments(runner, sql)
+    return [[f.name for f in chain] for chain in exec_plan.pipelines]
+
+
+@pytest.mark.parametrize("fusion", [True, False])
+def test_a_join_that_feeds_a_two_column_probe_gets_a_coalesce(fusion):
+    """The partsupp probe has two key columns: it cannot fuse and pays for
+    every page at full capacity, so what the part join kept is packed."""
+    *builds, probe = _pipelines(_runner(segment_fusion=fusion), _THEN_PARTSUPP)
+    assert builds == [["TableScan", "Coalesce", "JoinBuild"],
+                      ["TableScan", "JoinBuild"]]
+    assert probe == ["TableScan", "LookupJoin(inner)", "Coalesce",
+                     "LookupJoin(inner)", "HashAggregation(single)",
+                     "PageConsumer"]
+
+
+def test_a_join_that_feeds_a_fusible_probe_gets_none():
+    """The orders probe has one unique key: a Coalesce would cut the fused
+    segment in two, so the probe side gets none and stays one segment."""
+    segs, exec_plan = _segments(_runner(), _THEN_ORDERS)
+    assert [s.member_names for s in segs] == [
+        ["LookupJoin(inner)", "LookupJoin(inner)", "HashAggregation(single)"]]
+    assert [f.name for f in exec_plan.pipelines[-1]] == \
+        ["TableScan", segs[0].name, "PageConsumer"]
+    assert _pipelines(_runner(segment_fusion=False), _THEN_ORDERS)[-1] == \
+        ["TableScan", "LookupJoin(inner)", "LookupJoin(inner)",
+         "HashAggregation(single)", "PageConsumer"]
+    # a green line has one partsupp row and one order: the packed plan and
+    # the fused one count the same lines
+    assert _runner().execute(_THEN_PARTSUPP).rows[0][0] == \
+        _runner().execute(_THEN_ORDERS).rows[0][0] > 0
+
+
+def test_q3_plan_is_what_it_was_before_joins_were_packed():
+    """Both of Q3's probes are fusible: its only Coalesces are the ones
+    behind the three filtered scans, and its probe pipeline is one segment."""
+    assert _pipelines(_runner(), QUERIES[3]) == [
+        ["TableScan", "Coalesce", "JoinBuild"],
+        ["TableScan", "Coalesce", "JoinBuild"],
+        ["TableScan", "Coalesce",
+         "FusedSegment[LookupJoin(inner)+LookupJoin(inner)+FilterProject"
+         "+HashAggregation(single)]",
+         "TopN", "FilterProject", "PageConsumer"]]
+
+
 # ------------------------------------------------------------ observability
 
 def test_segment_stats_flow_into_query_result():

@@ -8,9 +8,11 @@ to the benchmark's plain numpy reference (`benchmark/queries/q9.py`, which
 imports nothing of the program). Beside it, what the cell's per-layer metrics
 read: of the query's five join builds four take the direct-address table and
 ONE, partsupp's on two columns, the sorted form; every lineitem page is
-counted once for each of the five probes and once more, as a sorted page, for
-the partsupp probe (the binary search), the same whether the single-key
-probes ran fused or alone; the build has a span and a histogram.
+counted once for each of the three probes ahead of the partsupp probe; a
+`CoalesceOperator` then packs what the part join kept (PR 35), and each page
+it emits is counted once for the partsupp probe, once more as a sorted page
+(the binary search) and once for the orders probe, the same whether the
+single-key probes ran fused or alone; the build has a span and a histogram.
 """
 import json
 import os
@@ -66,11 +68,13 @@ def _join_numbers():
     numbers = dict(raw["counters"])
     numbers["join.build_s.n"] = raw["histograms"].get(
         "join.build_s", {"n": 0})["n"]
+    numbers.update(METRICS.raw_snapshot("coalesce.")["counters"])
     return numbers
 
 
 def _run(sql, **properties):
-    """-> (result, what each `join.*` number gained over the query)"""
+    """-> (result, what each `join.*` and `coalesce.*` number gained over the
+    query)"""
     runner = LocalQueryRunner(session=Session(
         catalog="tpch", schema="tiny", properties=properties))
     before = _join_numbers()
@@ -81,15 +85,16 @@ def _run(sql, **properties):
 
 @pytest.fixture(scope="module")
 def probe_pages(q9):
-    """Pages of lineitem a query at `tiny` scans: what each of the two fused
-    segments of the probe pipeline says it dispatched, one a page (nothing
-    packs the pages between them)."""
+    """-> (pages of lineitem a query at `tiny` scans, pages the partsupp
+    probe sees): what each of the two fused segments of the probe pipeline
+    says it dispatched, one a page. A `CoalesceOperator` packs the part
+    join's survivors between them, so the second sees fewer."""
     result, _gained = _run(q9.template.format(color="green"))
     first, second = result.stats["segments"]["segments"]
     assert first["operators"] == ["LookupJoin(inner)"] * 3
     assert second["operators"][-1] == "HashAggregation(single)"
-    assert first["dispatches"] == second["dispatches"] >= 1
-    return first["dispatches"]
+    assert first["dispatches"] > second["dispatches"] >= 1
+    return first["dispatches"], second["dispatches"]
 
 
 @pytest.mark.parametrize("colour", COLOURS)
@@ -119,9 +124,10 @@ def test_the_reference_imports_nothing_of_the_program():
 @pytest.mark.parametrize("fused", [True, False])
 def test_one_query_counts_its_builds_and_probe_pages(q9, probe_pages, fused):
     """Five builds, four direct-address and one sorted on two key columns.
-    Every lineitem page is counted once for each of the five probes, and
-    under `join.probe.sorted_pages` for the partsupp probe alone: the same
-    whether the single-key probes ran inside fused segments or as
+    Every lineitem page is counted once for each of the three probes ahead
+    of the Coalesce; every packed page once for the partsupp probe, under
+    `join.probe.sorted_pages` for it alone, and once for the orders probe:
+    the same whether the single-key probes ran inside fused segments or as
     `LookupJoinOperator`s (session property `segment_fusion`)."""
     result, gained = _run(q9.template.format(color="green"),
                           segment_fusion=fused)
@@ -132,24 +138,45 @@ def test_one_query_counts_its_builds_and_probe_pages(q9, probe_pages, fused):
     assert gained["join.builds.dense"] == 4
     assert gained["join.builds.sorted"] == 1
     assert gained["join.builds.multikey"] == 1
-    assert gained["join.probe.pages"] == PROBES * probe_pages
-    assert gained["join.probe.sorted_pages"] == probe_pages
+    scanned, packed = probe_pages
+    assert gained["join.probe.pages"] == 3 * scanned + (PROBES - 3) * packed
+    assert gained["join.probe.sorted_pages"] == packed
     assert gained["join.build_s.n"] == PROBES
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_lineitems_pages_go_through_a_coalesce_in_pack_mode(q9, probe_pages,
+                                                            fused):
+    """Two Coalesces a Q9: one behind part's filtered scan, as in any join
+    on the filtered part, and one behind the part join. The colour keeps a
+    twentieth of the parts and so of the lines: both decide for pack mode on
+    their first page, and every page of lineitem goes through
+    `block._compact`, fused or not."""
+    scanned, _packed = probe_pages
+    _result, parts = _run("select count(*) from partsupp, part where "
+                          "ps_partkey = p_partkey and p_name like '%green%'")
+    assert parts["coalesce.packed_pages"] == parts["coalesce.pages"] >= 1
+    _result, gained = _run(q9.template.format(color="green"),
+                           segment_fusion=fused)
+    assert gained["coalesce.pages"] == parts["coalesce.pages"] + scanned
+    assert gained["coalesce.packed_pages"] == gained["coalesce.pages"]
 
 
 def test_the_two_column_probe_stands_alone_between_two_fused_segments(q9):
     """`probe_plan_fusible` refuses a probe on more than one key column, so
-    Q9's probe pipeline is three fused probes, the partsupp probe as a
-    standalone `LookupJoinOperator` over every page as the part join left
-    it, then the orders probe fused with the aggregation."""
+    Q9's probe pipeline is three fused probes, a Coalesce that packs what
+    the part join kept, the partsupp probe as a standalone
+    `LookupJoinOperator`, then the orders probe fused with the
+    aggregation."""
     runner = LocalQueryRunner(session=Session(catalog="tpch", schema="tiny"))
     plan = "\n".join(str(r[0]) for r in runner.execute(
         "explain analyze " + q9.template.format(color="green")).rows)
     probe = plan[plan.index("pipeline 5:"):plan.index("fused segments:")]
     operators = [line.split()[0] for line in probe.splitlines()[1:] if line]
     assert operators == ["TableScan", "FusedSegment[LookupJoin(in",
-                         "LookupJoin(inner)", "FusedSegment[LookupJoin(in",
-                         "OrderBy", "PageConsumer"]
+                         "Coalesce", "LookupJoin(inner)",
+                         "FusedSegment[LookupJoin(in", "OrderBy",
+                         "PageConsumer"]
 
 
 def test_the_build_has_a_span_with_its_kind_keys_and_pages(q9):
