@@ -94,6 +94,20 @@ class TpchMetadata(ConnectorMetadata):
     def get_unique_column_sets(self, table: TableHandle):
         return list(self._UNIQUE_KEYS.get(table.schema_table.table, []))
 
+    # clause 1.4.2's key relations: a foreign key holds values of the table
+    # it references, so that table's row count is its distinct count.
+    # l_orderkey (-> orders) is NOT here and keeps lineitem's own rows:
+    # add_exchanges' broadcast choice reads it through estimate_rows' JoinNode
+    # arm, and with orders' count the mesh cell's Q3 replicates customer
+    # (PERF.md §7: the perf_opt that measures q3_sf1_mesh4 adds the line)
+    _FOREIGN_KEYS = {
+        "n_regionkey": "region",
+        "s_nationkey": "nation", "c_nationkey": "nation",
+        "ps_partkey": "part", "l_partkey": "part",
+        "ps_suppkey": "supplier", "l_suppkey": "supplier",
+        "o_custkey": "customer",
+    }
+
     def get_table_statistics(self, table: TableHandle, constraint: Constraint) -> TableStatistics:
         name = table.schema_table.table
         sf = table.extra[0]
@@ -103,8 +117,9 @@ class TpchMetadata(ConnectorMetadata):
             cs = ColumnStatistics(null_fraction=0.0)
             if cdict is not None and type(cdict).__name__ == "Dictionary":
                 cs.distinct_count = float(len(cdict))
-            elif cname.endswith(("key",)):
-                cs.distinct_count = rows
+            elif cname.endswith("key"):
+                cs.distinct_count = float(g.table_row_count(
+                    self._FOREIGN_KEYS.get(cname, name), sf))
             stats.columns[cname] = cs
         return stats
 

@@ -21,14 +21,16 @@ structure is one of:
    [lo,hi) ranges and arbitrary key domains; multi-column keys are packed
    bijectively into the 64 bits where their ranges fit (`_plan_packing`), else go
    through a 64-bit mix with post-match verification on the true key columns
-   (collisions only mask rows, never corrupt results). The one TPC-H join that
-   takes this form is the two-column one: partsupp on (ps_partkey, ps_suppkey),
-   probed by lineitem in Q9 - a direct-address table over the packed pair
-   would have 2^32 slots at SF1.
+   (collisions only mask rows, never corrupt results). The TPC-H joins that
+   take this form are the two-column ones: partsupp on (ps_partkey,
+   ps_suppkey), probed by lineitem in Q9 - a direct-address table over the
+   packed pair would have 2^32 slots at SF1 - and customer on (c_nationkey,
+   c_custkey) in Q5, unique on its customer key.
 
 What a build became and what a probe page searched is counted: `join.builds`
 with `.dense`, `.sorted` and `.multikey` beside it, `join.probe.pages` with
-`.sorted_pages`, and the build's host seconds as the span `join.build` and the
+`.sorted_pages` and `.expanded_pages` (a build that is not unique on the
+clauses: the path a fan-out takes), and the build's host seconds as the span `join.build` and the
 histogram `join.build_s` (`/v1/metrics`).
 
 Those two are all there is, and no option selects between them.
@@ -1048,6 +1050,7 @@ class LookupJoinOperator(Operator):
             jt == INNER, jt in (LEFT, FULL)))
 
     def _emit_expanded(self, page: Page, probe_keys, probe_mask) -> None:
+        METRICS.count("join.probe.expanded_pages")
         src = self._source
         jt = self.f.join_type
         if jt not in (INNER, LEFT, FULL):

@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import dataclasses
 import itertools
+import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 from ...metadata import MetadataManager, Session
@@ -17,6 +18,8 @@ from ...ops.expressions import (Call, Constant, RowExpression, SpecialForm,
                                 SymbolRef, rewrite_expression, special,
                                 symbols_in, symbol_ref)
 from ...types import BOOLEAN
+from ...utils import trace
+from ...utils.metrics import METRICS
 from .plan import (AggregationNode, EnforceSingleRowNode, FilterNode, JoinNode,
                    LimitNode, Ordering, OutputNode, PlanNode, ProjectNode,
                    SemiJoinNode, SortNode, Symbol, TableScanNode, TopNNode,
@@ -345,6 +348,54 @@ def _join_key_ndv(node: PlanNode, sym: Symbol, metadata) -> Optional[float]:
     return cs.distinct_count if cs is not None else None
 
 
+def _unique_cover(node: PlanNode, names: Set[str], metadata: MetadataManager
+                  ) -> Optional[Tuple[float, Set[str]]]:
+    """Where symbols among `names` cover a unique column set of `node`: (the
+    distinct count of that set, which is the rows of the relation under its
+    filters, the set's symbols). A table's sets are the connector's
+    (get_unique_column_sets), an aggregation's is its group keys. Else None."""
+    if isinstance(node, TableScanNode):
+        by_col = {c.name: s.name for s, c in node.assignments
+                  if s.name in names}
+        conn = metadata.connector(node.table.connector_id).metadata()
+        for uset in conn.get_unique_column_sets(node.table):
+            if set(uset) <= by_col.keys():
+                return (estimate_rows(node, metadata),
+                        {by_col[c] for c in uset})
+        return None
+    if isinstance(node, FilterNode):
+        return _unique_cover(node.source, names, metadata)
+    if isinstance(node, ProjectNode):
+        outer = {e.name: s.name for s, e in node.assignments
+                 if s.name in names and isinstance(e, SymbolRef)}
+        hit = _unique_cover(node.source, set(outer), metadata)
+        return hit and (hit[0], {outer[n] for n in hit[1]})
+    if isinstance(node, AggregationNode) and node.keys:
+        keys = {k.name for k in node.keys}
+        if keys <= names:
+            return estimate_rows(node, metadata), keys
+    return None
+
+
+def join_rows(probe_rows: float, build: PlanNode, build_rows: float,
+              clauses: Sequence[Tuple[Optional[float], Symbol]],
+              metadata: MetadataManager) -> float:
+    """The estimate of what an inner equi-join emits that the join ORDER is
+    priced from: cost.join_output_rows over the statistics looked up here.
+    `clauses`: (the probe key's distinct count, the build's key symbol) a
+    clause. Where the build's keys cover a unique column set of it, a probe
+    row finds at most one row there: those clauses count as one, the others
+    divide on."""
+    from .cost import join_output_rows
+
+    hit = _unique_cover(build, {key.name for _ndv, key in clauses}, metadata)
+    unique_rows, covered = hit if hit is not None else (None, ())
+    ndvs = [max(probe_ndv or 0.0,
+                _join_key_ndv(build, key, metadata) or 0.0) or None
+            for probe_ndv, key in clauses if key.name not in covered]
+    return join_output_rows(probe_rows, build_rows, ndvs, unique_rows)
+
+
 def estimate_rows(node: PlanNode, metadata: MetadataManager) -> float:
     if isinstance(node, TableScanNode):
         stats = metadata.get_table_statistics(node.table)
@@ -372,6 +423,10 @@ def estimate_rows(node: PlanNode, metadata: MetadataManager) -> float:
             return max(1.0, min(src, ndv))
         return max(1.0, src * 0.1)
     if isinstance(node, JoinNode):
+        # a join UNDER a region's leaf (a subquery's) and the probe of
+        # add_exchanges' broadcast choice; the join ORDER's steps read
+        # join_rows. Left as it was, l_orderkey's distinct count with it:
+        # tpch-sf1-mesh4's Q3 plan hangs on both (PERF.md §7)
         l = estimate_rows(node.left, metadata)
         r = estimate_rows(node.right, metadata)
         if not node.criteria:
@@ -412,10 +467,27 @@ def reorder_joins(plan: PlanNode, metadata: MetadataManager) -> PlanNode:
     """Greedy left-deep reordering of inner-join regions.
 
     A region = maximal tree of inner JoinNodes and FilterNodes. The spine (probe
-    side) starts at the largest relation; each step joins the smallest relation
-    equi-connected to the spine (the reference's greedy fallback when the
-    exhaustive ReorderJoins search is off). Build sides end up small -> they fit
-    the TPU-resident hash table; the big fact table streams through as probe."""
+    side) starts at the largest relation; each step joins the relation whose
+    join is cheapest by cost.join_step_cost over join_rows' estimate of what
+    it emits (the reference's greedy fallback when the exhaustive ReorderJoins
+    search is off). Where no candidate fans out that is the smallest relation
+    equi-connected to the spine: build sides end up small -> they fit the
+    TPU-resident hash table; the big fact table streams through as probe.
+
+    Timed as the span `planner.reorder_joins` (args: the relations and joins
+    ordered, the estimated rows of the widest intermediate) and the histogram
+    `planner.reorder_joins_s`, inside what `query.plan_s` times."""
+    noted = {"relations": 0, "joins": 0, "widest_rows": 0.0}
+    t0 = time.perf_counter()
+    with trace.span(trace.PLANNER, "reorder_joins") as ordered:
+        out = _reorder_joins(plan, metadata, noted)
+        ordered.note(**noted)
+    METRICS.histogram("planner.reorder_joins_s", time.perf_counter() - t0)
+    return out
+
+
+def _reorder_joins(plan: PlanNode, metadata: MetadataManager,
+                   noted: Dict[str, float]) -> PlanNode:
     def visit(node: PlanNode) -> Optional[PlanNode]:
         # region roots: an inner join, or a filter stack sitting on one (equality
         # conjuncts that pushdown could not sink into one side land there)
@@ -428,7 +500,7 @@ def reorder_joins(plan: PlanNode, metadata: MetadataManager) -> PlanNode:
             _flatten_region(node, relations, conjuncts)
             if len(relations) < 2:
                 return None
-            return _greedy_join(relations, conjuncts, metadata)
+            return _greedy_join(relations, conjuncts, metadata, noted)
         return None
 
     return _rewrite_topdown_regions(plan, visit)
@@ -463,16 +535,15 @@ def _flatten_region(node: PlanNode, relations: List[PlanNode],
 
 
 def _greedy_join(relations: List[PlanNode], conjuncts: List[RowExpression],
-                 metadata: MetadataManager) -> PlanNode:
+                 metadata: MetadataManager, noted: Dict[str, float]
+                 ) -> PlanNode:
     rel_syms: List[Set[str]] = [{s.name for s in r.outputs()} for r in relations]
-    sym_types: Dict[str, Symbol] = {}
-    for r in relations:
-        for s in r.outputs():
-            sym_types[s.name] = s
+    owner: Dict[str, int] = {n: i for i, syms in enumerate(rel_syms)
+                             for n in syms}
     sizes = [estimate_rows(r, metadata) for r in relations]
 
     # recurse into the relation subtrees first (nested regions below barriers)
-    relations = [reorder_joins(r, metadata) for r in relations]
+    relations = [_reorder_joins(r, metadata, noted) for r in relations]
 
     pending = list(conjuncts)
     remaining = set(range(len(relations)))
@@ -509,22 +580,32 @@ def _greedy_join(relations: List[PlanNode], conjuncts: List[RowExpression],
     # candidate is priced as one hash-join step — probe the current spine,
     # build the candidate, emit the estimated output — and the cheapest
     # joins next. Build memory weighs double (HBM is the TPU's wall).
+    # `spine_rows` is join_rows' estimate of the spine; `widest` is the
+    # stream the next probe pays for: a probe masks the rows it drops and
+    # the page keeps its slots, so only a join that fans out (clauses that
+    # cover no unique column set of the candidate) moves a step's price.
     from .cost import join_step_cost
 
-    spine_rows = sizes[spine_i]
+    spine_rows = widest = sizes[spine_i]
+    noted["relations"] += len(relations)
+    noted["joins"] += len(relations) - 1
     while remaining:
-        connected = [i for i in remaining if equi_pairs_for(i)]
-        pool = connected or list(remaining)
+        pairs_of = {i: equi_pairs_for(i) for i in remaining}
+        pool = [i for i in remaining if pairs_of[i]] or list(remaining)
+        # each candidate's output, estimated once a step
+        output_rows = {i: join_rows(
+            spine_rows, relations[i], sizes[i],
+            [(_join_key_ndv(relations[owner[a.name]], a, metadata), b)
+             for a, b in pairs_of[i]], metadata) for i in pool}
 
         def step_cost(i: int) -> float:
-            out_rows = max(spine_rows, sizes[i]) if equi_pairs_for(i) \
-                else spine_rows * sizes[i]
-            return join_step_cost(spine_rows, sizes[i], out_rows).total()
+            return join_step_cost(widest, sizes[i],
+                                  max(widest, output_rows[i])).total()
 
         nxt = min(pool, key=step_cost)
-        spine_rows = max(spine_rows, sizes[nxt]) if equi_pairs_for(nxt) \
-            else spine_rows * sizes[nxt]
-        pairs = equi_pairs_for(nxt)
+        spine_rows = output_rows[nxt]
+        widest = max(widest, spine_rows)
+        pairs = pairs_of[nxt]
         used = []
         for c in pending:
             p = _as_equi(c)
@@ -542,6 +623,7 @@ def _greedy_join(relations: List[PlanNode], conjuncts: List[RowExpression],
 
     if pending:
         spine = FilterNode(spine, and_all(pending))
+    noted["widest_rows"] = max(noted["widest_rows"], widest)
     return spine
 
 

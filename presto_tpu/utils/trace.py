@@ -54,6 +54,7 @@ Categories — one per instrumented subsystem:
   exchange   streaming-exchange chunk dispatch/delivery + pump stalls
   kernel     kernel-cache misses (jit closure builds)
   join       a join build publishing its lookup source (ops/hash_join.py)
+  planner    the join order (sql/planner/optimizer.py reorder_joins)
   http       cluster task create/poll and exchange pulls
   pool       shared-pool generator steps (exec/shared_pools.py)
   protocol   queued / serialize / result_wait (server/protocol.py; profiler
@@ -80,6 +81,7 @@ SCAN = "scan"
 EXCHANGE = "exchange"
 KERNEL = "kernel"
 JOIN = "join"
+PLANNER = "planner"
 HTTP = "http"
 POOL = "pool"
 
