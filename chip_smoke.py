@@ -319,8 +319,8 @@ def mesh_phase(devs, schema: str, watch: CompileWatch) -> None:
                          "xla_compile_s": one_built["xla_compile_s"]},
              matches_one_device=True,
              exchange={k: ex.get(k) for k in (
-                 "mode", "exchanges", "chunks", "collective_compiles",
-                 "carry_rows")})
+                 "mode", "exchanges", "chunks", "fills", "refills",
+                 "collective_compiles", "carry_rows")})
         collectives += int(ex.get("chunks") or 0)
         if not ex.get("exchanges"):
             raise RuntimeError(f"q{qid}: no exchange ran on the mesh: {ex}")

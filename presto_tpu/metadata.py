@@ -27,7 +27,8 @@ class Session:
     # engine defaults (the SystemSessionProperties subset that matters here)
     DEFAULTS = {
         # None = platform default (default_page_capacity), resolved only
-        # when execution actually needs the backend
+        # when execution actually needs the backend; the mesh runner holds
+        # the same default to streaming_exchange.MESH_PAGE_ROWS
         "page_capacity": None,
         "task_concurrency": 4,
         # intra-pipeline driver parallelism: AUTO = task_concurrency on
@@ -86,10 +87,15 @@ class Session:
         # fixed-capacity chunks stream through the inter-fragment collectives
         # while producer drivers still run (producer/consumer fragments share
         # one task executor)
-        # per-worker chunk capacity in rows (pow2-rounded); 0 = engine
-        # default (streaming_exchange.DEFAULT_CHUNK_ROWS, 4096). The chunk
-        # shape is FIXED per query, so each exchange kind compiles ONE
-        # collective program per query shape instead of one per pow2 volume
+        # per-worker chunk capacity in rows (pow2-rounded); 0 = DERIVED by
+        # each exchange, when it is built, from the page capacity of its
+        # producing fragment and the in-flight bound
+        # (streaming_exchange.derive_chunk_rows: the page's pow2, two chunks
+        # a side inside exchange_inflight_bytes, at least 4096), so a page
+        # that fits its chunk is never split. A value is the tests'
+        # override (pages longer than the chunk: the leftover path). The
+        # chunk shape is FIXED per stream, so each exchange compiles ONE
+        # collective program per shape instead of one per pow2 volume
         "exchange_chunk_rows": 0,
         # in-flight byte bound per exchange: producer sinks park (BLOCKED)
         # while staged + undelivered bytes exceed it — no stage ever holds a

@@ -8,7 +8,7 @@ HTTP with LZ4 pages (execution/buffer/PagesSerde.java:39).
 TPU re-design: there is no serialization, no HTTP, no LZ4 — a partitioned exchange is
 ONE collective inside the SPMD program:
 
-    repartition = sort rows by target partition + lax.all_to_all over the mesh axis
+    repartition = rank rows within their target partition + lax.all_to_all over the mesh axis
     broadcast   = lax.all_gather
     single      = all_gather then mask to worker 0
 
@@ -69,21 +69,54 @@ def range_partition_ids(range_key: jnp.ndarray, splitters: jnp.ndarray,
     return jnp.where(mask, jnp.clip(pid, 0, n_parts - 1), n_parts)
 
 
+def _rank_in_partition(pid: jnp.ndarray, n_parts: int) -> jnp.ndarray:
+    """Each row's slot within its partition, rows in their own order (what a
+    stable sort by `pid` would give), with no sort: one prefix sum a
+    partition, so `n_parts` of them a collective (a prefix sum over 2^20 rows
+    runs in 0.8 ms on the v5e, PERF.md section 7). Compile seconds for a
+    described v5e on the builder's HOST, no chip number (PERF.md section 6,
+    PR 37): a collective that argsorts a 2^16-row chunk 25 s, this one 3.0 s
+    for 4 workers and 3.2 s for 8; ONE prefix sum over an (n_parts, n)
+    one-hot compiles in the same seconds and holds 1.4x the scratch, so the
+    loop stays."""
+    pos = jnp.zeros(pid.shape[0], dtype=jnp.int32)
+    for p in range(n_parts):
+        mine = pid == p
+        pos = jnp.where(mine, jnp.cumsum(mine.astype(jnp.int32)) - 1, pos)
+    return pos
+
+
+def move_rows(arrays: Sequence[jnp.ndarray], tgt: jnp.ndarray, n_slots: int,
+              into: Sequence[jnp.ndarray] = ()):
+    """Rows -> slots `tgt` of (n_slots,) buffers (`tgt == n_slots` drops the
+    row): the buffers `into` where given, whose other slots keep what they
+    hold, else fresh zeros. ONE int32 scatter builds each slot's source row
+    and every column moves by a gather (block._compact's form, PR 32: a
+    64-bit scatter is 74 ms a 2^20 rows on the v5e, a 64-bit gather 17.5, and
+    a scatter a column is what the compiler spends its seconds on: for a
+    described v5e on the builder's HOST, no chip number, 6.4 s for a fill of
+    4 columns at 2^16 rows against 1.3 s, PERF.md section 6, PR 37).
+    -> (buffers, slots filled)"""
+    src = jnp.full(n_slots, -1, dtype=jnp.int32).at[tgt].set(
+        jnp.arange(tgt.shape[0], dtype=jnp.int32), mode="drop")
+    filled = src >= 0
+    idx = jnp.maximum(src, 0)
+    into = into or [jnp.zeros((), a.dtype) for a in arrays]
+    return [jnp.where(filled, a[idx], b)
+            for a, b in zip(arrays, into)], filled
+
+
 def repartition_by_pid(arrays: Sequence[jnp.ndarray], mask: jnp.ndarray,
                        pid: jnp.ndarray, n_parts: int, out_cap_per_peer: int,
                        axis_name: str = WORKER_AXIS):
     """Route rows to the peers named by `pid` (n_parts = masked-off). Shared
-    tail of hash REPARTITION and range MERGE exchanges."""
-    n = mask.shape[0]
-    # stable sort rows by partition; within-partition order preserved
-    order = jnp.argsort(pid, stable=True)
-    pid_s = pid[order]
-    # slot of each row within its partition
-    pos_in_part = jnp.arange(n, dtype=jnp.int32) - jnp.searchsorted(
-        pid_s, pid_s, side="left").astype(jnp.int32)
-    keep = (pid_s < n_parts) & (pos_in_part < out_cap_per_peer)
-    dropped = jnp.sum((pid_s < n_parts) & ~keep)
-    outs, recv_mask = _route_kept(arrays, order, pid_s, pos_in_part, keep,
+    tail of hash REPARTITION and range MERGE exchanges; within a partition
+    rows keep their order."""
+    pos_in_part = _rank_in_partition(pid, n_parts)
+    live = pid < n_parts
+    keep = live & (pos_in_part < out_cap_per_peer)
+    dropped = jnp.sum(live & ~keep)
+    outs, recv_mask = _route_kept(arrays, pid, pos_in_part, keep,
                                   n_parts, out_cap_per_peer, axis_name)
     return outs, recv_mask, dropped
 
@@ -101,47 +134,33 @@ def repartition_by_pid_with_carry(arrays: Sequence[jnp.ndarray],
 
     Returns (recv_arrays, recv_mask, carry_arrays, carry_mask)."""
     n = mask.shape[0]
-    order = jnp.argsort(pid, stable=True)
-    pid_s = pid[order]
-    pos_in_part = jnp.arange(n, dtype=jnp.int32) - jnp.searchsorted(
-        pid_s, pid_s, side="left").astype(jnp.int32)
-    live = pid_s < n_parts
+    pos_in_part = _rank_in_partition(pid, n_parts)
+    live = pid < n_parts
     keep = live & (pos_in_part < out_cap_per_peer)
     overflow = live & ~keep
-    outs, recv_mask = _route_kept(arrays, order, pid_s, pos_in_part, keep,
+    outs, recv_mask = _route_kept(arrays, pid, pos_in_part, keep,
                                   n_parts, out_cap_per_peer, axis_name)
     # compact the overflow rows to the front of (n,) carry buffers
     cpos = jnp.cumsum(overflow.astype(jnp.int32)) - 1
-    ctgt = jnp.where(overflow, cpos, n)
-    carry_mask = jnp.zeros(n, dtype=jnp.bool_).at[ctgt].set(overflow,
-                                                            mode="drop")
-    carry = [jnp.zeros(n, dtype=a.dtype).at[ctgt].set(a[order], mode="drop")
-             for a in arrays]
+    carry, carry_mask = move_rows(arrays, jnp.where(overflow, cpos, n), n)
     return outs, recv_mask, carry, carry_mask
 
 
-def _route_kept(arrays, order, pid_s, pos_in_part, keep, n_parts: int,
+def _route_kept(arrays, pid, pos_in_part, keep, n_parts: int,
                 out_cap_per_peer: int, axis_name: str):
-    """Scatter the kept (sorted-by-pid) rows into (n_parts, cap) send buffers
-    and run the all_to_all; shared tail of the drop and carry repartitions."""
-    # scatter into (n_parts, cap) send buffers
-    tgt = jnp.where(keep, pid_s * out_cap_per_peer + pos_in_part,
-                    n_parts * out_cap_per_peer)
-    send_mask = jnp.zeros(n_parts * out_cap_per_peer, dtype=jnp.bool_
-                          ).at[tgt].set(keep, mode="drop")
-    outs = []
-    for a in arrays:
-        buf = jnp.zeros(n_parts * out_cap_per_peer, dtype=a.dtype
-                        ).at[tgt].set(a[order], mode="drop")
-        outs.append(buf.reshape(n_parts, out_cap_per_peer))
-    send_mask = send_mask.reshape(n_parts, out_cap_per_peer)
+    """Move the kept rows into (n_parts, cap) send buffers and run the
+    all_to_all; shared tail of the drop and carry repartitions."""
+    slots = n_parts * out_cap_per_peer
+    tgt = jnp.where(keep, pid * out_cap_per_peer + pos_in_part, slots)
+    bufs, send_mask = move_rows(arrays, tgt, slots)
     # the collective: peer p receives every worker's partition-p slice
-    recv = [lax.all_to_all(b, axis_name, split_axis=0, concat_axis=0, tiled=False)
-            for b in outs]
-    recv_mask = lax.all_to_all(send_mask, axis_name, split_axis=0, concat_axis=0,
-                               tiled=False)
-    outs = [r.reshape(n_parts * out_cap_per_peer) for r in recv]
-    return outs, recv_mask.reshape(n_parts * out_cap_per_peer)
+    recv = [lax.all_to_all(b.reshape(n_parts, out_cap_per_peer), axis_name,
+                           split_axis=0, concat_axis=0, tiled=False)
+            for b in bufs]
+    recv_mask = lax.all_to_all(
+        send_mask.reshape(n_parts, out_cap_per_peer), axis_name,
+        split_axis=0, concat_axis=0, tiled=False)
+    return [r.reshape(slots) for r in recv], recv_mask.reshape(slots)
 
 
 def broadcast_gather(arrays: Sequence[jnp.ndarray], mask: jnp.ndarray,

@@ -32,7 +32,7 @@ from ..block import Dictionary
 from ..exec.local_planner import LocalExecutionPlanner
 from ..exec.shared_pools import next_query_key
 from ..exec.task_executor import TaskExecutor
-from ..metadata import CatalogManager, Session
+from ..metadata import CatalogManager, Session, default_page_capacity
 from ..runner import LocalQueryRunner, QueryResult
 from ..sql import tree as t
 from ..sql.planner.add_exchanges import add_exchanges
@@ -48,7 +48,8 @@ from .mesh import MeshContext
 # EXCHANGE_STATS re-exported here because the multichip dryrun (and history)
 # imports it from this module
 from .streaming_exchange import (EXCHANGE_STATS, ExchangeSinkOperatorFactory,  # noqa: F401
-                                 ExchangeStatsBook, StreamingExchange)
+                                 ExchangeStatsBook, MESH_PAGE_ROWS,
+                                 StreamingExchange)
 
 
 class DistributedQueryRunner:
@@ -57,7 +58,9 @@ class DistributedQueryRunner:
     def __init__(self, mesh: Optional[MeshContext] = None,
                  session: Optional[Session] = None,
                  catalogs: Optional[CatalogManager] = None,
-                 page_capacity: int = 1 << 14):
+                 page_capacity: Optional[int] = None):
+        # page_capacity None = the platform's page, resolved at execution
+        # (_execute_streaming) as the local runner resolves its own
         self.local = LocalQueryRunner(session, catalogs, page_capacity)
         self.mesh = mesh if mesh is not None else MeshContext()
 
@@ -177,9 +180,15 @@ class DistributedQueryRunner:
         exchanges: Dict[int, StreamingExchange] = {}
         sink_facs: Dict[int, ExchangeSinkOperatorFactory] = {}
         mem_ctx, over_target, mem_release = self.local._query_memory()
-        chunk_rows = int(self.session.get("exchange_chunk_rows") or 0)
-        inflight = int(self.session.get("exchange_inflight_bytes") or 0)
-        page_cap = int(self.session.get("page_capacity") or (1 << 14))
+        session = self.session
+        chunk_rows = int(session.get("exchange_chunk_rows") or 0)
+        inflight = int(session.get("exchange_inflight_bytes") or 0)
+        page_cap = int(session.get("page_capacity") or 0)
+        if not page_cap:
+            # the platform's page, as the local runner reads it, held to the
+            # grain the mesh's data plane runs at; every fragment plans with it
+            page_cap = min(default_page_capacity(), MESH_PAGE_ROWS)
+            session = session.with_properties(page_capacity=page_cap)
         # ONE shared-pool fairness slot per query: every fragment's scan
         # stages and every exchange pump of this query share it
         pool_key = next_query_key("mesh-q") \
@@ -195,7 +204,7 @@ class DistributedQueryRunner:
                     root = self._fragment_root(sub, frag)
                     workers = [0] if frag.partitioning == SINGLE_PART \
                         else list(range(W))
-                    lp = LocalExecutionPlanner(self.metadata, self.session,
+                    lp = LocalExecutionPlanner(self.metadata, session,
                                                n_workers=W,
                                                remote_dicts=frag_dicts,
                                                devices=self.mesh.devices,
@@ -329,6 +338,9 @@ class DistributedQueryRunner:
                 lines.append(
                     f"  exchange [{exch.get('kind')}]: "
                     f"chunks={exch.get('chunks', 0)} "
+                    f"chunk_rows={exch.get('chunk_rows', 0)} "
+                    f"fills={exch.get('fills', 0)} "
+                    f"refills={exch.get('refills', 0)} "
                     f"carry_rows={exch.get('carry_rows', 0)} "
                     f"rows_out={exch.get('rows_out', 0)} "
                     f"compiles={exch.get('compiles', 0)} "

@@ -8,7 +8,21 @@ in flight. This module is that data plane, TPU-shaped:
 
 - producer drivers of fragment F end in an :class:`ExchangeSinkOperator`
   feeding per-worker CHUNK buffers (fixed pow2 capacity) instead of
-  accumulating pages;
+  accumulating pages. The capacity is DERIVED, not chosen
+  (:func:`derive_chunk_rows`), when the exchange is built: the pow2 of the
+  page capacity its producing fragment was planned with (a scan's pages
+  and an upstream exchange's receive pages are no longer), no larger than
+  what `exchange_inflight_bytes` holds twice over a side, no smaller than
+  MIN_CHUNK_ROWS, so the data plane runs at the grain of the pages the
+  engine runs at on this platform, and the same query builds the same
+  shapes in every run. A page that fits a chunk is never split: it is
+  appended by one prefix sum, one index scatter and a gather a column, and
+  when what is left of a chunk cannot take the next page whole the chunk
+  goes out partly filled (padding on the wire) and the page opens a fresh
+  one. Only a page LONGER than its chunk (the tests' override
+  `exchange_chunk_rows`, a chunk the bound capped, a carry) is filled to
+  the brim with its leftover re-queued (`exchange.refills` counts those,
+  `exchange.fills` every fill program of either side);
 - an exchange pump thread dispatches ONE compiled shard_map collective per
   chunk; the shape is static per query, so the repartition/broadcast/merge
   program compiles once per (kind, shape) and is reused for every chunk;
@@ -20,9 +34,10 @@ in flight. This module is that data plane, TPU-shaped:
   come back as same-shape CARRY buffers, re-fed into the next chunk — skewed
   keys are correct by construction, not by worst-case capacity sizing;
 - received rows are PACKED per consumer into pages of the shard's length,
-  handed over when full (the last one at the stream's end): a consumer
-  sees ceil(rows / length) pages however the pumps' timing cut the chunks,
-  so the fragments above an exchange trace the same shapes in every run;
+  handed over when full (the last one at the stream's end; a stream that
+  fits ONE page hands it cut to the pow2 of its rows): a consumer sees
+  ceil(rows / length) pages however the pumps' timing cut the chunks, so
+  the fragments above an exchange trace the same shapes in every run;
 - in-flight bytes are bounded on both sides: producers park (BLOCKED, the
   task executor's poll-able future) when staged + undelivered bytes exceed
   `exchange_inflight_bytes`, mirroring the scan pipeline's byte budget; no
@@ -192,10 +207,44 @@ def _pow2(n: int, floor: int = 1) -> int:
 # chunk fill kernel: append a page's live rows to a fixed-capacity chunk
 # ---------------------------------------------------------------------------
 
-# default per-worker chunk capacity (rows) and in-flight byte budget; session
-# knobs exchange_chunk_rows / exchange_inflight_bytes override
-DEFAULT_CHUNK_ROWS = 1 << 12
+# the floor of a derived send chunk (rows a worker: the fixed grain the
+# exchange ran at before it read one from its input) and the default
+# in-flight byte budget (session knob exchange_inflight_bytes overrides)
+MIN_CHUNK_ROWS = 1 << 12
 DEFAULT_INFLIGHT_BYTES = 1 << 28
+
+# The grain of the mesh's data plane: the longest page (rows) the mesh runner
+# asks for where the session names none (parallel/runner.py), and so the
+# largest send chunk an exchange derives. Under the platform's page (2^22 on
+# an accelerator) because of what four v5e chips gave
+# (tools/exchange_grain_sweep.py, PERF.md section 6, PR 37). The sweep ran
+# 2^16, 2^18 and 2^20 on a tree whose fills still scattered a column and whose
+# collective still sorted: a server's first Q3 from an empty compile cache
+# took 277 / 351 / 494 s (the check gives a whole run 360 s) and a warm one
+# 3.9 / 4.5 / 5.1 s, RISING with the grain: a page that does not fit a chunk
+# is not split, so chunks leave half filled and every padded slot is moved by
+# the collective's program and the receive side's pack. 2^16 was the smallest
+# grain run, so the sweep does not bracket its best: 2^14, 2^15 and 2^17 are
+# not measured, on that tree or this one (PERF.md section 7). On this tree
+# 2^16 alone is measured: a first Q3 162 s cold, 2.13 s warm. A session's
+# `page_capacity` still wins.
+MESH_PAGE_ROWS = 1 << 16
+
+
+def derive_chunk_rows(page_rows: int, row_bytes: int, n_workers: int,
+                      inflight_bytes: int, override: int = 0) -> int:
+    """Rows a worker's send chunk holds: the pow2 of `page_rows`, the longest
+    page the producing fragment hands over (a page that fits its chunk is
+    appended whole, never split), no larger than what `inflight_bytes` holds
+    twice over a side (two chunks are in flight for the double buffering,
+    each `row_bytes` x rows x `n_workers`), no smaller than MIN_CHUNK_ROWS.
+    `override` (the session's `exchange_chunk_rows`, the tests' knob) wins
+    over all of it."""
+    if override:
+        return _pow2(override, floor=64)
+    held = int(inflight_bytes) // (2 * max(int(row_bytes), 1) * n_workers)
+    bound = 1 << max(held.bit_length() - 1, 0)   # the largest pow2 <= held
+    return max(min(_pow2(page_rows), bound), MIN_CHUNK_ROWS)
 
 # per-peer receive floor for the repartition: small, because the chunk shape
 # is FIXED per query anyway (no compile-diversity concern) and carry-over
@@ -283,37 +332,68 @@ class SkewCoordinator:
         return (hb, hp) if side == BUILD_SIDE else (hp, hb)
 
 
+@functools.lru_cache(maxsize=1)
+def _append_chunk_jit():
+    """(chunk state, page) -> new chunk state, for a page whose live rows
+    FIT what is left of the chunk (the pump knows both counts on the host):
+    live rows append densely at positions count..count+live-1. One prefix
+    sum, one index scatter and a gather a column (exchange.move_rows);
+    nothing is left over, so no second pass."""
+    import jax
+    import jax.numpy as jnp
+
+    from .exchange import move_rows
+
+    def fn(ch_d, ch_n, ch_m, count, pd, pn, pm):
+        C, ncols = ch_m.shape[0], len(pd)
+        pos = count + jnp.cumsum(pm.astype(jnp.int32)) - 1
+        cols, new = move_rows(pd + pn, jnp.where(pm, pos, C), C,
+                              into=ch_d + ch_n)
+        return tuple(cols[:ncols]), tuple(cols[ncols:]), ch_m | new
+    return jax.jit(fn)
+
+
 @functools.lru_cache(maxsize=128)
 def _fill_chunk_jit(ncols: int, C: int):
-    """(chunk state, page) -> (new chunk state, leftover page).
+    """(chunk state, page) -> (new chunk state, leftover page), for a page
+    that does NOT fit: longer than its chunk (the `exchange_chunk_rows`
+    override, a chunk the in-flight bound capped) or past the end of a
+    receive page being packed.
 
     Live page rows append densely at chunk positions count..count+live-1;
     rows past capacity C compact to the front of same-shape leftover buffers
-    (the pump dispatches the full chunk and re-feeds the leftover). One
-    fused scatter per page — the chunk buffers never round-trip the host."""
+    (the pump dispatches the full chunk and re-feeds the leftover). The
+    chunk buffers never round-trip the host."""
     import jax
     import jax.numpy as jnp
+
+    from .exchange import move_rows
 
     def fn(ch_d, ch_n, ch_m, count, pd, pn, pm):
         P = pm.shape[0]
         pos = count + jnp.cumsum(pm.astype(jnp.int32)) - 1
-        into = pm & (pos < C)
-        tgt = jnp.where(into, pos, C)
-        new_m = ch_m.at[tgt].set(into, mode="drop")
-        new_d = tuple(d.at[tgt].set(p, mode="drop")
-                      for d, p in zip(ch_d, pd))
-        new_n = tuple(x.at[tgt].set(p, mode="drop")
-                      for x, p in zip(ch_n, pn))
-        left = pm & (pos >= C)
-        lpos = jnp.cumsum(left.astype(jnp.int32)) - 1
-        ltgt = jnp.where(left, lpos, P)
-        left_m = jnp.zeros(P, dtype=jnp.bool_).at[ltgt].set(left, mode="drop")
-        left_d = tuple(jnp.zeros(P, dtype=p.dtype).at[ltgt].set(p, mode="drop")
-                       for p in pd)
-        left_n = tuple(jnp.zeros(P, dtype=jnp.bool_).at[ltgt].set(p,
-                                                                  mode="drop")
-                       for p in pn)
-        return new_d, new_n, new_m, left_d, left_n, left_m
+        cols, new = move_rows(pd + pn, jnp.where(pm & (pos < C), pos, C), C,
+                              into=ch_d + ch_n)
+        # a row past the chunk's end is the leftover's row pos - C
+        left, left_m = move_rows(
+            pd + pn, jnp.where(pm & (pos >= C), pos - C, P), P)
+        return (tuple(cols[:ncols]), tuple(cols[ncols:]), ch_m | new,
+                tuple(left[:ncols]), tuple(left[ncols:]), left_m)
+    return jax.jit(fn)
+
+
+@functools.lru_cache(maxsize=1)
+def _shard_facts_jit():
+    """(mask, null masks) -> (live rows, which columns hold a null): what the
+    pump reads back of a page or a received shard, as ONE program (taken
+    eagerly it was a program a column and two for the count)."""
+    import jax
+    import jax.numpy as jnp
+
+    def fn(mask, nulls):
+        has_nulls = jnp.stack([jnp.any(x) for x in nulls]) if nulls else \
+            jnp.zeros(0, dtype=jnp.bool_)
+        return jnp.sum(mask.astype(jnp.int32)), has_nulls
     return jax.jit(fn)
 
 
@@ -534,7 +614,7 @@ class StreamingExchange:
                  key_idx: Optional[List[int]], types: Sequence[Type],
                  dicts: Sequence[Optional[Dictionary]],
                  orderings=None, chunk_rows: int = 0,
-                 inflight_bytes: int = 0, page_capacity: int = 1 << 14,
+                 inflight_bytes: int = 0, page_capacity: int = 0,
                  book: Optional[ExchangeStatsBook] = None,
                  pool_key: Optional[str] = None, memory=None):
         self.mesh = mesh
@@ -547,9 +627,21 @@ class StreamingExchange:
         self.book = book
         W = mesh.n_workers
         self.W = W
-        self.chunk_rows = _pow2(chunk_rows or DEFAULT_CHUNK_ROWS, floor=64)
         self.inflight_bytes = int(inflight_bytes or DEFAULT_INFLIGHT_BYTES)
+        if not page_capacity:
+            from ..metadata import default_page_capacity
+            page_capacity = default_page_capacity()
         self.page_capacity = page_capacity
+        # the send chunk (rows a worker), fixed HERE from what the planner
+        # knows: the page capacity the producing fragment was planned with
+        # bounds a scan's pages and an upstream exchange's receive pages
+        # (_emit_gen); a page past it takes the leftover path. Not from the
+        # first page that arrives: which worker's page that is, is timing,
+        # and another C is another set of programs. `chunk_rows` given = the
+        # tests' override
+        self.chunk_rows = derive_chunk_rows(
+            page_capacity, exchange_row_bytes(self.types), W,
+            self.inflight_bytes, int(chunk_rows or 0))
         if kind in (REPARTITION, MERGE):
             # per-peer receive slice: 2x the balanced share, floored low —
             # overflow carries over, so this only trades dispatch count
@@ -574,6 +666,7 @@ class StreamingExchange:
         # receive side: per shard length, each consumer's page being packed
         # (pump-thread private, see _deliver_part)
         self._recv: Dict[int, List[Optional[_ChunkState]]] = {}
+        self._pages_to = [0] * W   # pages handed to each consumer so far
         self._pump: Optional[threading.Thread] = None
         # pool_key set: the pump runs as generator steps on the process-wide
         # EXCHANGE_POOL under the query's fairness slot; None = a dedicated
@@ -604,6 +697,10 @@ class StreamingExchange:
                       "chunks": 0, "overlap_chunks": 0, "rows_in": 0,
                       "rows_out": 0, "pages_out": 0, "live_bytes": 0,
                       "carry_rows": 0, "compiles": 0,
+                      # fill programs the pump ran (pages appended to send
+                      # chunks + received shards packed) and, of the send
+                      # side's, those that left a leftover to feed again
+                      "fills": 0, "refills": 0,
                       "dispatch_s": 0.0, "overlap_s": 0.0, "stall_s": 0.0,
                       "partition_rows": [0] * W, "hot_keys": 0,
                       "replicated_rows": 0}
@@ -842,7 +939,7 @@ class StreamingExchange:
         for L, packing in self._recv.items():
             for w, acc in enumerate(packing):
                 if acc is not None and acc.count:
-                    yield from self._emit_gen(w, acc, L)
+                    yield from self._emit_gen(w, acc, L, last=True)
                 packing[w] = None
 
     # ------------------------------------------------------------ page intake
@@ -879,15 +976,14 @@ class StreamingExchange:
         absorb loop resolves them only when a carry entry is actually
         reached (by which point the collective has usually drained)."""
         import jax
-        import jax.numpy as jnp
 
         unknown = [entry for q in queue for entry in q
                    if entry.live is None and
                    (include_carry or not entry.is_carry)]
         if not unknown:
             return
-        counts = jax.device_get(
-            [jnp.sum(e.mask.astype(jnp.int32)) for e in unknown])
+        facts = _shard_facts_jit()
+        counts = jax.device_get([facts(e.mask, ())[0] for e in unknown])
         for e, n in zip(unknown, counts):
             e.live = int(n)
             if e.is_carry:  # a re-queued carry buffer, not a producer page
@@ -907,10 +1003,17 @@ class StreamingExchange:
     def _absorb_gen(self, state, queue, pending_delivery,
                     flush: bool = False):
         """Move queued pages into chunk buffers; dispatch whenever a worker's
-        chunk fills with more rows waiting (or, in flush mode, whenever any
-        rows remain at all). Returns the still-undelivered dispatch."""
+        next page does not fit what is left of its chunk (or, in flush mode,
+        whenever any rows remain at all). A page is never split when it fits
+        a chunk: the chunk that cannot take it whole goes out partly filled
+        (padding on the wire) and the page opens a fresh one, so the fill is
+        one prefix sum, one index scatter and a gather a column. Only a page
+        LONGER than its
+        chunk (the override, a chunk the in-flight bound capped, a carry) is
+        filled to the brim with its leftover re-queued. Returns the
+        still-undelivered dispatch."""
         C = self.chunk_rows
-        fill = _fill_chunk_jit(len(self.types), C)
+        ncols = len(self.types)
         while True:
             self._check_live()
             # resolve producer pages' live counts in one batched transfer;
@@ -924,27 +1027,38 @@ class StreamingExchange:
                         # a carry buffer reached the front: NOW its count is
                         # worth the sync (it gates further progress here)
                         self._resolve_lives(queue)
-                    qp = queue[w].pop(0)
+                    qp = queue[w][0]
+                    room = C - st.count
+                    if room < qp.live <= C:
+                        break   # fits a chunk, not this one: dispatch first
+                    queue[w].pop(0)
                     if qp.charged_bytes:
                         self._release_bytes(qp.charged_bytes)
                     if not qp.live:
                         continue
-                    nd, nn, nm, ld, ln, lm = fill(
-                        st.datas, st.nulls, st.mask, st.count,
-                        qp.datas, qp.nulls, qp.mask)
-                    st.datas, st.nulls, st.mask = nd, nn, nm
-                    absorbed = min(C - st.count, qp.live)
-                    st.count += absorbed
-                    if not qp.is_carry:
-                        self.stats["rows_in"] += absorbed
-                    if qp.live > absorbed:
+                    self.stats["fills"] += 1
+                    absorbed = min(room, qp.live)
+                    if qp.live <= room:
+                        st.datas, st.nulls, st.mask = _append_chunk_jit()(
+                            st.datas, st.nulls, st.mask, st.count,
+                            qp.datas, qp.nulls, qp.mask)
+                    else:
+                        nd, nn, nm, ld, ln, lm = _fill_chunk_jit(ncols, C)(
+                            st.datas, st.nulls, st.mask, st.count,
+                            qp.datas, qp.nulls, qp.mask)
+                        st.datas, st.nulls, st.mask = nd, nn, nm
                         # leftover goes back to the FRONT; its live count is
                         # known arithmetically — no device sync
+                        self.stats["refills"] += 1
                         queue[w].insert(0, _QueuedPage(
                             ld, ln, lm, live=qp.live - absorbed,
                             is_carry=qp.is_carry))
-            must_dispatch = any(
-                state[w].count >= C and queue[w] for w in range(self.W))
+                    st.count += absorbed
+                    if not qp.is_carry:
+                        self.stats["rows_in"] += absorbed
+            # a page still queued behind a chunk is one the chunk cannot
+            # take: full, or too little room for it whole
+            must_dispatch = any(queue)
             if not must_dispatch and flush and any(s.count for s in state):
                 must_dispatch = True
             if not must_dispatch:
@@ -1073,7 +1187,8 @@ class StreamingExchange:
         if producing:
             self.stats["overlap_chunks"] += 1
             self.stats["overlap_s"] += dt
-        span.note(chunk=chunk_no, overlap=producing)
+        span.note(chunk=chunk_no, overlap=producing,
+                  fills=self.stats["fills"], refills=self.stats["refills"])
         span.__exit__(None, None, None)
         if self.book is not None:
             self.book.bump("chunks")
@@ -1226,50 +1341,54 @@ class StreamingExchange:
         a new shape and compiled again, in a warm query. Packed, a consumer
         sees ceil(rows / length) pages whatever the timing was."""
         import jax
-        import jax.numpy as jnp
 
         W, ncols = self.W, len(self.types)
         out_len = out_mask.shape[0] // W
-        fill = _fill_chunk_jit(ncols, out_len)
         data_shards = [self._shards_by_worker(out_arrays[c], out_len)
                        for c in range(ncols)]
         null_shards = [self._shards_by_worker(out_arrays[ncols + c], out_len)
                        for c in range(ncols)]
         mask_shards = self._shards_by_worker(out_mask, out_len)
         # ONE host sync for all workers' live counts + null-mask presence
-        live_devs = [jnp.sum(m.astype(jnp.int32)) for m in mask_shards]
-        null_devs = [jnp.stack([jnp.any(null_shards[c][w])
-                                for c in range(ncols)])
-                     for w in range(W)] if ncols else []
-        synced = jax.device_get(live_devs + null_devs)
-        lives = [int(x) for x in synced[:W]]
-        has_nulls = synced[W:]
+        facts = _shard_facts_jit()
+        synced = jax.device_get([
+            facts(mask_shards[w],
+                  tuple(null_shards[c][w] for c in range(ncols)))
+            for w in range(W)])
+        lives = [int(live) for live, _has in synced]
+        has_nulls = [has for _live, has in synced]
         packing = self._recv.setdefault(out_len, [None] * W)
         for w in range(W):
             live_w = lives[w]
             if not live_w:
                 continue
-            hn = np.asarray(has_nulls[w]) if ncols else \
-                np.zeros(0, dtype=bool)
+            hn = np.asarray(has_nulls[w])
             acc = packing[w]
             if acc is None:
                 acc = packing[w] = self._fresh_chunk(w, out_len)
                 acc.has_nulls = np.zeros(ncols, dtype=bool)
-            nd, nn, nm, ld, ln, lm = fill(
-                acc.datas, acc.nulls, acc.mask, acc.count,
-                tuple(data_shards[c][w] for c in range(ncols)),
-                tuple(null_shards[c][w] for c in range(ncols)),
-                mask_shards[w])
-            acc.datas, acc.nulls, acc.mask = nd, nn, nm
+            shard = (tuple(data_shards[c][w] for c in range(ncols)),
+                     tuple(null_shards[c][w] for c in range(ncols)),
+                     mask_shards[w])
             absorbed = min(out_len - acc.count, live_w)
+            self.stats["fills"] += 1
+            if live_w == absorbed:
+                # the usual case: the shard fits the page being packed
+                acc.datas, acc.nulls, acc.mask = _append_chunk_jit()(
+                    acc.datas, acc.nulls, acc.mask, acc.count, *shard)
+                rest = None
+            else:
+                # what does not fit opens the next page (same shape, live
+                # rows packed at the front): no second fill, so no refill
+                nd, nn, nm, ld, ln, lm = _fill_chunk_jit(ncols, out_len)(
+                    acc.datas, acc.nulls, acc.mask, acc.count, *shard)
+                acc.datas, acc.nulls, acc.mask = nd, nn, nm
+                rest = _ChunkState(ld, ln, lm, live_w - absorbed, hn)
             acc.count += absorbed
             acc.has_nulls = acc.has_nulls | hn
             if acc.count >= out_len:
                 yield from self._emit_gen(w, acc, out_len)
-                # what did not fit opens the next page (same shape, live
-                # rows packed at the front)
-                packing[w] = _ChunkState(ld, ln, lm, live_w - absorbed, hn) \
-                    if live_w > absorbed else None
+                packing[w] = rest
             live_bytes = live_w * exchange_row_bytes(self.types, hn)
             self.stats["rows_out"] += live_w
             self.stats["live_bytes"] += live_bytes
@@ -1279,13 +1398,26 @@ class StreamingExchange:
                 self.book.bump("live_bytes", live_bytes)
         return sum(lives)
 
-    def _emit_gen(self, w: int, acc: _ChunkState, out_len: int):
+    def _emit_gen(self, w: int, acc: _ChunkState, out_len: int,
+                  last: bool = False):
         """Enqueue one packed receive buffer for consumer `w` as standard
         pow2 pages (parking on the queue's byte bound: a full queue parks
-        the pump STEP, never a pool worker)."""
+        the pump STEP, never a pool worker). A consumer whose WHOLE stream
+        is this one buffer (`last`, nothing handed before) gets one page cut
+        to the pow2 of its rows: the chunk is sized from the fragment's page
+        capacity, not from the rows that came, and the operators above a
+        small exchange (a final aggregation, a TopN's merge) should trace
+        the shape of their input, as they do above a short table's scan.
+        Only where the rows a consumer receives do not depend on the pumps'
+        timing, so that this length does not either: not under MERGE's
+        splitters or a skew role, which sample the first chunk."""
         cap = min(max(self.page_capacity, 1 << 9), out_len)
+        if last and not self._pages_to[w] and self.kind != MERGE \
+                and self._skew is None:
+            cap = min(cap, _pow2(acc.count, floor=MIN_CHUNK_ROWS))
         n_pages = -(-acc.count // cap)
         self.stats["pages_out"] += n_pages
+        self._pages_to[w] += n_pages
         whole = cap == out_len   # the usual case: no slice, no program
         for off in range(0, n_pages * cap, cap):
             blocks = []
@@ -1314,6 +1446,8 @@ class StreamingExchange:
             self.book.bump("stall_s", self.stats["stall_s"])
             self.book.bump("dispatch_s", self.stats["dispatch_s"])
             self.book.bump("carry_rows", self.stats["carry_rows"])
+            self.book.bump("fills", self.stats["fills"])
+            self.book.bump("refills", self.stats["refills"])
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,9 @@
 
 Differential: the mesh == the single-chip LocalQueryRunner over the same
 catalog, on every exchange kind — REPARTITION, BROADCAST, GATHER, MERGE
-(global order, dict-encoded columns). Mechanism: overflow carry-over under total key skew,
+(global order, dict-encoded columns). Mechanism: the send chunk derived from
+the fragment's page and the in-flight bound (PR 37), pages that fit a chunk never
+split, overflow carry-over under total key skew,
 producer backpressure on the in-flight byte budget (no deadlock with a slow
 consumer), clean close-while-blocked teardown, stats plumbing.
 
@@ -233,6 +235,283 @@ def test_skew_declines_when_downstream_needs_copartitioning(eight_devices):
         skewed.stats["exchange"]["per_exchange"]
 
 
+# ------------------------------------------------ the derived send chunk
+
+@pytest.mark.parametrize("page_rows,row_bytes,workers,inflight,override,want", [
+    # at the floor: a short page still sends MIN_CHUNK_ROWS-row chunks
+    (64, 8, 4, 1 << 28, 0, 1 << 12),
+    (4096, 8, 4, 1 << 28, 0, 1 << 12),
+    # at the page: the pow2 of the producing fragment's page
+    (1 << 18, 28, 4, 1 << 28, 0, 1 << 18),
+    (200_000, 28, 4, 1 << 28, 0, 1 << 18),
+    # capped by the bound: two chunks a side must fit exchange_inflight_bytes
+    # (2 x 40 B x 4 workers x 2^19 rows = 160 MiB fits 256 MiB, 2^20 not)
+    (1 << 22, 40, 4, 1 << 28, 0, 1 << 19),
+    (1 << 20, 28, 4, 1 << 28, 0, 1 << 20),
+    (1 << 20, 28, 8, 1 << 28, 0, 1 << 19),
+    (1 << 18, 8, 2, 1 << 20, 0, 1 << 15),
+    # a bound under the floor does not go under it
+    (1 << 18, 8, 2, 1 << 10, 0, 1 << 12),
+    # exchange_chunk_rows set: the override wins (pow2-rounded, floor 64)
+    (1 << 18, 28, 4, 1 << 28, 256, 256),
+    (1 << 18, 28, 4, 1 << 28, 300, 512),
+    (64, 8, 4, 1 << 10, 1, 64),
+])
+def test_derive_chunk_rows(page_rows, row_bytes, workers, inflight, override,
+                           want):
+    from presto_tpu.parallel.streaming_exchange import derive_chunk_rows
+
+    assert derive_chunk_rows(page_rows, row_bytes, workers, inflight,
+                             override) == want
+
+
+def _keyed_page(keys, live=None):
+    import jax.numpy as jnp
+
+    from presto_tpu.block import Block, Page
+    from presto_tpu.types import BIGINT
+
+    keys = np.asarray(keys, dtype=np.int64)
+    mask = np.ones(len(keys), dtype=bool) if live is None else \
+        np.asarray(live, dtype=bool)
+    return Page((Block(BIGINT, jnp.asarray(keys)),), jnp.asarray(mask))
+
+
+class _PeakMemory:
+    """Stands where the query's memory context stands: keeps the largest
+    reservation the exchange ever published."""
+
+    def __init__(self):
+        self.peak = 0
+
+    def set_bytes(self, n):
+        self.peak = max(self.peak, n)
+
+    def close(self):
+        pass
+
+
+def _run_exchange(mesh, kind, pages_by_worker, lengths=None, **kw):
+    """Feed the pages as one well-behaved producer a worker would (an add
+    only while the exchange has capacity), drain every consumer, and return
+    (the exchange, {consumer: [values received]}); `lengths` (a dict) takes
+    {consumer: [length of each page received]}."""
+    from presto_tpu.parallel.streaming_exchange import (ExchangeStatsBook,
+                                                        StreamingExchange)
+    from presto_tpu.sql.planner.plan import MERGE, REPARTITION
+    from presto_tpu.types import BIGINT
+
+    ex = StreamingExchange(
+        mesh, 7, kind, [0] if kind == REPARTITION else None, [BIGINT],
+        [None], orderings=((0, False, False),) if kind == MERGE else None,
+        book=ExchangeStatsBook(), **kw)
+    got = {w: [] for w in range(mesh.n_workers)}
+    failed = []
+
+    def consume(w):
+        buf = ex.out_buffer(w)
+        try:
+            while True:
+                page = buf.poll()
+                if page is not None:
+                    if lengths is not None:
+                        lengths.setdefault(w, []).append(page.capacity)
+                    vals = np.asarray(page.blocks[0].data)
+                    got[w].extend(vals[np.asarray(page.mask)].tolist())
+                elif buf.is_done(None):
+                    return
+                else:
+                    time.sleep(0.002)
+        except BaseException as e:  # noqa: BLE001 - surfaced by the caller
+            failed.append(e)
+
+    ex.start(n_producers=1)
+    consumers = [threading.Thread(target=consume, args=(w,), daemon=True)
+                 for w in got]
+    for t in consumers:
+        t.start()
+    try:
+        for w, pages in enumerate(pages_by_worker):
+            for page in pages:
+                deadline = time.time() + 30
+                while not ex.has_capacity() and time.time() < deadline:
+                    time.sleep(0.002)
+                assert ex.has_capacity(), "the producer stayed parked"
+                ex.add_page(w, page)
+        ex.producer_finished()
+        for t in consumers:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in consumers), \
+            "the pump did not end the stream: a consumer still waits"
+        assert not failed, failed
+    finally:
+        ex.close()
+    return ex, got
+
+
+PAGE = 1 << 12   # the derived chunk's floor: such a page is one chunk's worth
+
+
+@pytest.mark.parametrize("kind", ["REPARTITION", "BROADCAST", "GATHER",
+                                  "MERGE"])
+def test_pages_no_longer_than_the_chunk_are_never_split(eight_devices, kind):
+    """Every size derived: the chunk is the pow2 of the page the fragment
+    was planned with, a page that
+    does not fit what is left of a chunk opens the next one, so no fill
+    leaves a leftover and a fill is one program a live page sent and one a
+    shard received."""
+    from presto_tpu.sql.planner import plan
+
+    mesh = MeshContext(eight_devices[:4])
+    W = mesh.n_workers
+    rng = np.random.default_rng(37)
+    pages, sent, live_pages = [], [], 0
+    for w in range(W):
+        mine = []
+        for i in range(3):
+            keys = rng.integers(0, 1 << 40, PAGE)
+            live = rng.random(PAGE) < (0.7, 0.5, 0.0)[i]   # the third: dead
+            mine.append(_keyed_page(keys, live))
+            sent.extend(keys[live].tolist())
+            live_pages += bool(live.any())
+        pages.append(mine)
+    ex, got = _run_exchange(mesh, getattr(plan, kind), pages,
+                            page_capacity=PAGE)
+    st = ex.stats
+    assert st["chunk_rows"] == PAGE
+    assert st["refills"] == 0
+    assert st["carry_rows"] == 0
+    # 0.7 + 0.5 of a page do not fit one chunk: the second page opened its own
+    assert st["chunks"] == 2
+    assert st["rows_in"] == len(sent)
+    copies = W if kind == "BROADCAST" else 1
+    receivers = 1 if kind == "GATHER" else W
+    assert st["rows_out"] == copies * len(sent)
+    assert st["fills"] == live_pages + st["chunks"] * receivers
+    everything = sorted(v for vals in got.values() for v in vals)
+    assert everything == sorted(sent * copies)
+    if kind == "GATHER":
+        assert not any(got[w] for w in range(1, W))
+    if kind == "MERGE":     # worker order is value order
+        tops = [max(got[w]) for w in range(W) if got[w]]
+        lows = [min(got[w]) for w in range(W) if got[w]]
+        assert all(t <= lo for t, lo in zip(tops, lows[1:]))
+    snap = ex.book.snapshot()
+    assert snap["fills"] == st["fills"] and snap["refills"] == 0
+
+
+@pytest.mark.parametrize("first", ["short_page_first", "long_page_first"])
+def test_the_chunk_does_not_depend_on_which_page_comes_first(eight_devices,
+                                                             first):
+    """Splits of uneven length hand over pages of different pow2 lengths and
+    which reaches the pump first is timing: the chunk is fixed when the
+    exchange is built, so either order runs the same shapes, splits no page
+    and builds the collective once."""
+    from presto_tpu.sql.planner.plan import REPARTITION
+
+    mesh = MeshContext(eight_devices[:4])
+    rng = np.random.default_rng(5)
+    short, long_ = rng.integers(0, 1 << 40, 512), \
+        rng.integers(0, 1 << 40, PAGE)
+    order = (short, long_) if first == "short_page_first" else (long_, short)
+    pages = [[_keyed_page(v) for v in order]] + [[] for _ in range(3)]
+    ex, got = _run_exchange(mesh, REPARTITION, pages, page_capacity=PAGE)
+    st = ex.stats
+    assert (st["chunk_rows"], st["out_cap"]) == (PAGE, PAGE // 2)
+    assert st["refills"] == 0 and st["compiles"] <= 1
+    assert st["rows_in"] == st["rows_out"] == PAGE + 512
+    assert sorted(v for vals in got.values() for v in vals) == \
+        sorted(short.tolist() + long_.tolist())
+
+
+@pytest.mark.parametrize("kind,rows,want", [
+    # the whole stream fits one receive page: cut to the pow2 of its rows
+    ("GATHER", 40, [1 << 12]),
+    ("GATHER", 5000, [1 << 13]),
+    ("REPARTITION", 40, [1 << 12]),
+    # more than a page: the pages keep the length the fragment was planned at
+    ("GATHER", 3 * (1 << 14), [1 << 14, 1 << 14, 1 << 14]),
+    # MERGE's splitters sample the first chunk: its pages keep their length
+    ("MERGE", 40, [1 << 14]),
+])
+def test_a_stream_of_one_page_is_cut_to_its_rows(eight_devices, kind, rows,
+                                                 want):
+    """The chunk comes from the fragment's page, not from the rows that came:
+    a consumer whose whole stream is one page still traces a shape of its
+    input's size, and a longer stream the planned one."""
+    from presto_tpu.sql.planner import plan
+
+    mesh = MeshContext(eight_devices[:4])
+    keys = np.arange(rows) * 4
+    pages = [[_keyed_page(keys[i:i + (1 << 14)])
+              for i in range(0, rows, 1 << 14)]] + [[] for _ in range(3)]
+    lengths = {}
+    ex, got = _run_exchange(mesh, getattr(plan, kind), pages,
+                            lengths=lengths, page_capacity=1 << 14)
+    assert ex.stats["chunk_rows"] == 1 << 14
+    assert sorted(v for vals in got.values() for v in vals) == keys.tolist()
+    busiest = max(lengths, key=lambda w: len(got[w]))
+    assert len(got[busiest]) == rows or kind != "GATHER"
+    assert lengths[busiest] == want, lengths
+
+
+@pytest.mark.parametrize("keys", ["spread", "one_key"])
+def test_a_page_longer_than_its_chunk_arrives_whole(eight_devices, keys):
+    """The override path: 1024-row pages into 256-row chunks go through the
+    brim fill and its leftover; with every row on one key each chunk
+    overflows its 128-slot peer slice and the rest rides the carry."""
+    from presto_tpu.sql.planner.plan import REPARTITION
+
+    mesh = MeshContext(eight_devices[:4])
+    rng = np.random.default_rng(3)
+    pages, sent = [], []
+    for w in range(4):
+        vals = rng.integers(0, 1 << 40, 1024) if keys == "spread" \
+            else np.full(1024, 11)
+        live = rng.random(1024) < 0.9
+        pages.append([_keyed_page(vals, live)])
+        sent.extend(vals[live].tolist())
+    ex, got = _run_exchange(mesh, REPARTITION, pages, chunk_rows=256)
+    st = ex.stats
+    assert (st["chunk_rows"], st["out_cap"]) == (256, 128)
+    assert st["refills"] >= 3 * 4         # a page is four chunks' worth
+    assert st["fills"] > st["refills"]
+    assert st["rows_in"] == st["rows_out"] == len(sent)
+    assert sorted(v for vals in got.values() for v in vals) == sorted(sent)
+    if keys == "one_key":
+        assert st["carry_rows"] > 0
+        assert sorted(len(v) for v in got.values())[:3] == [0, 0, 0]
+    else:
+        assert all(got.values())
+
+
+def test_a_page_past_a_consumer_queues_bound_is_delivered(mesh2):
+    """A receive page whose bytes pass its consumer queue's bound still goes
+    in (an empty queue admits any page) and the pump ends; the reservation
+    the exchange publishes never passes exchange_inflight_bytes by more than
+    one chunk."""
+    from presto_tpu.ops.scan_pipeline import page_nbytes
+    from presto_tpu.sql.planner.plan import GATHER
+
+    rows, inflight = 1 << 15, 1 << 20
+    pages = [[_keyed_page(np.arange(rows) + (w * 8 + i) * rows)
+              for i in range(6)] for w in range(2)]
+    one_page = page_nbytes(pages[0][0])
+    memory = _PeakMemory()
+    ex, got = _run_exchange(mesh2, GATHER, pages, inflight_bytes=inflight,
+                            page_capacity=rows, memory=memory)
+    queue_bound = ex.out_buffer(0).max_bytes
+    assert one_page > queue_bound, (one_page, queue_bound)
+    assert ex.stats["chunk_rows"] == rows
+    assert ex.stats["refills"] == 0
+    assert ex.stats["rows_out"] == 12 * rows
+    assert sorted(got[0]) == sorted(
+        v for w in range(2) for p in pages[w]
+        for v in np.asarray(p.blocks[0].data).tolist())
+    one_chunk = 2 * one_page            # a chunk is a page a worker here
+    assert 0 < memory.peak <= inflight + one_chunk, memory.peak
+
+
 # ------------------------------------------------- backpressure / teardown
 
 def _exchange(mesh, **kw):
@@ -249,13 +528,7 @@ def _exchange(mesh, **kw):
 
 
 def _page(n=256, fill=1):
-    import jax.numpy as jnp
-
-    from presto_tpu.block import Block, Page
-    from presto_tpu.types import BIGINT
-
-    return Page((Block(BIGINT, jnp.full((n,), fill, dtype=jnp.int64)),),
-                jnp.ones((n,), dtype=jnp.bool_))
+    return _keyed_page(np.full(n, fill))
 
 
 def test_backpressure_blocks_and_releases(mesh2):
