@@ -557,6 +557,38 @@ class _Closed(Exception):
     """Internal pump-unwind signal for close-while-running teardown."""
 
 
+# The states of a pump. From start() to the pump's end exactly ONE is open at
+# any moment (StreamingExchange._enter), so their seconds add up to the pump's
+# life (`pump_s`) and a trace shows, for every moment a chip sat idle inside a
+# mesh query, what each exchange's host side was doing:
+#   starved       the bounded wait for producer pages
+#   sync          blocked in jax.device_get (live counts, the collective's
+#                 output, the skew sample): the pump waits for a chip
+#   fill          host Python of the send side: page intake, fill programs
+#                 enqueued, and the pump loop's own bookkeeping
+#   dispatch      _dispatch: assemble, the collective lock, the program call
+#   deliver       host Python of the receive side: packs and page cuts
+#   backpressure  parked on a consumer queue's byte bound
+#   skew_wait     waiting for the peer side's frozen hot-key set
+#   queued        yielded and runnable, waiting for a pool worker (no span:
+#                 the step that resumes the pump may run on another thread)
+# Each but `queued` is a span `presto.exchange.<name> f<fragment>`.
+PUMP_STATES = ("starved", "sync", "fill", "dispatch", "deliver",
+               "backpressure", "skew_wait", "queued")
+(STARVED, SYNC, FILL, DISPATCH, DELIVER, BACKPRESSURE, SKEW_WAIT,
+ QUEUED) = PUMP_STATES
+# a part OF dispatch, not a state beside it: the wait for
+# COLLECTIVE_DISPATCH_LOCK (an arg of the chunk_dispatch span, no span)
+LOCK_WAIT = "lock_wait"
+_STATE_SPANS = {STARVED: "pump_stall", SYNC: "pump_sync", FILL: "pump_fill",
+                DISPATCH: "chunk_dispatch", DELIVER: "chunk_deliver",
+                BACKPRESSURE: "pump_backpressure",
+                SKEW_WAIT: "pump_skew_wait"}
+# a starved pump wakes every STEP_WAIT_S: its shorter stalls stay out of
+# the ring (the profiler's trace holds them all)
+_STALL_RING_FLOOR_NS = 1_000_000
+
+
 # ---------------------------------------------------------------------------
 # the exchange itself
 # ---------------------------------------------------------------------------
@@ -700,10 +732,18 @@ class StreamingExchange:
                       # fill programs the pump ran (pages appended to send
                       # chunks + received shards packed) and, of the send
                       # side's, those that left a leftover to feed again
-                      "fills": 0, "refills": 0,
-                      "dispatch_s": 0.0, "overlap_s": 0.0, "stall_s": 0.0,
+                      "fills": 0, "refills": 0, "overlap_s": 0.0,
                       "partition_rows": [0] * W, "hot_keys": 0,
                       "replicated_rows": 0}
+        # the pump's state clock (_enter): nanoseconds a state, the state
+        # open now and since when, and its span where it has one
+        self._state_ns = dict.fromkeys(PUMP_STATES + (LOCK_WAIT,), 0)
+        self._state: Optional[str] = None
+        self._state_at = 0
+        self._started_at = 0
+        self._span = None
+        self._span_names = {st: f"{name} f{fragment_id}"
+                            for st, name in _STATE_SPANS.items()}
 
     # ------------------------------------------------------------- lifecycle
 
@@ -723,6 +763,7 @@ class StreamingExchange:
             self._cv.notify_all()
         record_exchange_stat("exchanges", 1, self.book)
         self._pump_started = True
+        self._started_at = self._enter(QUEUED)
         if self._pool_key:
             self._pool = EXCHANGE_POOL.client(self._pool_key)
             self._pool.submit(self._pump_steps())
@@ -807,6 +848,50 @@ class StreamingExchange:
 
     # -------------------------------------------------------------- the pump
 
+    def _enter(self, state: Optional[str], **args) -> Optional[int]:
+        """Close the pump's open state and open `state` (None: the pump has
+        ended). The ONE place the pump reads the clock: the nanoseconds since
+        the last change go to the state that was open, so the states add up
+        to the pump's life exactly. -> that clock reading (None where the
+        state is open already: a run of fills is one `fill`).
+
+        A state with a span opens it HERE, on the thread that runs the step,
+        and every `yield` is preceded by QUEUED (_yield), which has none: no
+        span stays open on a pool thread. With no recorder bound
+        `trace.span` hands out the shared no-op span. The wait for the
+        collective lock lies INSIDE dispatch: it leaves the chunk_dispatch
+        span open and becomes its `lock_wait_us`."""
+        was = self._state
+        if state == was:
+            return None
+        now = time.perf_counter_ns()
+        ns = now - self._state_at
+        if was is not None:
+            self._state_ns[was] += ns
+        if was == LOCK_WAIT:
+            self._span.note(lock_wait_us=ns // 1000)
+        self._state, self._state_at = state, now
+        if {was, state} == {DISPATCH, LOCK_WAIT}:
+            return now
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+        name = self._span_names.get(state)
+        if name is not None:
+            self._span = trace.span(
+                trace.EXCHANGE, name,
+                min_ns=_STALL_RING_FLOOR_NS if state == STARVED else 0,
+                **args)
+            self._span.__enter__()
+        return now
+
+    def _yield(self, status: str, resume: str, **args):
+        """One `yield` of the pump to its scheduler: QUEUED from the yield
+        to the statement after it, then `resume`."""
+        self._enter(QUEUED)
+        yield status
+        self._enter(resume, **args)
+
     def _pump_loop(self) -> None:
         """Dedicated-thread scheduler (shared_pools=False): drain the pump
         generator; its internal bounded waits provide the blocking cadence."""
@@ -819,6 +904,7 @@ class StreamingExchange:
         (a dedicated thread, or steps on the shared EXCHANGE_POOL under the
         query's fairness slot)."""
         try:
+            self._enter(FILL)
             yield from self._pump_gen()
         except _Closed:
             pass  # close() already poisoned the consumer side
@@ -843,6 +929,8 @@ class StreamingExchange:
             # even an interrupted pump (close mid-flush, producer error)
             # publishes what it measured — chunk counts bumped at dispatch
             # must never appear without their overlap/stall attribution
+            self.stats["pump_s"] = \
+                (self._enter(None) - self._started_at) / 1e9
             self._publish_stats()
             self._pump_done.set()
 
@@ -889,7 +977,6 @@ class StreamingExchange:
                 yield from self._deliver_gen(pending_delivery)
                 pending_delivery = None
             with self._cv:
-                t0 = time.perf_counter_ns()
                 waited = False
                 if not any(self._inbox) and \
                         (self._open_producers is None or
@@ -897,22 +984,20 @@ class StreamingExchange:
                         self._error is None and not self._closed:
                     # ONE bounded wait per step, not wait-until-work: a
                     # starved pump frees its pool worker every STEP_WAIT_S
-                    # in the ring from 1 ms up: real starvation
-                    with trace.span(trace.EXCHANGE,
-                                    f"pump_stall f{self.fragment_id}",
-                                    min_ns=1_000_000):
-                        self._cv.wait(timeout=STEP_WAIT_S)
+                    self._enter(STARVED)
+                    self._cv.wait(timeout=STEP_WAIT_S)
                     waited = True
-                    self.stats["stall_s"] += \
-                        (time.perf_counter_ns() - t0) / 1e9
                 drained = self._inbox
                 self._inbox = [[] for _ in range(W)]
                 producers_done = (self._open_producers is not None and
                                   self._open_producers <= 0)
             self._check_live()
             if waited and not any(drained) and not producers_done:
-                yield WAIT  # still starved: park, other queries' pumps run
+                # still starved: park, other queries' pumps run (a wake
+                # with nothing to do stays ONE pump_stall, no fill)
+                yield from self._yield(WAIT, STARVED)
                 continue
+            self._enter(FILL)
 
             # ---- ingest drained pages into the absorb queues --------------
             for w in range(W):
@@ -939,7 +1024,9 @@ class StreamingExchange:
         for L, packing in self._recv.items():
             for w, acc in enumerate(packing):
                 if acc is not None and acc.count:
-                    yield from self._emit_gen(w, acc, L, last=True)
+                    self._enter(DELIVER, chunk=self.stats["chunks"])
+                    yield from self._emit_gen(w, acc, L,
+                                              self.stats["chunks"], last=True)
                 packing[w] = None
 
     # ------------------------------------------------------------ page intake
@@ -983,7 +1070,10 @@ class StreamingExchange:
         if not unknown:
             return
         facts = _shard_facts_jit()
-        counts = jax.device_get([facts(e.mask, ())[0] for e in unknown])
+        counts = [facts(e.mask, ())[0] for e in unknown]
+        self._enter(SYNC, of="carry" if include_carry else "live")
+        counts = jax.device_get(counts)
+        self._enter(FILL)
         for e, n in zip(unknown, counts):
             e.live = int(n)
             if e.is_carry:  # a re-queued carry buffer, not a producer page
@@ -1077,10 +1167,13 @@ class StreamingExchange:
                     own = self._detect_hot(state)
                     self._skew.freeze(self._skew_role, own)
                     self.stats["hot_keys"] = int(len(own))
-                while not self._skew.wait_peer(self._skew_role,
-                                               timeout=STEP_WAIT_S):
-                    self._check_live()
-                    yield WAIT
+                if not self._skew.wait_peer(self._skew_role, timeout=0):
+                    self._enter(SKEW_WAIT)
+                    while not self._skew.wait_peer(self._skew_role,
+                                                   timeout=STEP_WAIT_S):
+                        self._check_live()
+                        yield from self._yield(WAIT, SKEW_WAIT)
+                    self._enter(FILL)
             new_pending = self._dispatch(state, queue)
             # deliver the PREVIOUS chunk now that this one is in flight —
             # its live-count sync overlaps the new in-flight collective
@@ -1088,7 +1181,8 @@ class StreamingExchange:
             if pending_delivery is not None:
                 yield from self._deliver_gen(pending_delivery)
             pending_delivery = new_pending
-            yield AGAIN  # fairness checkpoint between chunk dispatches
+            # fairness checkpoint between chunk dispatches
+            yield from self._yield(AGAIN, FILL)
 
     def _release_bytes(self, n: int) -> None:
         """A page absorbed into chunk buffers stops counting against the
@@ -1119,11 +1213,7 @@ class StreamingExchange:
         buffering)."""
         W, C = self.W, self.chunk_rows
         ncols = len(self.types)
-        t0 = time.perf_counter_ns()
-        span = trace.span(trace.EXCHANGE,
-                          f"chunk_dispatch f{self.fragment_id}", kind=self.kind,
-                          program="exchange-stream")
-        span.__enter__()
+        t0 = self._enter(DISPATCH, kind=self.kind, program="exchange-stream")
         range_keys = None
         if self.kind == MERGE:
             range_keys = self._merge_range_keys(state)
@@ -1162,7 +1252,9 @@ class StreamingExchange:
             if self.book is not None:
                 self.book.bump("collective_compiles")
         hot_out = hot_mask = hot_carry = hot_carry_mask = None
+        self._enter(LOCK_WAIT)
         with COLLECTIVE_DISPATCH_LOCK:
+            self._enter(DISPATCH)
             if self.kind == MERGE:
                 g_rk = self._assemble(range_keys, C)
                 out_arrays, out_mask, carry_arrays, carry_mask = program(
@@ -1179,17 +1271,17 @@ class StreamingExchange:
                 carry_arrays = carry_mask = None
         with self._cv:
             producing = (self._open_producers or 0) > 0
-        dt_ns = time.perf_counter_ns() - t0
-        dt = dt_ns / 1e9
         self.stats["chunks"] += 1
         chunk_no = self.stats["chunks"]
-        self.stats["dispatch_s"] += dt
+        self._span.note(chunk=chunk_no, overlap=producing,
+                        fills=self.stats["fills"],
+                        refills=self.stats["refills"])
+        # the dispatch ends here; re-queueing the carry below is the send
+        # side's bookkeeping again
+        dt = (self._enter(FILL) - t0) / 1e9
         if producing:
             self.stats["overlap_chunks"] += 1
             self.stats["overlap_s"] += dt
-        span.note(chunk=chunk_no, overlap=producing,
-                  fills=self.stats["fills"], refills=self.stats["refills"])
-        span.__exit__(None, None, None)
         if self.book is not None:
             self.book.bump("chunks")
             if producing:
@@ -1282,8 +1374,11 @@ class StreamingExchange:
             keys = [jnp.where(st.nulls[i], 0, st.datas[i]).astype(jnp.int64)
                     for i in self.key_idx]
             # chunks pack live rows at the front: [:count] is the live set
-            samples.append(np.asarray(
-                jax.device_get(combined_key(keys)))[:st.count])
+            ck = combined_key(keys)
+            self._enter(SYNC, of="hot")
+            ck = jax.device_get(ck)
+            self._enter(FILL)
+            samples.append(np.asarray(ck)[:st.count])
         pooled = np.concatenate(samples) if samples else \
             np.zeros(0, dtype=np.int64)
         if len(pooled) < SKEW_MIN_SAMPLE:
@@ -1310,24 +1405,20 @@ class StreamingExchange:
         lane (every worker holds a full copy) delivers through the same
         path as a second part."""
         out_arrays, out_mask, hot_part, dispatch_t0, chunk_no = dispatched
-        t0 = time.perf_counter_ns()
-        yield from self._deliver_part(out_arrays, out_mask)
+        self._enter(DELIVER, chunk=chunk_no)
+        yield from self._deliver_part(out_arrays, out_mask, chunk_no)
         if hot_part is not None:
             hot_arrays, hot_mask = hot_part
-            replicated = yield from self._deliver_part(hot_arrays, hot_mask)
+            replicated = yield from self._deliver_part(hot_arrays, hot_mask,
+                                                       chunk_no)
             self.stats["replicated_rows"] += replicated
         self._charge_memory()
-        end = time.perf_counter_ns()
         # per-chunk latency = dispatch issue -> pages delivered; the /v1/
         # metrics percentiles the serving roadmap needs come from here
         METRICS.histogram("exchange.chunk_latency_s",
-                          (end - dispatch_t0) / 1e9)
-        trace.record(trace.EXCHANGE, f"chunk_deliver f{self.fragment_id}",
-                     t0, end - t0,
-                     {"chunk": chunk_no}
-                     if trace.active() is not None else None)
+                          (self._enter(FILL) - dispatch_t0) / 1e9)
 
-    def _deliver_part(self, out_arrays, out_mask):
+    def _deliver_part(self, out_arrays, out_mask, chunk_no: int):
         """One output lane (regular or hot) -> consumer queues. Returns the
         total live rows delivered; per-partition counts accumulate into
         stats["partition_rows"] (the skew-spread observable).
@@ -1351,10 +1442,12 @@ class StreamingExchange:
         mask_shards = self._shards_by_worker(out_mask, out_len)
         # ONE host sync for all workers' live counts + null-mask presence
         facts = _shard_facts_jit()
-        synced = jax.device_get([
-            facts(mask_shards[w],
-                  tuple(null_shards[c][w] for c in range(ncols)))
-            for w in range(W)])
+        synced = [facts(mask_shards[w],
+                        tuple(null_shards[c][w] for c in range(ncols)))
+                  for w in range(W)]
+        self._enter(SYNC, of="deliver")
+        synced = jax.device_get(synced)
+        self._enter(DELIVER, chunk=chunk_no)
         lives = [int(live) for live, _has in synced]
         has_nulls = [has for _live, has in synced]
         packing = self._recv.setdefault(out_len, [None] * W)
@@ -1387,7 +1480,7 @@ class StreamingExchange:
             acc.count += absorbed
             acc.has_nulls = acc.has_nulls | hn
             if acc.count >= out_len:
-                yield from self._emit_gen(w, acc, out_len)
+                yield from self._emit_gen(w, acc, out_len, chunk_no)
                 packing[w] = rest
             live_bytes = live_w * exchange_row_bytes(self.types, hn)
             self.stats["rows_out"] += live_w
@@ -1399,7 +1492,7 @@ class StreamingExchange:
         return sum(lives)
 
     def _emit_gen(self, w: int, acc: _ChunkState, out_len: int,
-                  last: bool = False):
+                  chunk_no: int, last: bool = False):
         """Enqueue one packed receive buffer for consumer `w` as standard
         pow2 pages (parking on the queue's byte bound: a full queue parks
         the pump STEP, never a pool worker). A consumer whose WHOLE stream
@@ -1429,22 +1522,41 @@ class StreamingExchange:
                 blocks.append(Block(t, data, nm, d))
             page = Page(tuple(blocks),
                         acc.mask if whole else acc.mask[off:off + cap])
+            if self._out[w].try_put(page):
+                continue
+            # consumer backpressure: the queue is full, park the step
+            self._enter(BACKPRESSURE, to=w)
             while not self._out[w].try_put(page, wait_s=STEP_WAIT_S):
                 self._check_live()
-                yield WAIT  # consumer backpressure: park the step
+                yield from self._yield(WAIT, BACKPRESSURE, to=w)
+            self._enter(DELIVER, chunk=chunk_no)
 
     def _publish_stats(self) -> None:
         if self.book is not None:
+            ns = self._state_ns
+            state_s = {st: ns[st] / 1e9 for st in PUMP_STATES}
+            # the lock wait is a part of the dispatch, as it was counted
+            # before it had a name
+            state_s[DISPATCH] += ns[LOCK_WAIT] / 1e9
+            # seconds by the names they reach /v1/metrics under
+            # (`exchange.<name>`): <state>_s, but for the starved wait,
+            # which keeps the name it had
+            seconds = {"stall_s" if st == STARVED else f"{st}_s": v
+                       for st, v in state_s.items()}
+            seconds["lock_wait_s"] = ns[LOCK_WAIT] / 1e9
             entry = dict(self.stats)
-            for k in ("dispatch_s", "overlap_s", "stall_s"):
+            entry["state_s"] = {st: round(v, 6) for st, v in state_s.items()}
+            for k in ("overlap_s", "pump_s"):
                 entry[k] = round(entry[k], 6)
+            for k in ("dispatch_s", "stall_s", "lock_wait_s"):
+                entry[k] = round(seconds[k], 6)
             entry["partition_rows"] = list(self.stats["partition_rows"])
             if self._skew_role is not None:
                 entry["skew_role"] = self._skew_role
             self.book.add_exchange(entry)
             self.book.bump("overlap_s", self.stats["overlap_s"])
-            self.book.bump("stall_s", self.stats["stall_s"])
-            self.book.bump("dispatch_s", self.stats["dispatch_s"])
+            for name, s in seconds.items():
+                self.book.bump(name, s)
             self.book.bump("carry_rows", self.stats["carry_rows"])
             self.book.bump("fills", self.stats["fills"])
             self.book.bump("refills", self.stats["refills"])

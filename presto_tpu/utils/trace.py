@@ -51,7 +51,8 @@ Categories — one per instrumented subsystem:
   operator   Operator add_input/get_output (via ops.operator.timed)
   segment    fused-segment page dispatches + compile markers
   scan       scan-pipeline read/decode/upload stage work + compute stalls
-  exchange   streaming-exchange chunk dispatch/delivery + pump stalls
+  exchange   streaming-exchange pump states: stall, sync, fill, chunk
+             dispatch/delivery, back-pressure, skew wait
   kernel     kernel-cache misses (jit closure builds)
   join       a join build publishing its lookup source (ops/hash_join.py)
   planner    the join order (sql/planner/optimizer.py reorder_joins)

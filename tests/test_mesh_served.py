@@ -365,3 +365,30 @@ def test_exchange_spans_reach_the_profilers_host_plane(tmp_path, mesh_server,
                    s[3].get("qid") == qid for s in inside), name
     assert all(stats.get("program") == "exchange-stream"
                for _n, _s, _e, stats in dispatches)
+    # every state of a pump but `queued` is a span (PR 38): the blocked
+    # device_get, the send side's fills and the receive side's packs of all
+    # six exchanges lie there too, under the same rules
+    from presto_tpu.parallel.streaming_exchange import _STATE_SPANS
+
+    by_exchange = {}
+    for e in events:
+        kind, _, fragment = e[0][len("presto.exchange."):].partition(" f")
+        if e[0].startswith("presto.exchange.") and \
+                kind in _STATE_SPANS.values():
+            by_exchange.setdefault(fragment, {}).setdefault(kind, []).append(e)
+    assert sorted(by_exchange) == [str(f) for f in range(6)]
+    for fragment, kinds in by_exchange.items():
+        # (a pump whose producers never leave it waiting has no pump_stall)
+        assert {"pump_sync", "pump_fill", "chunk_deliver",
+                "chunk_dispatch"} <= set(kinds), (fragment, sorted(kinds))
+        spans = sorted(e for es in kinds.values() for e in es)
+        for name, start, end, stats in spans:
+            assert stats.get("qid") == qid, (name, stats)
+            # none is left open when the root ends
+            assert root[1] <= start <= end <= root[2], name
+            assert stats.get("parent") or any(
+                s[1] <= start and end <= s[2] for s in steps), (name, stats)
+        # ONE state at a moment: no two state spans of an exchange overlap
+        spans.sort(key=lambda e: e[1])
+        for a, b in zip(spans, spans[1:]):
+            assert a[2] <= b[1], (a, b)

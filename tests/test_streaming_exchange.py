@@ -664,3 +664,129 @@ def test_stats_and_metrics_plumbing(mesh2):
     # compile discipline: at most one collective program per (kind, shape)
     # per query — warm caches can make it zero, never more than exchanges
     assert ex.get("collective_compiles", 0) <= ex["exchanges"]
+
+
+# ------------------------------------------------------------- pump states
+#
+# Every pump carries ONE state at every moment of its life (PUMP_STATES,
+# StreamingExchange._enter): the states' seconds add up to `pump_s`, under
+# both schedulers, after an early close too, and with no recorder bound
+# entering a state builds no span.
+
+STATES_SQL = ("select c_mktsegment, count(*), sum(o_totalprice) "
+              "from customer join orders on c_custkey = o_custkey "
+              "group by c_mktsegment order by 1")
+
+
+def _assert_states_add_up(entry):
+    from presto_tpu.parallel.streaming_exchange import PUMP_STATES
+
+    assert tuple(entry["state_s"]) == PUMP_STATES, entry
+    assert all(v >= 0 for v in entry["state_s"].values()), entry
+    total = sum(entry["state_s"].values())
+    assert abs(total - entry["pump_s"]) <= max(0.02 * entry["pump_s"],
+                                               0.002), entry
+    assert entry["pump_s"] > 0
+    # the lock wait is a part OF the dispatch (rounded to 1 us each)
+    assert 0 <= entry["lock_wait_s"] <= entry["dispatch_s"] + 2e-6, entry
+    assert entry["dispatch_s"] == entry["state_s"]["dispatch"]
+    assert entry["stall_s"] == entry["state_s"]["starved"]
+
+
+@pytest.mark.parametrize("shared_pools", [True, False],
+                         ids=["pool", "dedicated_thread"])
+def test_pump_states_add_up_to_the_pumps_life(eight_devices, local,
+                                              shared_pools):
+    from presto_tpu.parallel.streaming_exchange import PUMP_STATES
+    from presto_tpu.utils.metrics import METRICS
+
+    # the name a state's seconds reach /v1/metrics and the query's stats by
+    keys = {"stall_s" if s == "starved" else f"{s}_s": s for s in PUMP_STATES}
+    names = ["exchange." + k for k in list(keys) + ["lock_wait_s"]]
+    runner = DistributedQueryRunner(
+        MeshContext(eight_devices[:4]),
+        session=_session(join_distribution_type="PARTITIONED",
+                         shared_pools=shared_pools))
+    # on its own thread a pump is never queued for a worker: what it reads
+    # there is its thread's start and the resume after each yield, some
+    # hundred microseconds. Under six test workers the machine held single
+    # thread starts up past 1 ms in three attempts running, so the bound is
+    # held by the promptest pump of an attempt, and in one of three
+    for _attempt in range(3):
+        before = {n: METRICS.counter_value(n) for n in names}
+        r = check(runner, local, STATES_SQL)
+        ex = r.stats["exchange"]
+        assert len(ex["per_exchange"]) == ex["exchanges"] >= 3
+        for entry in ex["per_exchange"]:
+            _assert_states_add_up(entry)
+        # the query's seconds are its exchanges', under the names they reach
+        # /v1/metrics by (one count_many a query)
+        for n in names:
+            key = n[len("exchange."):]
+            per = [e["state_s"][keys[key]] if key in keys else e[key]
+                   for e in ex["per_exchange"]]
+            assert ex[key] == pytest.approx(sum(per), abs=1e-5), key
+            assert METRICS.counter_value(n) - before[n] == \
+                pytest.approx(ex[key], abs=1e-5), n
+        assert ex["fill_s"] > 0 and ex["deliver_s"] > 0 and ex["sync_s"] > 0
+        queued = min(e["state_s"]["queued"] for e in ex["per_exchange"])
+        if shared_pools or queued < 0.001:
+            break
+    assert shared_pools or queued < 0.001, ex["per_exchange"]
+
+
+@pytest.mark.parametrize("pool_key", [None, "states-closed-early"],
+                         ids=["dedicated_thread", "pool"])
+def test_a_stream_closed_early_still_publishes_states_that_add_up(mesh2,
+                                                                  pool_key):
+    ex = _exchange(mesh2, pool_key=pool_key)
+    ex.start(n_producers=1)
+    ex.add_page(0, _page())
+    time.sleep(0.05)       # the pump is somewhere in the page, or starved
+    ex.close(error=ValueError("producer exploded"))
+    assert ex._pump_done.is_set()
+    (entry,) = ex.book.per_exchange
+    _assert_states_add_up(entry)
+    # nothing is left open: the clock has stopped and the span is closed
+    assert ex._state is None and ex._span is None
+
+
+@pytest.mark.parametrize("shared_pools", [True, False],
+                         ids=["pool", "dedicated_thread"])
+def test_with_no_recorder_a_pump_builds_no_span(mesh2, local, monkeypatch,
+                                                shared_pools):
+    from presto_tpu.utils import trace
+
+    built = []
+
+    class Counted(trace._Span):
+        __slots__ = ()
+
+        def __init__(self, rec, cat, name, args, min_ns=0):
+            built.append((cat, name, args))
+            super().__init__(rec, cat, name, args, min_ns)
+
+    monkeypatch.setattr(trace, "_Span", Counted)
+    sql = ("select o_orderstatus, count(*) from orders "
+           "group by o_orderstatus order by 1")
+
+    def pump_spans(**props):
+        del built[:]
+        r = check(DistributedQueryRunner(
+            mesh2, session=_session(shared_pools=shared_pools, **props)),
+            local, sql)
+        for entry in r.stats["exchange"]["per_exchange"]:
+            _assert_states_add_up(entry)      # timed all the same
+        return [(name.split(" ")[0], args) for cat, name, args in built
+                if cat == trace.EXCHANGE]
+
+    # the always-on black-box ring: every state but `queued` is a span,
+    # and says what it waited for or worked on
+    spans = pump_spans()
+    assert {"pump_fill", "pump_sync", "chunk_dispatch",
+            "chunk_deliver"} <= {name for name, _args in spans}, spans
+    assert {args["of"] for name, args in spans if name == "pump_sync"} <= \
+        {"live", "carry", "deliver", "hot"}
+    assert all(args["chunk"] >= 1 for name, args in spans
+               if name == "chunk_deliver")
+    assert pump_spans(query_blackbox=False) == []

@@ -12,7 +12,9 @@ One JSON line a grain: the first query's seconds from an EMPTY compile cache
 (the persistent cache is pointed at a fresh directory and every in-process
 cache is dropped, so each grain compiles all it runs), the warm walls, the
 collective chunks, fill programs and refills of the last warm query, the
-chunk each exchange derived, and from a profile of one more warm query the
+chunk each exchange derived, the pump seconds of that query by state (summed
+over its exchanges, and each exchange's own beside its `pump_s`: where the
+pumps' time went is WHY a warm wall moves with the grain), and from a profile of one more warm query the
 device programs over all chips, a chip's mean busy seconds and the fullest
 chip's peak HBM. The grain the mesh runs at is the one with the shortest warm
 wall whose cell still ends its cold run inside the check's limit (PR 37: the
@@ -116,6 +118,12 @@ def sweep(grains, schema, chips, scratch):
             "refills": ex.get("refills"),
             "chunk_rows": {e["fragment"]: e["chunk_rows"]
                            for e in ex.get("per_exchange", [])},
+            "state_s": {k: ex[k] for k in sorted(ex)
+                        if k.endswith("_s") and k != "overlap_s"},
+            "per_exchange": {e["fragment"]: dict(e["state_s"],
+                                                 lock_wait=e["lock_wait_s"],
+                                                 pump_s=e["pump_s"])
+                             for e in ex.get("per_exchange", [])},
             "device_programs": programs, "busy_s_a_chip": busy_s,
             # the process's peak so far: read the grains in rising order
             "peak_hbm_bytes": max((p for p in peaks if p), default=None),
