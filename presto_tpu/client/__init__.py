@@ -28,7 +28,12 @@ class Column:
 
 
 class StatementClient:
-    """One statement's lifecycle: submit -> page through results."""
+    """One statement's lifecycle: submit -> page through results.
+
+    `poll_interval_s` is the least time between two GETs of a query that is
+    not done. A server that long-polls holds such a GET until the query ends
+    (a second at the most), so the client never sleeps in front of it; one
+    that answers at once is asked again `poll_interval_s` after it was."""
 
     def __init__(self, server: str, sql: str, poll_interval_s: float = 0.05,
                  timeout_s: float = 3600.0, user: Optional[str] = None,
@@ -68,6 +73,7 @@ class StatementClient:
         payload = self._request("POST", f"{self.server}/v1/statement",
                                 self.sql.encode())
         deadline = time.time() + self.timeout_s
+        get_s = None   # how long the last GET took (None: the POST's answer)
         while True:
             if "error" in payload and payload["error"]:
                 raise QueryError(payload["error"])
@@ -82,10 +88,14 @@ class StatementClient:
             if time.time() > deadline:
                 raise TimeoutError(f"query still {self.stats.get('state')} "
                                    f"after {self.timeout_s}s")
-            state = self.stats.get("state")
-            if state in ("QUEUED", "RUNNING"):
-                time.sleep(self.poll_interval_s)
+            if get_s is not None and \
+                    self.stats.get("state") in ("QUEUED", "RUNNING"):
+                pause = self.poll_interval_s - get_s
+                if pause > 0:   # the server did not hold the GET
+                    time.sleep(pause)
+            t0 = time.monotonic()
             payload = self._request("GET", next_uri)
+            get_s = time.monotonic() - t0
 
 
 def execute(server: str, sql: str) -> List[list]:

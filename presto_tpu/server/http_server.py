@@ -7,7 +7,8 @@ QueryManager, exactly as StatementResource is thin over SqlQueryManager.
 
 Endpoints:
   POST   /v1/statement            body = SQL text -> QueryResults JSON
-  GET    /v1/statement/{id}/{tok} page `tok` (follow nextUri)
+  GET    /v1/statement/{id}/{tok} page `tok` (follow nextUri); a long-poll:
+                                  held until the query ends, a second at most
   DELETE /v1/statement/{id}/{tok} cancel
   GET    /v1/info                 server info (ServerInfoResource analogue)
   GET    /v1/query                all queries (QueryResource analogue)
@@ -179,6 +180,11 @@ class _Handler(BaseHTTPRequestHandler):
             info = self.manager.get(m.group(1))
             if info is None:
                 return self._not_found()
+            if not info.done():
+                # the long-poll, BEFORE the request's span: the park has a
+                # span of its own (protocol.long_poll), and a handler's span
+                # as long as the query would claim every second of it
+                self.manager.await_done(info)
             with trace.request("GET /v1/statement/{id}/{token}",
                                info.query_id):
                 self._send_json(self.manager.results_payload(

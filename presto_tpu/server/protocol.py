@@ -34,6 +34,10 @@ CANCELED = "CANCELED"
 
 _DONE = {FINISHED, FAILED, CANCELED}
 
+# the longest a GET is held for a query that is not done before it is
+# answered as it stands (StatementResource.java's MAX_WAIT_TIME)
+MAX_WAIT_S = 1.0
+
 
 @dataclasses.dataclass
 class QueryInfo:
@@ -65,7 +69,8 @@ class QueryInfo:
     failure_trace_path: Optional[str] = None
     # the protocol layer's open spans by name (utils/trace.Stage): `query`
     # (submit -> the GET that serves the final state), `queued` (submit ->
-    # RUNNING), `result_wait` (the final state -> the first fetch of it).
+    # RUNNING), `result_wait` (the final state -> the first fetch of it: the
+    # time a GET parked in `await_done` takes to wake and answer).
     # Whoever ends one takes it out under the manager's lock first.
     stages: Dict = dataclasses.field(default_factory=dict, repr=False)
     profiled: bool = False   # a jax.profiler trace was live at submit
@@ -102,6 +107,9 @@ class QueryManager:
         self._queries: Dict[str, QueryInfo] = {}
         self._ids = itertools.count(1)
         self._lock = threading.Lock()
+        # what a parked GET waits on (await_done): notified by _finished,
+        # the one place every final state passes, and by close()
+        self._done_cv = threading.Condition(self._lock)
         # live execution threads by query id (removed by _run on exit):
         # close() joins them so shutdown never abandons a query mid-write
         # and tests never leak engine threads across cases
@@ -195,6 +203,25 @@ class QueryManager:
         if "result_wait" not in info.stages:   # a canceled query that fails
             info.stages["result_wait"] = trace.Stage(
                 "protocol.result_wait", info.query_id, info.profiled)
+        self._done_cv.notify_all()
+
+    def await_done(self, info: QueryInfo,
+                   max_wait_s: float = MAX_WAIT_S) -> bool:
+        """The long-poll (Query.waitForResults): park the calling HTTP
+        thread until `info` reaches a final state, the manager closes or
+        `max_wait_s` have passed; the caller then answers with the state as
+        it stands. -> whether the final state ended the wait."""
+        METRICS.count("protocol.long_poll.parked")
+        stage = trace.Stage("protocol.long_poll", info.query_id,
+                            info.profiled)
+        with self._done_cv:
+            self._done_cv.wait_for(lambda: info.done() or self._closed,
+                                   max_wait_s)
+            woken = info.done()
+        if woken:
+            METRICS.count("protocol.long_poll.woken")
+        METRICS.histogram("protocol.long_poll_s", stage.end(woken=int(woken)))
+        return woken
 
     def _end_stage(self, info: QueryInfo, name: str) -> None:
         """End the named open span, once: its `query.<name>_s` observation
@@ -223,6 +250,7 @@ class QueryManager:
         with self._lock:
             self._closed = True
             live = list(self._run_threads.values())
+            self._done_cv.notify_all()   # no parked GET outlives the server
         deadline = time.monotonic() + timeout_s
         for t in live:
             t.join(timeout=max(0.0, deadline - time.monotonic()))
@@ -390,7 +418,8 @@ class QueryManager:
             payload["error"] = info.error
             return payload
         if info.state in (QUEUED, RUNNING):
-            # not ready: client polls the same token
+            # not ready, after the handler held a GET for MAX_WAIT_S (the
+            # POST's answer is not held): the client asks for the same token
             payload["nextUri"] = \
                 f"{base_uri}/v1/statement/{info.query_id}/{token}"
             return payload

@@ -58,8 +58,9 @@ Categories — one per instrumented subsystem:
   planner    the join order (sql/planner/optimizer.py reorder_joins)
   http       cluster task create/poll and exchange pulls
   pool       shared-pool generator steps (exec/shared_pools.py)
-  protocol   queued / serialize / result_wait (server/protocol.py; profiler
-             trace and /v1/metrics histograms only: the ring is the runner's)
+  protocol   queued / serialize / result_wait, and long_poll: a GET parked
+             until its query ends (server/protocol.py; profiler trace and
+             /v1/metrics histograms only: the ring is the runner's)
 """
 from __future__ import annotations
 
@@ -499,11 +500,14 @@ class Stage:
         self._ann = _annotation(name, qid) if live else None
         self.t0 = time.perf_counter_ns()
 
-    def end(self) -> float:
+    def end(self, **meta) -> float:
         """-> seconds since the start. Once: the caller takes the stage out
-        of where it keeps it before it ends it."""
+        of where it keeps it before it ends it. `meta`: what is known only
+        now, as args of the profiler's event."""
         dur = time.perf_counter_ns() - self.t0
         if self._ann is not None:
+            if meta:
+                self._ann.set_metadata(**meta)
             self._ann.__exit__(None, None, None)
         return dur / 1e9
 
