@@ -67,6 +67,12 @@ from .utils import compile_lock as _compile_lock  # noqa: E402
 
 _compile_lock.install()
 
+# Page-sized host arrays (a scan's chunks and pages) come from the malloc
+# heap, not from an mmap each (utils/hostmem.py)
+from .utils import hostmem as _hostmem  # noqa: E402
+
+_hostmem.install()
+
 from .types import (BIGINT, BOOLEAN, DATE, DOUBLE, INTEGER, REAL, SMALLINT,  # noqa: E402,F401
                     TIMESTAMP, VARCHAR, DecimalType, Type, parse_type)
 from .block import Block, Dictionary, Page, page_from_arrays, page_from_pylists  # noqa: E402,F401

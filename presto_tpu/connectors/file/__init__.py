@@ -18,7 +18,18 @@ Dictionary handling: each table exposes ONE unioned dictionary per varchar
 column (built from all files' persisted dictionaries); per-file codes remap
 to it at scan time, so files written before a dictionary grew stay valid.
 Virtual dictionaries (formatted/packed source columns) are materialized for
-the codes actually written.
+the codes actually written, unless the catalog DECLARES the table's schema
+(`FileMetadata(declared=...)`, the hive metastore's role: the stored tpch
+catalog does) and declares that very dictionary: the file then holds the bare
+codes and no dictionary, and the declared one decodes them.
+
+The narrow wire form: a PCOL header holds each integer column's min and max,
+and a pcol page source emits, PER TABLE COLUMN (the union of its files'
+statistics, so two files never give two shapes), the narrowest signed dtype
+that holds the range (`_wire_dtypes`); `ops/scan._widen_page` widens it back
+to the declared type on the device. Files keep the declared widths. A file
+page source declares no `cache_token`: files can change, so nothing of a
+table stays in `ops/scan.RESIDENT_CACHE` and every scan reads the files.
 """
 from __future__ import annotations
 
@@ -35,6 +46,7 @@ from ...block import Block, Dictionary, Page
 from ...formats.parquet import ParquetFile
 from ...formats.pcol import PcolFile, write_pcol
 from ...types import is_string
+from ...utils.batching import narrowest_int_dtype
 from ...spi.connector import (ColumnHandle, ColumnMetadata, ColumnStatistics,
                               Connector, ConnectorMetadata,
                               ConnectorPageSink, ConnectorPageSinkProvider,
@@ -97,16 +109,52 @@ class _TableInfo:
         # header for schema/rows/dict-union): split readers reuse these so
         # pipeline construction re-opens and re-parses NOTHING
         self.pcol_headers = pcol_headers or {}
+        # column -> the narrow dtype its pages leave the host in
+        self.wire_dtypes = _wire_dtypes(metadata, self.pcol_headers.values())
+
+
+def _wire_dtypes(metadata: TableMetadata, headers) -> Dict[str, np.dtype]:
+    """{column: narrow wire dtype} of a pcol table, from the UNION of its
+    files' header statistics: one dtype a table column whatever file a page
+    comes from, so a scan's pages share one shape and a warm query compiles
+    nothing. Codes of a materialized dictionary are bounded by its size (a
+    file's own codes are remapped into it); any other integer column needs a
+    min and max in every file that has rows. A column with no entry keeps its
+    stored dtype."""
+    headers = [h for h in headers if h["rows"] > 0]
+    out: Dict[str, np.dtype] = {}
+    for col in metadata.columns if headers else ():
+        entries = [next((e for e in h["columns"] if e["name"] == col.name),
+                        None) for h in headers]
+        if any(e is None or np.dtype(e["dtype"]).kind != "i" for e in entries):
+            continue
+        if col.dictionary is not None and hasattr(col.dictionary, "values"):
+            lo, hi = 0, max(len(col.dictionary) - 1, 0)
+        elif all("min" in e for e in entries):
+            lo = min(e["min"] for e in entries)
+            hi = max(e["max"] for e in entries)
+        else:
+            continue
+        narrow = narrowest_int_dtype(lo, hi)
+        if narrow is not None and narrow.itemsize < max(
+                np.dtype(e["dtype"]).itemsize for e in entries):
+            out[col.name] = narrow
+    return out
 
 
 class FileMetadata(ConnectorMetadata):
     def __init__(self, connector_id: str, base_dir: str,
-                 write_format: str = "pcol"):
+                 write_format: str = "pcol", declared=None):
         if write_format not in ("pcol", "parquet", "orc"):
             raise ValueError(f"unknown file write format {write_format!r}")
         self.connector_id = connector_id
         self.base = base_dir
         self.write_format = write_format
+        # SchemaTableName -> TableMetadata or None: the schema a catalog
+        # DECLARES for a pcol table (types and dictionaries, the metastore's
+        # part). The files then hold data alone: a varchar column may be
+        # stored as the bare codes of the declared dictionary.
+        self._declared = declared
         self._cache: Dict[SchemaTableName, _TableInfo] = {}
         self._lock = threading.Lock()
 
@@ -186,11 +234,34 @@ class FileMetadata(ConnectorMetadata):
             cols.append(ColumnMetadata(
                 e["name"], _type_from_tag(e["type"], e["scale"]),
                 dictionary=d))
+        declared = self._declared(name) if self._declared else None
+        if declared is not None:
+            cols = self._declared_columns(name, declared, cols)
         info = _TableInfo(TableMetadata(name, tuple(cols)), files, rows, sig,
                           pcol_headers=by_path)
         with self._lock:
             self._cache[name] = info
         return info
+
+    @staticmethod
+    def _declared_columns(name, declared: TableMetadata, stored) -> list:
+        """The declared columns in the place of what the files' headers
+        gave: they must name the stored columns, in order and of one type;
+        a stored dictionary must be the declared one's values (a file that
+        holds no dictionary holds the declared one's codes)."""
+        if [(c.name, c.type.name) for c in declared.columns] != \
+                [(c.name, c.type.name) for c in stored]:
+            raise RuntimeError(
+                f"table {name}: the files' columns are not the declared ones")
+        for want, have in zip(declared.columns, stored):
+            if have.dictionary is not None and (
+                    not hasattr(want.dictionary, "values")
+                    or list(have.dictionary.values)
+                    != list(want.dictionary.values)):
+                raise RuntimeError(
+                    f"table {name}: column {have.name}'s stored dictionary "
+                    "is not the declared one")
+        return list(declared.columns)
 
     def _load_external(self, name: SchemaTableName, files: List[str],
                       sig) -> _TableInfo:
@@ -285,8 +356,9 @@ class FileMetadata(ConnectorMetadata):
         # unioned at load)
         names = [c.name for c in metadata.columns]
         types = [c.type for c in metadata.columns]
+        virtual = None if self._declared else Dictionary([])
         dicts = [c.dictionary if c.dictionary is None or
-                 hasattr(c.dictionary, "values") else Dictionary([])
+                 hasattr(c.dictionary, "values") else virtual
                  for c in metadata.columns]
         if self.write_format == "parquet":
             from ...formats.parquet_writer import write_parquet
@@ -336,13 +408,16 @@ class FileMetadata(ConnectorMetadata):
 
 
 def iter_pcol_pages(path: str, names, type_of, table_dicts, capacity: int,
-                    prefilter_fn=None):
+                    prefilter_fn=None, wire_dtypes=None):
     """One pcol file -> fixed-capacity masked pages, remapping per-file
     varchar codes into the TABLE's unioned dictionaries. Shared by the file
     and raptor connectors (one implementation of the chunk loop: the file
     is opened ONCE, columns are read once and sliced per chunk).
     `prefilter_fn(pf) -> bool mask | None` runs on the open file and ANDs
-    into the row mask (the native libpcol range scan)."""
+    into the row mask (the native libpcol range scan). `wire_dtypes`
+    ({column: dtype}, the file connector's `_wire_dtypes`) names the columns
+    that leave in a narrower dtype than they are stored in."""
+    wire_dtypes = wire_dtypes or {}
     pf = PcolFile(path)
     try:
         if pf.rows == 0:
@@ -361,10 +436,12 @@ def iter_pcol_pages(path: str, names, type_of, table_dicts, capacity: int,
             blocks = []
             for cname in names:
                 data, nulls = cols[cname]
-                seg = np.array(data[lo:hi])
+                seg = data[lo:hi]
                 if cname in remap:
                     seg = remap[cname][np.clip(seg.astype(np.int32), 0,
                                                len(remap[cname]) - 1)]
+                # a copy, off the mapping, in the wire dtype where one is named
+                seg = seg.astype(wire_dtypes.get(cname, seg.dtype))
                 if n_rows < capacity:
                     seg = np.concatenate(
                         [seg, np.zeros(capacity - n_rows, dtype=seg.dtype)])
@@ -382,6 +459,11 @@ def iter_pcol_pages(path: str, names, type_of, table_dicts, capacity: int,
     finally:
         pf.close()
 
+
+# a range reader compacts its pre-filter's survivors only where they are at
+# most this share of the range (ops/coalesce.py's PASSTHROUGH_SELECTIVITY asks
+# the same of a page on the device)
+PREFILTER_COMPACT_BELOW = 0.5
 
 # CAP on rows per parallel pcol range split: binds only when the target
 # page is larger (the 4M-row accelerator capacity -> 4 ranges per page, so
@@ -411,19 +493,23 @@ def pcol_dict_remaps(columns, names, table_dicts):
 
 def read_pcol_range_chunk(path: str, names, type_of, table_dicts,
                           lo: int, hi: int, prefilter_fn=None, remaps=None,
-                          header=None):
+                          header=None, wire_dtypes=None):
     """Decode rows [lo, hi) of one pcol file into a compacted HostChunk —
     the read+decode step of the streaming scan pipeline. Opens its own
     mapping so ranges of one file are readable concurrently; all returned
     arrays are detached from the mapping before it closes. `prefilter_fn(pf,
     lo, hi) -> bool mask | None` compacts non-surviving rows away HERE, so
-    they never cost host->HBM bytes. `remaps` (pcol_dict_remaps) carries the
+    they never cost host->HBM bytes, where at most PREFILTER_COMPACT_BELOW of
+    the range survives; a range that keeps more leaves whole. `remaps` (pcol_dict_remaps) carries the
     per-file dictionary re-encodings, precomputed by the caller; None =
     derive them here (the self-contained path). `header` likewise shares one
     parsed file header across the ranges (each range still opens its own
-    mapping so reads stay concurrent)."""
+    mapping so reads stay concurrent). `wire_dtypes` as in iter_pcol_pages:
+    the narrowing IS the copy off the mapping, so a narrowed column costs one
+    pass that reads the stored width and writes the narrow one."""
     from ...ops.scan_pipeline import HostChunk
 
+    wire_dtypes = wire_dtypes or {}
     pf = PcolFile(path, header=header)
     try:
         if remaps is None:
@@ -431,7 +517,13 @@ def read_pcol_range_chunk(path: str, names, type_of, table_dicts,
         keep = None
         if prefilter_fn is not None:
             pre = prefilter_fn(pf, lo, hi)
-            if pre is not None:
+            # compacting is a gather a column: it pays in upload bytes only
+            # where it drops much of the range (TPC-H Q1's date bound keeps
+            # 98%, and compacting it tripled the readers' seconds: PERF.md
+            # section 6, PR 40). The device's filter sees every row that is
+            # left, so a range kept whole stays exact.
+            if pre is not None and np.count_nonzero(pre) <= \
+                    (hi - lo) * PREFILTER_COMPACT_BELOW:
                 keep = np.flatnonzero(pre)
         cols = []
         nulls = []
@@ -441,12 +533,12 @@ def read_pcol_range_chunk(path: str, names, type_of, table_dicts,
             rm = remaps.get(cname)
             if rm is not None:
                 seg = rm[np.clip(seg.astype(np.int32), 0, len(rm) - 1)]
-                if keep is not None:
-                    seg = seg[keep]
-            elif keep is not None:
+            # the copy off the mapping, into the wire dtype where one is
+            # named: survivors are then gathered from the narrow array
+            seg = seg.astype(wire_dtypes.get(cname, seg.dtype),
+                             copy=rm is None)
+            if keep is not None:
                 seg = seg[keep]
-            else:
-                seg = np.array(seg)  # copy off the mapping
             cols.append(np.ascontiguousarray(seg))
             if nl is None:
                 nulls.append(None)
@@ -576,7 +668,14 @@ class FilePageSource(ConnectorPageSource):
         type_of = {c.name: info.metadata.column(c.name).type
                    for c in self.columns}
         yield from iter_pcol_pages(path, names, type_of, table_dicts,
-                                   self.capacity, self._native_prefilter)
+                                   self.capacity, self._native_prefilter,
+                                   self._wire_of(info))
+
+    @staticmethod
+    def _wire_of(info) -> Dict[str, np.dtype]:
+        """The table's narrow wire dtypes; a provider that names none (the
+        hive connector's per-snapshot shim) keeps the stored ones."""
+        return getattr(info, "wire_dtypes", None) or {}
 
     def split_readers(self, target_rows: int):
         """Row-range split readers (the scan-pipeline SPI): a pcol split
@@ -614,6 +713,7 @@ class FilePageSource(ConnectorPageSource):
         rows = header["rows"]
         columns = {e["name"]: e for e in header["columns"]}
         lazy = _LazyRemaps(columns, names, table_dicts)
+        wire = self._wire_of(info)
         from ...formats.pcol import row_ranges
         step = max(1, min(int(target_rows), _RANGE_ROWS))
 
@@ -622,7 +722,7 @@ class FilePageSource(ConnectorPageSource):
                 yield read_pcol_range_chunk(path, names, type_of,
                                             table_dicts, lo, hi,
                                             self._native_prefilter,
-                                            lazy.get(), header)
+                                            lazy.get(), header, wire)
             return read
 
         return [reader(lo, hi) for lo, hi in row_ranges(rows, step)]
@@ -766,7 +866,9 @@ class FilePageSink(ConnectorPageSink):
         info = self._metadata.table_info(self._table)
         names = [c.name for c in info.metadata.columns]
         types = [c.type for c in info.metadata.columns]
-        dicts, pages = _materialize_dicts(self._pages)
+        dicts, pages = _materialize_dicts(
+            self._pages, [c.dictionary for c in info.metadata.columns]
+            if self._metadata._declared else None)
         d = self._metadata._table_dir(self._table.schema_table)
         if self._metadata.write_format == "parquet":
             from ...formats.parquet_writer import write_parquet
@@ -782,11 +884,16 @@ class FilePageSink(ConnectorPageSink):
         return [path]
 
 
-def _materialize_dicts(pages):
+def _materialize_dicts(pages, declared=None):
     """-> (per-column dictionaries, pages) ready to persist. Blocks carry
     their own dictionaries; virtual ones (formatted/packed) cannot persist,
     so the codes actually written decode to strings and re-encode through a
-    real Dictionary."""
+    real Dictionary, MAX_VARCHAR_DICTIONARY distinct values a sink at the
+    most (the strings go into the file's header, and a reader unions them at
+    plan time). `declared` (the catalog's own dictionaries, a column each)
+    keeps a column whose virtual dictionary IS the declared one as it is:
+    the file holds its codes and no dictionary (None here), which is how
+    l_comment's 60M distinct values at SF10 are stored at all."""
     ncols = len(pages[0].blocks)
     out_dicts: List[Optional[Dictionary]] = []
     out_pages = list(pages)
@@ -795,19 +902,30 @@ def _materialize_dicts(pages):
         if d is None or hasattr(d, "values"):
             out_dicts.append(d)
             continue
+        if declared is not None and declared[ci] is d:
+            out_dicts.append(None)
+            continue
         codes = np.concatenate(
             [np.asarray(p.blocks[ci].data)[np.asarray(p.mask)]
              for p in pages]).astype(np.int64)
         uniq = np.unique(codes)
+        if len(uniq) > MAX_VARCHAR_DICTIONARY:
+            raise ValueError(
+                f"column {ci}: {len(uniq)} distinct values of a virtual "
+                f"dictionary ({d!r}) in one sink, over the "
+                f"{MAX_VARCHAR_DICTIONARY} a file's header may hold; write "
+                "it through a catalog that declares the dictionary "
+                "(tpch.storage-dir) or leave the column out")
         strings = d.lookup(uniq)
         new_d = Dictionary([str(s) for s in strings])
-        code_map = {int(c): i for i, c in enumerate(uniq)}
         new_pages = []
         for p in out_pages:
             b = p.blocks[ci]
             data = np.asarray(b.data).astype(np.int64)
-            mapped = np.asarray([code_map.get(int(x), 0) for x in data],
-                                dtype=np.int32)
+            # a slot whose code was never live (padding) takes code 0
+            at = np.minimum(np.searchsorted(uniq, data), max(len(uniq) - 1, 0))
+            mapped = np.where(uniq[at] == data, at, 0).astype(np.int32) \
+                if len(uniq) else np.zeros(len(data), dtype=np.int32)
             blocks = list(p.blocks)
             blocks[ci] = Block(b.type, mapped, b.nulls, new_d)
             new_pages.append(Page(tuple(blocks), p.mask))
@@ -826,9 +944,10 @@ class FilePageSinkProvider(ConnectorPageSinkProvider):
 
 class FileConnector(Connector):
     def __init__(self, connector_id: str, base_dir: str,
-                 write_format: str = "pcol"):
+                 write_format: str = "pcol", declared=None):
         os.makedirs(base_dir, exist_ok=True)
-        self._metadata = FileMetadata(connector_id, base_dir, write_format)
+        self._metadata = FileMetadata(connector_id, base_dir, write_format,
+                                      declared)
         self._splits = FileSplitManager(connector_id, self._metadata)
         self._sources = FilePageSourceProvider(self._metadata)
         self._sinks = FilePageSinkProvider(self._metadata)

@@ -9,12 +9,27 @@ split-at-the-data scheduling (SOURCE_DISTRIBUTION).
 
 Supports pushed-down partitioning on the primary key like the reference's
 TpchNodePartitioningProvider, which lets co-partitioned scans skip the mesh exchange.
+
+A STORED catalog (`tpch.storage-dir`, `StoredTpchConnector`): the reference's benchmark
+suite runs over tables that generate_schemas/generate-tpch.py wrote once from this
+connector as columnar files (tpch_sf300_orc); here that is one catalog. It answers
+metadata and statistics as `tpch` does, writes a table's PCOL files through the file
+connector's page sink on the first lookup of its handle, and reads them through the file
+connector's split manager and page source on every scan: no `cache_token`, nothing of
+the table resident between two queries.
 """
 from __future__ import annotations
 
+import atexit
+import json
 import math
+import os
 import re
+import shutil
+import tempfile
 import threading
+import time
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -29,6 +44,9 @@ from ...spi.connector import (ColumnHandle, ColumnMetadata, ColumnStatistics, Co
                               Constraint, SchemaTableName, Split, TableHandle,
                               TableMetadata, TableStatistics)
 from ...types import BIGINT
+from ...utils import trace
+from ...utils.metrics import METRICS
+from ..file import FileConnector
 from . import generator as g
 
 SCHEMAS = {"tiny": 0.01, "sf1": 1.0, "sf10": 10.0, "sf100": 100.0, "sf300": 300.0,
@@ -225,15 +243,26 @@ GEN_CACHE = _GenCache()
 class TpchPageSource(ConnectorPageSource):
     """Generates, narrows, caches, and re-batches column chunks into FULL pages
     (exactly `capacity` live rows except the last) — page fill drives both the
-    upload efficiency and the per-page Python dispatch amortization."""
+    upload efficiency and the per-page Python dispatch amortization.
+    `wire=False` is the stored catalog's one pass over a table: the columns as
+    the generator gives them (the sink writes declared widths), nothing cached."""
 
-    def __init__(self, split: Split, columns: Sequence[ColumnHandle], page_capacity: int):
+    def __init__(self, split: Split, columns: Sequence[ColumnHandle], page_capacity: int,
+                 wire: bool = True):
         self.split = split
         self.columns = list(columns)
         name, _sf, lo, hi = split.payload
         est = (hi - lo) * 4 if name == "lineitem" else (hi - lo)
         self.capacity = clamp_capacity(est, page_capacity)
+        self._wire = wire
         self._bytes = 0
+
+    def _chunk(self, key: tuple, generate, dicts) -> Dict[str, np.ndarray]:
+        if not self._wire:
+            return generate()
+        name, sf = key[0], key[1]
+        return GEN_CACHE.get_or_generate(
+            key, lambda: _narrow_columns(name, sf, generate(), dicts))
 
     def _chunks(self, names, dicts) -> Iterator[Dict[str, np.ndarray]]:
         name, sf, lo, hi = self.split.payload
@@ -242,19 +271,15 @@ class TpchPageSource(ConnectorPageSource):
             order_step = max(1, self.capacity // 4)  # ~capacity rows per chunk
             for olo in range(lo, hi, order_step):
                 ohi = min(olo + order_step, hi)
-                yield GEN_CACHE.get_or_generate(
+                yield self._chunk(
                     ("lineitem", sf, olo, ohi, key_cols),
-                    lambda: _narrow_columns(
-                        name, sf, g.lineitem_for_orders(olo, ohi, sf, names),
-                        dicts))
+                    lambda: g.lineitem_for_orders(olo, ohi, sf, names), dicts)
         else:
             for rlo in range(lo, hi, self.capacity):
                 rhi = min(rlo + self.capacity, hi)
-                yield GEN_CACHE.get_or_generate(
+                yield self._chunk(
                     (name, sf, rlo, rhi, key_cols),
-                    lambda: _narrow_columns(
-                        name, sf, g.generate_rows(name, rlo, rhi, sf, names),
-                        dicts))
+                    lambda: g.generate_rows(name, rlo, rhi, sf, names), dicts)
 
     def __iter__(self) -> Iterator[Page]:
         name, sf, _lo, _hi = self.split.payload
@@ -262,7 +287,7 @@ class TpchPageSource(ConnectorPageSource):
         col_info = {n: (t, d) for (n, t, d) in _columns_of(name)}
         dicts = {n: d for n, (_t, d) in col_info.items()}
         wire_dtypes = {
-            n: (g.narrow_dtype(name, n, sf, dicts.get(n))
+            n: (self._wire and g.narrow_dtype(name, n, sf, dicts.get(n))
                 or col_info[n][0].np_dtype) for n in names}
         pend: List[List[np.ndarray]] = []
         pend_rows = 0
@@ -342,11 +367,169 @@ class TpchConnector(Connector):
         return self._partitioning
 
 
+# rows a stored file holds at the most: the TPU's page (2^22 rows), so a scan's
+# range readers of one page come from one or two files
+STORE_FILE_ROWS = 1 << 22
+# rows a page of the store's one pass holds: host pages, never uploaded
+STORE_PAGE_ROWS = 1 << 20
+STORED_MARK = "_STORED.json"   # written last: a table without it is written anew
+
+
+class _TableStore:
+    """The files of a stored tpch catalog: `<dir>/<schema>/<table>/*.pcol` and
+    `_STORED.json`, all columns at their declared widths, written through a
+    `FileConnector`'s page sink and read through its split manager and page
+    source. `storage_dir` None: a directory of its own, made with
+    `tempfile.mkdtemp` on the first write and removed when the process ends (no
+    run reads what another wrote, nothing lands in the checkout); a named
+    directory is the user's and is kept, its stored tables found again."""
+
+    def __init__(self, connector_id: str, storage_dir: Optional[str],
+                 metadata: "TpchMetadata"):
+        self.connector_id = connector_id
+        self._dir = storage_dir
+        self._own: Optional[str] = None   # the directory made here, if any
+        self._tpch = metadata
+        self._files: Optional[FileConnector] = None
+        self._lock = threading.Lock()     # the two maps below, never held over a write
+        self._table_locks: Dict[SchemaTableName, threading.Lock] = {}
+        self._stored: set = set()
+
+    def files(self) -> FileConnector:
+        with self._lock:
+            if self._files is None:
+                base = self._dir
+                if base is None:
+                    base = self._own = tempfile.mkdtemp(
+                        prefix="presto-tpu-tpch-")
+                    atexit.register(self.close)
+                self._files = FileConnector(self.connector_id, base,
+                                            declared=self._declared)
+            return self._files
+
+    def close(self) -> None:
+        """Remove the directory this store made for itself (never a named
+        one); the process's end calls it."""
+        with self._lock:
+            own, self._own = self._own, None
+        if own is not None:
+            shutil.rmtree(own, ignore_errors=True)
+
+    def _declared(self, name: SchemaTableName) -> Optional[TableMetadata]:
+        handle = self._tpch.get_table_handle(name)
+        return None if handle is None else self._tpch.get_table_metadata(handle)
+
+    def ensure(self, handle: TableHandle) -> None:
+        """Write the table's files unless they are there: ONCE a table, the
+        first lookup of its handle; a second caller waits for the first. The
+        caller holds no lock of the server's (planning runs in the query's own
+        thread, outside `QueryManager._lock`)."""
+        name = handle.schema_table
+        with self._lock:
+            if name in self._stored:
+                return
+            lock = self._table_locks.setdefault(name, threading.Lock())
+        with lock:
+            with self._lock:
+                if name in self._stored:
+                    return
+            mark = os.path.join(
+                self.files().metadata()._table_dir(name), STORED_MARK)
+            if not os.path.isfile(mark):
+                self._store(handle, mark)
+            with self._lock:
+                self._stored.add(name)
+
+    def _store(self, handle: TableHandle, mark: str) -> None:
+        name, sf = handle.schema_table, handle.extra[0]
+        files = self.files().metadata()
+        t0 = time.perf_counter()
+        with trace.span(trace.TPCH, "store", table=str(name)) as stored:
+            partial = files.get_table_handle(name)
+            if partial is not None:     # a write that never reached its mark
+                files.drop_table(partial)
+            files.create_table(self._tpch.get_table_metadata(handle))
+            target = files.begin_insert(files.get_table_handle(name))
+            rows = g.table_row_count(name.table, sf)
+            splits = TpchSplitManager(self.connector_id).get_splits(
+                handle, Constraint.all(), -(-rows // STORE_FILE_ROWS))
+            columns = list(self._tpch.get_column_handles(handle).values())
+            sinks = self.files().page_sink_provider()
+
+            def write(split: Split) -> tuple:
+                sink = sinks.create_page_sink(target)
+                for page in TpchPageSource(split, columns, STORE_PAGE_ROWS,
+                                           wire=False):
+                    sink.append_page(page)
+                return sink.rows_written, sink.finish()
+
+            with ThreadPoolExecutor(
+                    max_workers=min(len(splits), os.cpu_count() or 1, 8),
+                    thread_name_prefix="tpch-store") as pool:
+                done = list(pool.map(write, splits))
+            files.finish_insert(target, None)
+            paths = [p for _rows, written in done for p in written]
+            counts = {"tables": 1, "rows": sum(r for r, _w in done),
+                      "bytes": sum(os.path.getsize(p) for p in paths)}
+            with open(mark, "w") as f:
+                json.dump(dict(counts, files=len(paths)), f)
+            stored.note(rows=counts["rows"], bytes=counts["bytes"],
+                        files=len(paths))
+        METRICS.count_many(counts, prefix="tpch.store.")
+        METRICS.histogram("tpch.store_s", time.perf_counter() - t0)
+
+
+class StoredTpchMetadata(TpchMetadata):
+    """`tpch`'s metadata and statistics (so every plan is `tpch`'s), over
+    tables whose files are written on the first lookup of their handle."""
+
+    def __init__(self, connector_id: str, storage_dir: Optional[str]):
+        super().__init__(connector_id)
+        self.store = _TableStore(connector_id, storage_dir,
+                                 TpchMetadata(connector_id))
+
+    def get_table_handle(self, name: SchemaTableName) -> Optional[TableHandle]:
+        handle = super().get_table_handle(name)
+        if handle is not None:
+            self.store.ensure(handle)
+        return handle
+
+    def close(self) -> None:
+        self.store.close()
+
+
+class StoredTpchConnector(Connector):
+    """Composition: `tpch` for what a table IS, a `FileConnector` for where its
+    rows are. Splits and page sources are the file connector's own."""
+
+    def __init__(self, connector_id: str, storage_dir: Optional[str] = None):
+        self._metadata = StoredTpchMetadata(connector_id, storage_dir)
+        self._partitioning = TpchNodePartitioningProvider()
+
+    def metadata(self) -> ConnectorMetadata:
+        return self._metadata
+
+    def split_manager(self) -> ConnectorSplitManager:
+        return self._metadata.store.files().split_manager()
+
+    def page_source_provider(self) -> ConnectorPageSourceProvider:
+        return self._metadata.store.files().page_source_provider()
+
+    def node_partitioning_provider(self) -> ConnectorNodePartitioningProvider:
+        return self._partitioning
+
+    def shutdown(self) -> None:
+        self._metadata.close()
+
+
 class TpchConnectorFactory(ConnectorFactory):
     @property
     def name(self) -> str:
         return "tpch"
 
     def create(self, catalog_name: str, config: Dict[str, str]) -> Connector:
+        storage = config.get("tpch.storage-dir")
+        if storage:
+            return StoredTpchConnector(catalog_name, storage)
         return TpchConnector(catalog_name,
                              int(config.get("tpch.splits-per-node", "8")))

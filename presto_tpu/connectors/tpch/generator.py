@@ -25,6 +25,7 @@ import numpy as np
 
 from ...block import Dictionary
 from ...types import (BIGINT, DATE, INTEGER, Type, VARCHAR, WIDE_VARCHAR, DecimalType)
+from ...utils.batching import narrowest_int_dtype
 
 DEC = DecimalType(12, 2)
 
@@ -641,8 +642,4 @@ def narrow_dtype(table: str, column: str, sf: float,
         lo, hi = 0, max(len(dictionary) - 1, 0)
     else:
         return None
-    for dt in (np.int8, np.int16, np.int32):
-        info = np.iinfo(dt)
-        if info.min <= lo and hi <= info.max:
-            return np.dtype(dt)
-    return None
+    return narrowest_int_dtype(lo, hi)

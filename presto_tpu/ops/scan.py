@@ -6,10 +6,11 @@ numpy pages; this operator uploads them to the device and runs the fused
 filter+project processor so the very first device kernel already prunes.
 
 TPU-first design of the host→HBM boundary (the streaming-scan wall):
-- connectors may emit NARROW dtypes (see tpch connector `_narrow_array`) — the
-  scan widens back to each block's declared type ON DEVICE, inside the same
-  jitted program as the filter/projections, so the narrow form only exists on
-  the wire;
+- connectors may emit NARROW dtypes (the tpch connector's `_narrow_columns`
+  from its generator's static bounds, the file connector's `_wire_dtypes` from
+  its files' min/max statistics) — the scan widens back to each block's
+  declared type ON DEVICE, inside the same jitted program as the
+  filter/projections, so the narrow form only exists on the wire;
 - the staged scan pipeline (ops/scan_pipeline.py) walks the page source ahead
   of the driver: split-parallel readers decode row ranges concurrently,
   chunks re-batch into canonical device-shaped pages, and a dedicated upload

@@ -1,8 +1,9 @@
 """Staged host->HBM streaming scan pipeline.
 
 The streaming-scan wall, rebuilt as a pipeline of independent stages (the
-host side, not the chip, bounds an out-of-core stream; no cell of
-benchmark/ streams yet, PERF.md section 7):
+host side, not the chip, bounds an out-of-core stream; the cell
+`q1_sf10_files` of benchmark/ streams 60M rows a query through it and reads
+the stage counters below, PERF.md section 3):
 
     split readers (pool) -> ordered staging -> re-batch -> upload -> compute
     mmap + slice + remap    bytes-bounded      take_rows    async     driver
@@ -25,9 +26,10 @@ benchmark/ streams yet, PERF.md section 7):
   kernels see a handful of large static shapes — device occupancy stays
   high regardless of source file layout, and the XLA shape set (hence
   first-run compile count) stays small.
-- UPLOAD: a dedicated stage issues the (async) ``jax.device_put`` ahead of
-  the consumer, bounded by the same byte budget applied to uploaded pages
-  the driver has not consumed yet.
+- UPLOAD: a dedicated stage issues a page's ``jax.device_put``s ahead of
+  the consumer and waits for them there (one page on the link at a time),
+  bounded by the same byte budget applied to uploaded pages the driver has
+  not consumed yet.
 
 Scheduling: every stage is written as a GENERATOR whose each step performs
 one bounded unit of work (one chunk read / one re-batch / one upload) and
@@ -579,8 +581,12 @@ class ScanPipeline:
             page, nbytes, rows = item
             t0 = time.perf_counter_ns()
             with trace.span(trace.SCAN, "upload", rows=rows, bytes=nbytes):
-                dev = jax.tree.map(
-                    lambda a: jax.device_put(a, self._device), page)
+                # issued together, waited for here: upload_busy_s and the
+                # span are the seconds the page took to REACH the chip (its
+                # reader divides bytes by them), and the bytes the budget
+                # releases to the consumer are bytes that have arrived
+                dev = jax.block_until_ready(jax.tree.map(
+                    lambda a: jax.device_put(a, self._device), page))
             self._add("upload_busy_s", (time.perf_counter_ns() - t0) / 1e9)
             with self._stats_lock:
                 self._stats["pages"] += 1

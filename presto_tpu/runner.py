@@ -11,7 +11,7 @@ import dataclasses
 import itertools
 from typing import Dict, List, Optional
 
-from .connectors.tpch.connector import TpchConnector
+from .connectors.tpch.connector import StoredTpchConnector, TpchConnector
 from .exec.local_planner import LocalExecutionPlanner
 from .exec.task_executor import TaskExecutor
 from .metadata import CatalogManager, MetadataManager, Session
@@ -143,6 +143,10 @@ class LocalQueryRunner:
         if catalogs is None:
             catalogs = CatalogManager()
             catalogs.register("tpch", TpchConnector("tpch"))
+            # the same tables kept as columnar files and read on every
+            # query (a directory of its own, made on the first write)
+            catalogs.register("tpch_files",
+                              StoredTpchConnector("tpch_files"))
             from .connectors.tpcds import TpcdsConnector
             catalogs.register("tpcds", TpcdsConnector("tpcds"))
             from .connectors.memory import MemoryConnector

@@ -12,6 +12,10 @@ server/PluginManager.java:138). A catalog file names its connector with
         tpch.properties          # connector.name=tpch
         warehouse.properties     # connector.name=file
                                  # file.base-dir=/data/warehouse
+        tpch_files.properties    # connector.name=tpch
+                                 # tpch.storage-dir=/data/tpch  (the tables
+                                 # written there once as PCOL files and read
+                                 # on every query; the directory is kept)
 
 Factories register in FACTORIES (the PluginManager registry analogue);
 embedding code can add its own with register_connector_factory().

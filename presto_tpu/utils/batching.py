@@ -6,9 +6,22 @@ semantics can never diverge between them.
 """
 from __future__ import annotations
 
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 import numpy as np
+
+
+def narrowest_int_dtype(lo: int, hi: int) -> Optional[np.dtype]:
+    """The narrowest signed integer dtype of 1, 2 or 4 bytes that holds every
+    value of [lo, hi], None where it takes 8: the host->HBM wire form of an
+    integer column whose range is known (the tpch connector from its
+    generator's static bounds, the file connector from its files' statistics);
+    ops/scan._widen_page widens it back on the device."""
+    for dt in (np.int8, np.int16, np.int32):
+        info = np.iinfo(dt)
+        if info.min <= lo and hi <= info.max:
+            return np.dtype(dt)
+    return None
 
 
 def take_rows(pend: List[Sequence[np.ndarray]], count: int) -> List[np.ndarray]:

@@ -56,6 +56,8 @@ Categories — one per instrumented subsystem:
   kernel     kernel-cache misses (jit closure builds)
   join       a join build publishing its lookup source (ops/hash_join.py)
   planner    the join order (sql/planner/optimizer.py reorder_joins)
+  tpch       a stored tpch catalog writing a table's files, once a table
+             (connectors/tpch/connector.py `store`)
   http       cluster task create/poll and exchange pulls
   pool       shared-pool generator steps (exec/shared_pools.py)
   protocol   queued / serialize / result_wait, and long_poll: a GET parked
@@ -84,6 +86,7 @@ EXCHANGE = "exchange"
 KERNEL = "kernel"
 JOIN = "join"
 PLANNER = "planner"
+TPCH = "tpch"
 HTTP = "http"
 POOL = "pool"
 
