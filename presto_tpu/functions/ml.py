@@ -165,7 +165,7 @@ def _learn_resolver(classifier: bool):
             VARCHAR,
             [StateColumn(np.dtype(np.float64), SUM, 0.0, width=width)],
             input_map, final_map,
-            splittable=False, output_dict=model_dict)
+            splittable=False, output_dict=model_dict, host_final_map=True)
     return resolve
 
 

@@ -83,6 +83,9 @@ class AggregateFunction:
     null_skip_channels: Optional[tuple] = None
     # input_map is called as input_map(args, arg_null_masks, mask) when set
     needs_arg_nulls: bool = False
+    # final_map runs on the HOST, numpy in and out (it fills output_dict:
+    # ml's learn_*); every other final_map is traced into the result program
+    host_final_map: bool = False
 
 
 def _ones_i64(args, mask):

@@ -84,7 +84,7 @@ def _collect_combined(keys, mask, contribs, cols, kinds, identities, widths):
 class CollectAggregationBuilder:
     """Keeps all input rows; one sorted pass at finish (see module doc)."""
 
-    compact_table = True
+    result_rows = None  # a compact table whose live rows are data
 
     def __init__(self, key_types: Sequence[Type], key_dicts, calls:
                  Sequence[AggregateCall], page_capacity: int,
@@ -212,16 +212,17 @@ class CollectAggregationBuilder:
             if not self.user_key_types:
                 # global collect over empty input: one all-NULL group
                 return self._global_empty()
-            z = tuple(jnp.zeros(0, dtype=t.np_dtype) for t in self.key_types)
+            # numpy: they reach the device as the result program's arguments
+            z = tuple(np.zeros(0, dtype=t.np_dtype) for t in self.key_types)
             states = []
             for call in self.calls:
                 if call.function.name in COLLECT_NAMES:
-                    states.append(jnp.zeros(0, dtype=np.int32))
+                    states.append(np.zeros(0, dtype=np.int32))
                     continue
                 for col in call.function.state:
                     shape = (0, col.width) if col.width > 1 else (0,)
-                    states.append(jnp.zeros(shape, dtype=np.dtype(col.dtype)))
-            return z, tuple(states), jnp.zeros(0, dtype=jnp.bool_)
+                    states.append(np.zeros(shape, dtype=np.dtype(col.dtype)))
+            return z, tuple(states), np.zeros(0, dtype=np.bool_)
 
         keys = _null_safe_keys(page, self._key_channels) \
             if self._key_channels else \
@@ -327,11 +328,11 @@ class CollectAggregationBuilder:
         states = []
         for call in self.calls:
             if call.function.name in COLLECT_NAMES:
-                states.append(jnp.full(1, -1, dtype=np.int32))
+                states.append(np.full(1, -1, dtype=np.int32))
             else:
                 for col in call.function.state:
                     w = col.width
-                    arr = jnp.full((1, w) if w > 1 else (1,), col.identity,
-                                   dtype=np.dtype(col.dtype))
+                    arr = np.full((1, w) if w > 1 else (1,), col.identity,
+                                  dtype=np.dtype(col.dtype))
                     states.append(arr)
-        return (), tuple(states), jnp.ones(1, dtype=jnp.bool_)
+        return (), tuple(states), np.ones(1, dtype=np.bool_)

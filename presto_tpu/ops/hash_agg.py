@@ -120,8 +120,10 @@ def _state_widths(calls) -> Tuple[int, ...]:
 
 
 def _empty_state(kind_count_widths):
-    """Zero-group state arrays matching each column's width."""
-    return tuple(jnp.zeros((0, w) if w > 1 else 0, dtype=np.float64)
+    """Zero-group state arrays matching each column's width (numpy: an
+    eager jnp.zeros is a program of its own; these reach the device as
+    arguments of the result's program)."""
+    return tuple(np.zeros((0, w) if w > 1 else 0, dtype=np.float64)
                  for w in kind_count_widths)
 
 
@@ -201,10 +203,6 @@ def _where_valid(gvalid, s, ident):
     """Identity-fill invalid group slots, broadcasting over vector states."""
     cond = gvalid[:, None] if s.ndim == 2 else gvalid
     return jnp.where(cond, s, jnp.asarray(ident, dtype=s.dtype))
-
-
-def _fill(shape, dtype, value):
-    return jnp.full(shape, value, dtype=dtype)
 
 
 def _null_safe_keys(page: Page, key_channels) -> Tuple:
@@ -359,7 +357,9 @@ def _key_row_hash(keys) -> np.ndarray:
 class GroupedAggregationBuilder:
     """Sort-strategy accumulator (InMemoryHashAggregationBuilder analogue)."""
 
-    compact_table = True  # finish() returns a prefix-valid table
+    # finish() returns a prefix-valid table whose live rows are data: the
+    # operator reads their count (HashAggregationOperator._build_result)
+    result_rows = None
 
     def __init__(self, key_types: Sequence[Type], key_dicts: Sequence[Optional[Dictionary]],
                  calls: Sequence[AggregateCall], page_capacity: int,
@@ -664,8 +664,7 @@ class GroupedAggregationBuilder:
         keys, states = self._host_merge_parts(parts)
         n = len(keys[0]) if keys else 0
         if n == 0:
-            z = tuple(jnp.zeros(0, dtype=t.np_dtype) for t in self.key_types)
-            return z, _empty_state(self.widths), jnp.zeros(0, dtype=jnp.bool_)
+            return self._no_groups()
         return tuple(keys), tuple(states), np.ones(n, dtype=bool)
 
     def _host_merge_parts(self, parts):
@@ -864,21 +863,23 @@ class GroupedAggregationBuilder:
         for run in runs:
             mgr.release(run)
         if total == 0:
-            z = tuple(jnp.zeros(0, dtype=t.np_dtype) for t in self.key_types)
-            return z, _empty_state(self.widths), jnp.zeros(0, dtype=jnp.bool_)
+            return self._no_groups()
         return (tuple(np.concatenate(c) for c in out_keys),
                 tuple(np.concatenate(c) for c in out_states),
                 np.ones(total, dtype=bool))
 
+    def _no_groups(self):
+        """(keys, states, valid) of zero groups, in numpy (see _empty_state)."""
+        z = tuple(np.zeros(0, dtype=t.np_dtype) for t in self.key_types)
+        return z, _empty_state(self.widths), np.zeros(0, dtype=np.bool_)
+
     def finish(self):
-        """-> (keys, states, valid) on device, compact."""
+        """-> (keys, states, valid), compact: on device, or in host RAM
+        after a spill."""
         if self._pending or self._acc is None:
             if not self._pending and self._acc is None \
                     and not self._spilled and not self._disk_runs:
-                # empty input: zero groups
-                z = tuple(jnp.zeros(0, dtype=t.np_dtype) for t in self.key_types)
-                return z, _empty_state(self.widths), \
-                    jnp.zeros(0, dtype=jnp.bool_)
+                return self._no_groups()  # empty input
             if self._pending:
                 self._fold(final=True)
         if self._spilled or self._disk_runs:
@@ -895,23 +896,75 @@ class GroupedAggregationBuilder:
         return out
 
 
-@functools.partial(jax.jit, static_argnames=("cap", "dtypes"))
-def _slice_result_page(arrs, nulls, valid, lo, cap, dtypes):
-    """Assemble one output page: per-column [lo, lo+cap) slice, pad, and
-    dtype cast, in a single dispatch (the eager-slice loop cost one device
-    round-trip per column)."""
-    def seg(a, dt):
+def _cut_page(arrs, nulls, valid, lo, cap):
+    """One output page: every column's [lo, lo+cap) slice, zero-padded."""
+    def seg(a):
         n = a.shape[0]
         padded = jnp.concatenate([a, jnp.zeros(cap, dtype=a.dtype)])
         return jax.lax.dynamic_slice_in_dim(
             padded, jnp.clip(lo, 0, n), cap)
 
-    datas = tuple(seg(a, dt).astype(dt)
-                  for a, dt in zip(arrs, dtypes))
-    nmasks = tuple(None if nl is None else seg(nl, jnp.bool_)
-                   for nl in nulls)
-    m = seg(valid, jnp.bool_)
-    return datas, nmasks, m
+    datas = tuple(seg(a) for a in arrs)
+    nmasks = tuple(None if nl is None else seg(nl) for nl in nulls)
+    return datas, nmasks, seg(valid)
+
+
+# a page of a result whose size is data: one dispatch for all its columns
+_slice_result_page = jax.jit(_cut_page, static_argnames=("cap",))
+
+
+def _result_columns(calls, step, dtypes, keys, states, orders, finals):
+    """Traced: every output column as (data in its type, null mask or None):
+    the group keys (their flags as masks), then per call its state columns
+    (PARTIAL) or its final map's output under the (data, null_mask)
+    contract, `finals[i]` where call i's final map ran on the host.
+    `orders[i]` is the rank -> code map of call i's unsorted dictionary,
+    whose min/max states hold RANKS (_call_contributions)."""
+    cols = [(keys[i], keys[i + 1]) for i in range(0, len(keys), 2)]
+    si = 0
+    for call, order, final in zip(calls, orders, finals):
+        ncols = len(call.function.state)
+        group_states = states[si: si + ncols]
+        si += ncols
+        if step == PARTIAL:
+            cols.extend((s, None) for s in group_states)
+            continue
+        out = call.function.final_map(group_states) if final is None \
+            else final
+        nulls = None
+        if isinstance(out, tuple):
+            out, nulls = out
+        if order is not None:
+            # empty groups clip to an arbitrary code; their null flag masks
+            # them. States may arrive as f64 from the mesh exchange's
+            # common-dtype collectives: index with ints
+            out = order[jnp.clip(out, 0, order.shape[0] - 1
+                                 ).astype(jnp.int32)]
+        cols.append((out, nulls))
+    return (tuple(jnp.asarray(d, dtype=dt) for (d, _n), dt in zip(cols, dtypes)),
+            tuple(n for _d, n in cols))
+
+
+def _static_result(decode, calls, step, dtypes, rows, cap, state, orders,
+                   finals):
+    """The whole result of a builder whose size is static (`rows`), as ONE
+    program: the builder's decode, every final map and cast, and every page
+    cut. Nothing is read back to the host."""
+    keys, states, valid = decode(state)
+    arrs, nulls = _result_columns(calls, step, dtypes, keys, states, orders,
+                                  finals)
+    return tuple(_cut_page(arrs, nulls, valid, lo, cap)
+                 for lo in range(0, max(rows, 1), cap))
+
+
+def _result_epilogue(calls, step, dtypes, keys, states, valid, orders,
+                     finals):
+    """A result whose size is data: its columns, the live group count and
+    whether each key holds a NULL, in one program, for ONE host read."""
+    arrs, nulls = _result_columns(calls, step, dtypes, keys, states, orders,
+                                  finals)
+    any_null = tuple(jnp.any(keys[i]) for i in range(1, len(keys), 2))
+    return arrs, nulls, jnp.count_nonzero(valid), any_null
 
 
 @functools.partial(jax.jit, static_argnames=("kinds", "identities",
@@ -949,8 +1002,6 @@ class DirectAggregationBuilder:
 
     BigintGroupByHash analogue; domain = product of per-key dictionary/domain sizes."""
 
-    compact_table = False  # domain-indexed table: valid mask has holes
-
     def __init__(self, key_types, key_dicts, domains: Sequence[int], calls,
                  from_intermediate: bool = False):
         self.key_types = list(key_types)
@@ -962,6 +1013,9 @@ class DirectAggregationBuilder:
         self.calls = list(calls)
         self.from_intermediate = from_intermediate
         self.D = int(np.prod(self.domains))
+        # the result is the whole domain-indexed table, holes and all: its
+        # size is static, so the operator finishes it in one program
+        self.result_rows = self.D
         self.kinds = tuple(col.reduce for c in calls for col in c.function.state)
         self.identities = tuple(col.identity for c in calls for col in c.function.state)
         self.widths = _state_widths(calls)
@@ -995,16 +1049,24 @@ class DirectAggregationBuilder:
             SUM, mask.astype(jnp.int32), gid, self.D + 1)[: self.D] > 0)
         return tuple(new_table), new_seen
 
+    def _identity(self):
+        """The empty (table, seen) accumulator (the init program's body)."""
+        table = tuple(
+            jnp.full((self.D, col.width) if col.width > 1 else (self.D,),
+                     col.identity, dtype=np.dtype(col.dtype))
+            for c in self.calls for col in c.function.state)
+        return table, jnp.zeros(self.D, dtype=jnp.bool_)
+
     def init_state(self):
-        """(table, seen) accumulator, materialized on first use — threaded
+        """(table, seen) accumulator, made on first use by ONE cached
+        program (an eager fill was two programs a state column) — threaded
         through the page kernel as jit arguments (fused segments pass it the
         same way)."""
         if self._table is None:
-            self._table = tuple(
-                _fill((self.D, col.width) if col.width > 1 else (self.D,),
-                      np.dtype(col.dtype), col.identity)
-                for c in self.calls for col in c.function.state)
-            self._seen = jnp.zeros(self.D, dtype=jnp.bool_)
+            make = kernel_cache.get_or_install(
+                _builder_key("direct-init", self),
+                lambda: jax.jit(self._identity))
+            self._table, self._seen = make()
         return self._table, self._seen
 
     def absorb_state(self, state) -> None:
@@ -1023,18 +1085,21 @@ class DirectAggregationBuilder:
         table, seen = self.init_state()
         self.absorb_state(self._kernel(page, table, seen))
 
-    def finish(self):
-        if self._table is None:
-            z = tuple(x for t in self.key_types
-                      for x in (jnp.zeros(0, dtype=t.np_dtype),
-                                jnp.zeros(0, dtype=jnp.bool_)))
-            s = tuple(jnp.zeros(0, dtype=np.float64) for _ in self.kinds)
-            return z, s, jnp.zeros(0, dtype=jnp.bool_)
-        # decode linear gid back to interleaved (value, null_flag) key columns
-        D = self.D
-        idx = jnp.arange(D, dtype=jnp.int32)
+    def take_state(self):
+        """Hand the accumulator over (the identity if no page came): the
+        builder keeps no device reference to it (see
+        GroupedAggregationBuilder.finish)."""
+        state = self.init_state()
+        self._table = self._seen = None
+        return state
+
+    def decode(self, state):
+        """(table, seen) -> (keys, states, valid): the linear group id
+        decoded back to interleaved (value, null_flag) key columns. Traced
+        inside the operator's result program."""
+        table, seen = state
+        rem = jnp.arange(self.D, dtype=jnp.int32)
         pairs = []
-        rem = idx
         for base, dom, t in zip(reversed(self.base_domains),
                                 reversed(self.domains),
                                 reversed(self.key_types)):
@@ -1043,13 +1108,16 @@ class DirectAggregationBuilder:
             pairs.append((jnp.where(flag, 0, code).astype(t.np_dtype), flag))
             rem = rem // dom
         keys = tuple(x for v, f in reversed(pairs) for x in (v, f))
-        table, seen = self._table, self._seen
-        self._table = self._seen = None  # see GroupedAggregationBuilder.finish
         return keys, table, seen
+
+    def finish(self):
+        return self.decode(self.take_state())
 
 
 class GlobalAggregationBuilder:
     """No GROUP BY: scalar states (AggregationOperator analogue)."""
+
+    result_rows = 1  # static: the operator finishes it in one program
 
     def __init__(self, calls: Sequence[AggregateCall], from_intermediate: bool = False):
         self.calls = list(calls)
@@ -1113,15 +1181,19 @@ class GlobalAggregationBuilder:
         return state
 
     def _identity_state(self):
+        """The empty state (the init program's body)."""
         return tuple(
-            jnp.full((col.width,), col.identity, dtype=np.dtype(col.dtype))
-            if col.width > 1 else
-            jnp.asarray(col.identity, dtype=np.dtype(col.dtype))
+            jnp.full((col.width,) if col.width > 1 else (), col.identity,
+                     dtype=np.dtype(col.dtype))
             for c in self.calls for col in c.function.state)
 
     def init_state(self):
+        """The state, made on first use by ONE cached program."""
         if self._state is None:
-            self._state = self._identity_state()
+            make = kernel_cache.get_or_install(
+                _builder_key("global-init", self),
+                lambda: jax.jit(self._identity_state))
+            self._state = make()
         return self._state
 
     def absorb_state(self, state) -> None:
@@ -1134,14 +1206,22 @@ class GlobalAggregationBuilder:
                 lambda: jax.jit(self._accumulate))
         self.absorb_state(self._kernel(page, self.init_state()))
 
-    def finish(self):
-        if self._state is None:
-            self._state = self._identity_state()
-        keys = ()
-        states = tuple(jnp.reshape(s, (1, -1) if s.ndim else (1,))
-                       for s in self._state)
+    def take_state(self):
+        """Hand the state over (the identity if no page came)."""
+        state = self.init_state()
         self._state = None  # see GroupedAggregationBuilder.finish
-        return keys, states, jnp.ones(1, dtype=jnp.bool_)
+        return state
+
+    @staticmethod
+    def decode(state):
+        """state -> (keys, states, valid) of one group (traced inside the
+        operator's result program)."""
+        states = tuple(jnp.reshape(s, (1, -1) if s.ndim else (1,))
+                       for s in state)
+        return (), states, jnp.ones(1, dtype=jnp.bool_)
+
+    def finish(self):
+        return self.decode(self.take_state())
 
 
 # ---------------------------------------------------------------------------
@@ -1233,73 +1313,91 @@ class HashAggregationOperator(Operator):
         if self._result_pages is None:
             self._build_result()
 
-    def _build_result(self) -> None:
-        keys, states, valid = self.builder.finish()
-        self.context.revocable_memory.set_bytes(0)  # builder state consumed
-        pages: List[Page] = []
-        # sort-builder tables are compact (valid is a prefix): trim to live groups.
-        # direct-builder tables are domain-indexed with holes: keep the full (small)
-        # table and let the page masks carry liveness.
-        if getattr(self.builder, "compact_table", True):
-            # count on host: result building runs once per query, and the
-            # eager jnp.sum dispatch compiled a kernel per valid-shape
-            total = int(np.asarray(valid).sum())
-        else:
-            total = int(valid.shape[0])
-        # size result pages to the live groups' pow2 bucket: at the
-        # accelerator page capacity (1 << 22) a 4-group result padded to a
-        # full page made every downstream sort and the result transfer
-        # scale with the padding
-        cap = clamp_capacity(total, self.output_capacity)
-        # final transform per aggregate
-        out_cols: List[Tuple] = []  # (type, data, dictionary, nulls)
-        # builders return interleaved (value, null_flag) arrays per key column
-        for i, (t, d) in enumerate(zip(self.key_types, self.key_dicts)):
-            kv, kf = keys[2 * i], keys[2 * i + 1]
-            nulls = kf if bool(np.asarray(kf).any()) else None
-            out_cols.append((t, kv, d, nulls))
-        si = 0
+    def _rank_orders(self) -> Tuple:
+        """Per call: the rank -> code map of the unsorted varchar dictionary
+        whose min/max reduced sort RANKS (see _call_contributions), else
+        None. An argument of the result's program, not a trace constant."""
+        out = []
         for call in self.calls:
+            d = call.output_dictionary
+            ranked = (self.step != PARTIAL and d is not None
+                      and call.function.name in ("min", "max")
+                      and not d.is_sorted())
+            out.append(d.sort_order() if ranked else None)
+        return tuple(out)
+
+    def _host_finals(self, states_of) -> Tuple:
+        """Per call: the (data, nulls) its final map returned on the host
+        (AggregateFunction.host_final_map), else None. `states_of()` gives
+        the finished states; it is asked only where such a call is."""
+        host = [self.step != PARTIAL and c.function.host_final_map
+                for c in self.calls]
+        if not any(host):
+            return (None,) * len(self.calls)
+        states, out, si = states_of(), [], 0
+        for call, on_host in zip(self.calls, host):
             ncols = len(call.function.state)
-            group_states = states[si: si + ncols]
+            r = call.function.final_map(states[si: si + ncols]) \
+                if on_host else None
+            out.append(r if r is None or isinstance(r, tuple) else (r, None))
             si += ncols
+        return tuple(out)
+
+    def _build_result(self) -> None:
+        """The result pages. The builder's size decides the path: static
+        (the direct builder's D slots, the global builder's one row) -> ONE
+        cached program decodes, finishes, casts and cuts every page, and the
+        host reads nothing; data (the sort and collect builders' compact
+        tables) -> one program for the columns, the live count and each
+        key's any-NULL, ONE read of those, then a program a page."""
+        layout = list(zip(self.key_types, self.key_dicts))
+        for call in self.calls:
             if self.step == PARTIAL:
-                for it, s in zip(call.function.intermediate_types, group_states):
-                    out_cols.append((it, s, None, None))
+                layout.extend((it, None)
+                              for it in call.function.intermediate_types)
             else:
-                out = call.function.final_map(group_states)
-                nulls = None
-                if isinstance(out, tuple):  # (data, null_mask) contract
-                    out, nulls = out
-                d = call.output_dictionary
-                if call.function.name in ("min", "max") and d is not None \
-                        and not d.is_sorted():
-                    # states held sort RANKS (see _call_contributions): map the
-                    # winning rank back to its dictionary code (empty groups
-                    # clip to an arbitrary code; their null flag masks them)
-                    order = jnp.asarray(d.sort_order())
-                    # states may arrive as f64 from the mesh exchange's
-                    # common-dtype collectives: index with ints
-                    out = order[jnp.clip(out, 0, len(order) - 1
-                                         ).astype(jnp.int32)]
-                out_cols.append((call.function.output_type,
-                                 jnp.asarray(out, dtype=call.function.output_type.np_dtype),
-                                 d, nulls))
-        dtypes = tuple(np.dtype(t.np_dtype) for (t, _a, _d, _n) in out_cols)
-        arrs = tuple(a for (_t, a, _d, _n) in out_cols)
-        nulls_in = tuple(n for (_t, _a, _d, n) in out_cols)
-        for lo in range(0, max(total, 1), cap):
-            # one fused dispatch assembles the whole output page (slice +
-            # pad + dtype cast across every column)
-            datas, nmasks, m = _slice_result_page(
-                arrs, nulls_in, valid, jnp.asarray(lo, jnp.int32), cap,
-                dtypes)
-            blocks = [Block(t, dd, nn, d) for (t, _a, d, _n), dd, nn
-                      in zip(out_cols, datas, nmasks)]
-            pages.append(Page(tuple(blocks), m))
-            if total == 0:
-                break
-        self._result_pages = pages
+                layout.append((call.function.output_type,
+                               call.output_dictionary))
+        dtypes = tuple(np.dtype(t.np_dtype) for t, _d in layout)
+        calls = tuple(self.calls)
+        orders = self._rank_orders()
+        rows = self.builder.result_rows
+        if rows is not None:
+            cap = clamp_capacity(rows, self.output_capacity)
+            program = kernel_cache.get_or_install(
+                _builder_key("result", self.builder) + (self.step, cap),
+                lambda: jax.jit(functools.partial(
+                    _static_result, self.builder.decode, calls, self.step,
+                    dtypes, rows, cap)))
+            state = self.builder.take_state()
+            finals = self._host_finals(lambda: self.builder.decode(state)[1])
+            pages = program(state, orders, finals)
+            METRICS.count("agg.result.fused")
+            METRICS.count("agg.result.host_reads", 0)
+        else:
+            keys, states, valid = self.builder.finish()
+            epilogue = kernel_cache.get_or_install(
+                _builder_key("epilogue", self.builder) + (self.step,),
+                lambda: jax.jit(functools.partial(
+                    _result_epilogue, calls, self.step, dtypes)))
+            arrs, nulls, total, any_null = epilogue(
+                keys, states, valid, orders, self._host_finals(lambda: states))
+            total, any_null = jax.device_get((total, any_null))
+            METRICS.count("agg.result.host_reads")
+            nulls = tuple(None if i < len(any_null) and not any_null[i]
+                          else n for i, n in enumerate(nulls))
+            # size result pages to the live groups' pow2 bucket: at the
+            # accelerator page capacity (1 << 22) a 4-group result padded to
+            # a full page made every downstream sort and the result
+            # transfer scale with the padding
+            cap = clamp_capacity(int(total), self.output_capacity)
+            pages = [_slice_result_page(arrs, nulls, valid, np.int32(lo), cap)
+                     for lo in range(0, max(int(total), 1), cap)]
+        self.context.revocable_memory.set_bytes(0)  # builder state consumed
+        self._result_pages = [
+            Page(tuple(Block(t, dd, nn, d) for (t, d), dd, nn
+                       in zip(layout, datas, nmasks)), m)
+            for datas, nmasks, m in pages]
 
 
 def make_builder(key_types, key_dicts, key_domains, calls, page_capacity,

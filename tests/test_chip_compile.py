@@ -147,6 +147,18 @@ def test_scan_and_aggregate_compile(one_chip, programs, name):
         _compile(one_chip, fn, args, kwargs)
 
 
+@pytest.mark.parametrize("name,count", [("_static_result", 2),
+                                        ("_result_epilogue", 1)])
+def test_result_program_compiles(one_chip, programs, name, count):
+    """The aggregation's result is one program (PR 41): Q6's one row and
+    Q1's direct table decoded, finished, cast and cut into its page; Q3's
+    sort-builder columns finished with their live count and NULL flags."""
+    found = programs.named(name)
+    assert len(found) == count
+    for fn, args, kwargs in found:
+        _compile(one_chip, fn, args, kwargs)
+
+
 @pytest.mark.parametrize("name", ["_live_key_range", "_fused_build_dense"])
 def test_join_build_compiles(one_chip, programs, name):
     """Q3's two builds take the direct-address table (PR 30): the range read
