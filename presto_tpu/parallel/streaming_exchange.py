@@ -87,7 +87,15 @@ _STATS_LOCK = threading.Lock()
 class ExchangeStatsBook:
     """Per-query exchange counters (rolled into QueryResult.stats["exchange"]
     and flushed to /v1/metrics as `exchange.*`). Thread-safe: producer
-    drivers, the pump threads and the runner all write concurrently."""
+    drivers, the pump threads and the runner all write concurrently.
+
+    Beside the totals, each exchange's entry is summed by its kind:
+    `<kind>.live_bytes|rows|chunks|pump_s` (`exchange.broadcast.live_bytes`
+    on /v1/metrics), so what the broadcasts, the merge or the gather cost
+    can be read apart from the repartitions."""
+
+    BY_KIND = {"live_bytes": "live_bytes", "rows": "rows_out",
+               "chunks": "chunks", "pump_s": "pump_s"}
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -101,6 +109,9 @@ class ExchangeStatsBook:
     def add_exchange(self, entry: dict) -> None:
         with self._lock:
             self.per_exchange.append(entry)
+            for name, field in self.BY_KIND.items():
+                key = f"{entry['kind']}.{name}"
+                self.counters[key] = self.counters.get(key, 0) + entry[field]
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -563,7 +574,9 @@ class _Closed(Exception):
 # mesh query, what each exchange's host side was doing:
 #   starved       the bounded wait for producer pages
 #   sync          blocked in jax.device_get (live counts, the collective's
-#                 output, the skew sample): the pump waits for a chip
+#                 output, the skew sample, the merge's splitter sample, which
+#                 has a span `merge_splitters` of its own inside): the pump
+#                 waits for a chip
 #   fill          host Python of the send side: page intake, fill programs
 #                 enqueued, and the pump loop's own bookkeeping
 #   dispatch      _dispatch: assemble, the collective lock, the program call
@@ -1213,10 +1226,12 @@ class StreamingExchange:
         buffering)."""
         W, C = self.W, self.chunk_rows
         ncols = len(self.types)
+        # MERGE's routing keys are the send side's work on the chunk (and
+        # the first chunk's freeze of the splitters a `sync`), before the
+        # dispatch proper
+        range_keys = self._merge_range_keys(state) \
+            if self.kind == MERGE else None
         t0 = self._enter(DISPATCH, kind=self.kind, program="exchange-stream")
-        range_keys = None
-        if self.kind == MERGE:
-            range_keys = self._merge_range_keys(state)
         # skew plan (REPARTITION only; both sets frozen by _absorb_gen's
         # freeze-then-wait handshake before the first dispatch): a non-empty
         # plan swaps in the skew routing program for the whole stream
@@ -1341,19 +1356,33 @@ class StreamingExchange:
                                        desc, nf))
         self._range_dtype = str(keys[0].dtype)
         if self._splitters is None:
-            samples = []
-            for w in range(self.W):
-                lw = state[w].count
-                if lw:
-                    stride = max(1, lw // 128)
-                    samples.append(np.asarray(keys[w][:lw:stride][:128]))
-            pooled = np.sort(np.concatenate(samples)) if samples else \
-                np.zeros(1, dtype=keys[0].dtype)
-            self._splitters = np.asarray(
-                [pooled[len(pooled) * i // self.W]
-                 for i in range(1, self.W)], dtype=pooled.dtype)
+            self._freeze_splitters(state, keys)
         return [jax.device_put(keys[w], self.mesh.devices[w])
                 for w in range(self.W)]
+
+    def _freeze_splitters(self, state, keys) -> None:
+        """The W-1 range splitters from up to 128 keys of each worker's
+        first chunk, read to the host in ONE transfer: a `sync` of the pump
+        with the span `merge_splitters` around the read."""
+        import jax
+
+        samples = []
+        for w in range(self.W):
+            lw = state[w].count
+            if lw:
+                samples.append(keys[w][:lw:max(1, lw // 128)][:128])
+        self._enter(SYNC, of="splitters")
+        with trace.span(trace.EXCHANGE,
+                        f"merge_splitters f{self.fragment_id}",
+                        rows=sum(int(s.shape[0]) for s in samples),
+                        splitters=self.W - 1):
+            samples = jax.device_get(samples)
+        self._enter(FILL)
+        pooled = np.sort(np.concatenate(samples)) if samples else \
+            np.zeros(1, dtype=keys[0].dtype)
+        self._splitters = np.asarray(
+            [pooled[len(pooled) * i // self.W]
+             for i in range(1, self.W)], dtype=pooled.dtype)
 
     def _detect_hot(self, state) -> np.ndarray:
         """Heavy-hitter sample over the FIRST chunk's staged rows (all

@@ -278,21 +278,46 @@ def test_a_cells_plan_is_the_text_the_tree_before_pr36_printed(schema, query):
     assert _runner(schema).explain(QUERIES[query]) + "\n" == golden
 
 
+def _exchange_kinds(text):
+    """-> {kind: fragments whose output exchange is of it}, and the key
+    lists of the repartitions, from `explain`'s fragment heads"""
+    kinds, keys = {}, []
+    for line in text.splitlines():
+        if line.startswith("Fragment ") and " output=" in line:
+            kind = line.split(" output=")[1].split(" ")[0]
+            kinds[kind] = kinds.get(kind, 0) + 1
+            if kind == "repartition":
+                keys.append(line.split(" keys=")[1])
+    return kinds, keys
+
+
+@pytest.mark.parametrize("query, golden, kinds, two_column", [
+    (3, "q3_sf1_mesh4.txt", {"repartition": 5, "gather": 1}, 0),
+    (5, "q5_sf1_mesh4.txt",
+     {"broadcast": 3, "repartition": 5, "merge": 1, "gather": 1}, 2)],
+    ids=["q3_sf1_mesh4", "q5_sf1_mesh4"])
 def test_the_mesh_cells_q3_plans_the_same_fragments_and_exchanges(
-        eight_devices):
-    """As `tpch-sf1-mesh4` plans it: schema `sf1.0`, four workers, no session
-    property (PARTITIONED is what `add_exchanges` chooses by cost)."""
+        eight_devices, query, golden, kinds, two_column):
+    """As `tpch-sf1-mesh4` and `tpch-sf1-q5-mesh4` plan it: schema `sf1.0`,
+    four workers, no session property (`add_exchanges` chooses by cost:
+    Q3's joins PARTITIONED; Q5's supplier, nation and region replicated,
+    its cyclic edge on two columns, its ORDER BY merged)."""
     from presto_tpu.parallel.mesh import MeshContext
     from presto_tpu.parallel.runner import DistributedQueryRunner
 
     runner = DistributedQueryRunner(
         MeshContext(eight_devices[:4], n_workers=4),
         session=Session(catalog="tpch", schema="sf1.0"))
-    with open(os.path.join(GOLDEN, "q3_sf1_mesh4.txt")) as f:
-        golden = f.read()
-    text = runner.explain(QUERIES[3])
-    assert text + "\n" == golden
-    assert text.count("output=repartition") == 5 and "broadcast" not in text
+    with open(os.path.join(GOLDEN, golden)) as f:
+        expected = f.read()
+    text = runner.explain(QUERIES[query])
+    assert text + "\n" == expected
+    if query == 3:
+        assert text.count("output=repartition") == 5 and \
+            "broadcast" not in text
+    found, keys = _exchange_kinds(text)
+    assert found == kinds
+    assert sum(k.count(",") == 1 for k in keys) == two_column
 
 
 def test_the_order_is_timed_as_a_span_and_a_histogram():

@@ -786,7 +786,12 @@ def test_with_no_recorder_a_pump_builds_no_span(mesh2, local, monkeypatch,
     assert {"pump_fill", "pump_sync", "chunk_dispatch",
             "chunk_deliver"} <= {name for name, _args in spans}, spans
     assert {args["of"] for name, args in spans if name == "pump_sync"} <= \
-        {"live", "carry", "deliver", "hot"}
+        {"live", "carry", "deliver", "hot", "splitters"}
+    # the ORDER BY's MERGE froze its splitters once, inside such a sync
+    (froze,) = [args for name, args in spans if name == "merge_splitters"]
+    assert froze["splitters"] == mesh2.n_workers - 1 and froze["rows"] >= 1
+    assert [args for name, args in spans
+            if name == "pump_sync" and args["of"] == "splitters"]
     assert all(args["chunk"] >= 1 for name, args in spans
                if name == "chunk_deliver")
     assert pump_spans(query_blackbox=False) == []
