@@ -112,6 +112,61 @@ def _segment_reduce(kind: str, values, seg_ids, num_segments: int):
     return _SCATTER[kind](values, seg_ids, num_segments=num_segments)
 
 
+# Width of the blocks of the sorted-segment scan (_segmented_scan): one row
+# of the TPU's 128 lanes, so a block is log2(128) = 7 shifted passes and the
+# block totals scan again at 1/128 the size. Chosen over jnp.cumsum and a flat
+# lax.associative_scan by tools/sorted_reduce_sweep.py on a v5e (PERF.md
+# section 6).
+SCAN_BLOCK = 128
+
+
+def _lane_scan(op, starts, values):
+    """Inclusive segmented scan along the last axis, Hillis-Steele: pass d
+    folds each element's running value into the one d lanes later unless a
+    segment starts between them. Returns (any start so far, scan)."""
+    width = values.shape[-1]
+    lane = jnp.arange(width, dtype=jnp.int32)
+    d = 1
+    while d < width:
+        reach = lane >= d
+        take = reach & ~starts
+        values = jnp.where(take, op(jnp.roll(values, d, axis=-1), values),
+                           values)
+        starts = starts | (reach & jnp.roll(starts, d, axis=-1))
+        d *= 2
+    return starts, values
+
+
+def _segmented_scan(op, starts, values):
+    """out[i] = op-reduction of values[s..i], s the last row <= i where
+    `starts` is set (row 0 where none is): a scan of blocks of SCAN_BLOCK
+    rows, then of the block totals (the same function, 1/SCAN_BLOCK the
+    size), whose running value each block's rows before its first start
+    take in. Only shifts and selects: no scatter, no reduce-window."""
+    n = values.shape[0]
+    if n <= SCAN_BLOCK:
+        return _lane_scan(op, starts[None], values[None])[1][0]
+    pad = -n % SCAN_BLOCK
+    if pad:  # tail rows that start segments of their own
+        starts = jnp.concatenate([starts, jnp.ones(pad, dtype=jnp.bool_)])
+        values = jnp.concatenate([values, jnp.zeros(pad, values.dtype)])
+    seen, run = _lane_scan(op, starts.reshape(-1, SCAN_BLOCK),
+                           values.reshape(-1, SCAN_BLOCK))
+    totals = _segmented_scan(op, seen[:, -1], run[:, -1])
+    carry = jnp.roll(totals, 1)[:, None]  # the scan up to the block before
+    first_block = (jnp.arange(run.shape[0], dtype=jnp.int32) == 0)[:, None]
+    out = jnp.where(seen | first_block, run, op(carry, run))
+    return out.reshape(-1)[:n]
+
+
+def _sorted_scan_columns(kinds, widths) -> Tuple[bool, ...]:
+    """Which state columns sort_group_reduce reduces by the segmented scan
+    over its sorted rows: the scalar SUM, MIN and MAX ones. AMIN/AMAX with
+    their ACARRY payloads and wide (sketch) states keep _reduce_all's
+    scatters."""
+    return tuple(k in _COMBINE and w == 1 for k, w in zip(kinds, widths))
+
+
 WIDE_STATE_MAX_GROUPS = 1 << 13  # scatter-table bound for sketch aggregates
 
 
@@ -286,9 +341,21 @@ def sort_group_reduce(keys: Tuple[jnp.ndarray, ...], mask: jnp.ndarray,
 
     Returns (group_keys, group_states, group_valid_mask). Invalid input rows and
     groups beyond out_groups are dropped (caller sizes out_groups to capacity).
+
+    The rows are sorted, so a group's rows are one run: valid rows first, in
+    group order, then the overflow groups, then the invalid rows. Scalar
+    SUM/MIN/MAX states are a segmented scan over the sorted rows read at each
+    group's last row (_sorted_scan_columns); the rest go through _reduce_all.
     """
     n = mask.shape[0]
     widths = widths or (1,) * len(kinds)
+    if n == 0:  # nothing to sort, and nothing to gather from
+        z = (jnp.zeros(out_groups, dtype=k.dtype) for k in keys)
+        return (tuple(z), tuple(
+            jnp.full((out_groups, w) if w > 1 else (out_groups,), ident,
+                     dtype=c[1].dtype if isinstance(c, tuple) else c.dtype)
+            for c, ident, w in zip(contribs, identities, widths)),
+            jnp.zeros(out_groups, dtype=jnp.bool_), jnp.int32(0))
     invalid = ~mask
     order = lexsort_fast(tuple(reversed(keys)) + (invalid,))
     sk = tuple(k[order] for k in keys)
@@ -296,24 +363,34 @@ def sort_group_reduce(keys: Tuple[jnp.ndarray, ...], mask: jnp.ndarray,
     sc = tuple((c[0][order], c[1][order]) if isinstance(c, tuple) else c[order]
                for c in contribs)
 
-    first = jnp.zeros(n, dtype=jnp.bool_).at[0].set(True)
-    diff = jnp.zeros(n, dtype=jnp.bool_)
+    diff = jnp.arange(n, dtype=jnp.int32) == 0
     for k in sk:
         diff = diff | (k != jnp.roll(k, 1))
-    new_group = sv & (first | diff)
-    gid = jnp.cumsum(new_group.astype(jnp.int32)) - 1
-    num_groups = jnp.where(n > 0, gid[-1] + 1, 0)
+    new_group = sv & diff
+    # the groups' prefix count by the same scan (a jnp.cumsum is a
+    # reduce-window on the TPU, many times slower to compile)
+    gid = _segmented_scan(jax.lax.add, jnp.zeros(n, dtype=jnp.bool_),
+                          new_group.astype(jnp.int32)) - 1
+    num_groups = gid[-1] + 1
     gid = jnp.where(sv, gid, out_groups)  # trash bin
     gid = jnp.minimum(gid, out_groups)    # overflow also lands in the bin
 
-    states = _reduce_all(sc, kinds, identities, widths, gid, out_groups)
-    # group keys: first sorted row per group, ONE segment_min + a cheap
-    # gather per key column (the old per-key scatter into an out_groups
-    # table cost a full scatter pass per key — the dominant fold cost on
-    # multi-key aggregations). Empty slots gather garbage; gvalid masks them.
+    # each group's first sorted row: ONE int32 segment_min, the bin's too
     first = _segment_reduce(MIN, jnp.arange(n, dtype=jnp.int32), gid,
-                            out_groups + 1)[:out_groups]
-    safe = jnp.clip(first, 0, max(n - 1, 0))
+                            out_groups + 1)
+    # ... and its last: the row before the next group's first, or the last
+    # valid row (an empty slot's `first` is the int32 max)
+    valid_rows = jnp.sum(sv, dtype=jnp.int32)
+    last = jnp.clip(jnp.minimum(first[1:], valid_rows) - 1, 0, n - 1)
+    scan = _sorted_scan_columns(kinds, widths)
+    others = [i for i, s in enumerate(scan) if not s]
+    rest = iter(_reduce_all(*([seq[i] for i in others] for seq in (
+        sc, kinds, identities, widths)), gid, out_groups))
+    states = [_segmented_scan(_COMBINE[kind], new_group, c)[last] if s
+              else next(rest) for c, kind, s in zip(sc, kinds, scan)]
+    # group keys: a gather per key column at the first row (empty slots
+    # gather garbage; gvalid masks them)
+    safe = jnp.clip(first[:out_groups], 0, n - 1)
     gkeys = [k[safe] for k in sk]
     gvalid = jnp.arange(out_groups, dtype=jnp.int32) < jnp.minimum(num_groups, out_groups)
     # overwrite empty-group states with identities so MIN/MAX don't leak sentinels
@@ -378,6 +455,11 @@ class GroupedAggregationBuilder:
         self.identities: Tuple = tuple(
             col.identity for c in calls for col in c.function.state)
         self.widths: Tuple[int, ...] = _state_widths(calls)
+        # what each sort_group_reduce call of this builder does with its
+        # state columns, counted where a partial or a fold passes the host
+        scan = sum(_sorted_scan_columns(self.kinds, self.widths))
+        self._column_forms = {"scan_columns": scan,
+                              "scatter_columns": len(self.kinds) - scan}
         # vector (sketch) states scatter into (groups, width) tables — bound
         # BOTH the per-page group table and the device accumulator; overflow
         # beyond max_groups spills compacted partials to host RAM as usual
@@ -509,6 +591,7 @@ class GroupedAggregationBuilder:
         into the trash bin): the caller must recompute that page at the
         then-reset full size."""
         gkeys, gstates, gvalid, ng = partial
+        METRICS.count_many(self._column_forms, prefix="agg.sort.")
         full = capacity if self._wide_cap is None \
             else min(capacity, self._wide_cap)
         if out_groups < full:
@@ -593,6 +676,7 @@ class GroupedAggregationBuilder:
             gkeys, gstates, gvalid, ngroups = _combine_parts_kernel(
                 key_parts, valid_parts, state_parts, self.kinds,
                 self.identities, size, self.widths)
+            METRICS.count_many(self._column_forms, prefix="agg.sort.")
             n = int(ngroups)
             if n <= size or size >= self.max_groups:
                 break

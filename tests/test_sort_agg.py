@@ -2,17 +2,22 @@
 page level, against a plain Python dict group-by written here.
 
 Key distributions: a few groups (shrunken partials), one group, groups ~
-rows (the defer-raw path), NULL keys, two keys, float keys.
+rows (the defer-raw path), NULL keys, two keys, float keys. Then the
+kernel's sorted-segment reduction against jax.ops.segment_*, what it lowers
+to, and the counters of its column forms.
 """
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from presto_tpu.block import Block, Page, page_from_arrays
-from presto_tpu.ops.aggregates import AggregateCall, resolve_aggregate
+from presto_tpu.ops import hash_agg
+from presto_tpu.ops.aggregates import (MAX, MIN, SUM, AggregateCall,
+                                       resolve_aggregate)
 from presto_tpu.ops.hash_agg import GroupedAggregationBuilder
-from presto_tpu.types import BIGINT, DOUBLE
+from presto_tpu.types import BIGINT, DOUBLE, decimal_type
 
 
 def _agg_pages(rng, npages, cap, dist, with_nulls=False):
@@ -116,3 +121,122 @@ def test_float_keys():
     want = _plain_group_by(pages, 1)
     assert (0.5,) in want and (1.0,) in want  # halves stay apart
     assert _agg_result(pages, [DOUBLE]) == want
+
+
+# ------------------------------------------- the sorted-segment reduction
+
+SCATTER = {SUM: jax.ops.segment_sum, MIN: jax.ops.segment_min,
+           MAX: jax.ops.segment_max}
+ROWS = 300        # not a whole number of the scan's blocks: a padded tail
+OVERFLOW_GROUPS = 8
+
+
+def _sorted_case(case, dist, dtype, rng):
+    """(keys, mask, values, out_groups) of one page of the parametrised
+    case."""
+    n = 0 if case == "zero_row" else ROWS
+    if dist == "one":
+        keys = np.full(n, 42, dtype=np.int64)
+    elif dist == "few":
+        keys = rng.integers(0, 17, n).astype(np.int64) * 3 - 7
+    else:  # every row its own group
+        keys = rng.permutation(n).astype(np.int64) * 5
+    dtype = np.dtype(dtype)
+    if case == "wrap":  # sums past 2^63 (2^31 for int32) wrap as the scatter's
+        hi = np.iinfo(dtype).max if dtype.kind == "i" else 2.0 ** 62
+        values = rng.integers(int(hi) // 4, int(hi) // 2, n).astype(dtype)
+    else:
+        values = rng.integers(-1000, 1000, n).astype(dtype)
+        if dtype.kind == "f":
+            values = values / 8
+    if case == "nan":  # NaN in a float column, the extremes in an int one
+        odd = rng.random(n) < 0.1
+        if dtype.kind == "f":
+            values[odd] = np.nan
+        else:
+            info = np.iinfo(dtype)
+            values[odd] = np.where(rng.random(n) < 0.5, info.min,
+                                   info.max)[odd]
+    invalid = {"trash": 0.3, "overflow": 0.1}.get(case, 0.0)
+    mask = rng.random(n) >= invalid
+    out_groups = OVERFLOW_GROUPS if case == "overflow" else max(n, 16)
+    return keys, mask, values, out_groups
+
+
+@pytest.mark.parametrize("case", ["trash", "overflow", "zero_row", "wrap",
+                                  "nan"])
+@pytest.mark.parametrize("dist", ["one", "few", "own"])
+@pytest.mark.parametrize("dtype", ["int32", "int64", "float64"])
+@pytest.mark.parametrize("kind", [SUM, MIN, MAX])
+def test_sorted_reduction_equals_the_scatter(kind, dtype, dist, case):
+    """sort_group_reduce's segmented scan over the sorted rows, read at each
+    group's last row, against jax.ops.segment_* over the same groups: ints
+    and MIN/MAX bit-equal, float sums to 1e-12 (another association); the
+    trash rows reach no group, groups past out_groups are dropped, empty
+    slots hold the identity."""
+    rng = np.random.default_rng(
+        [SUM, MIN, MAX].index(kind) * 100 + len(dtype) * 10 + len(dist))
+    keys, mask, values, out_groups = _sorted_case(case, dist, dtype, rng)
+    ident = hash_agg._reduce_identity(kind, values.dtype).item()
+    gkeys, (state,), gvalid, num_groups = hash_agg._combine_kernel(
+        (jnp.asarray(keys),), jnp.asarray(mask), (jnp.asarray(values),),
+        (kind,), (ident,), out_groups, (1,))
+    uniq, group = np.unique(keys[mask], return_inverse=True)
+    want = SCATTER[kind](jnp.asarray(values[mask]),
+                         jnp.asarray(group, dtype=np.int32),
+                         num_segments=out_groups)
+    live = min(len(uniq), out_groups)
+    assert int(num_groups) == len(uniq)
+    assert np.asarray(gvalid).tolist() == [True] * live + \
+        [False] * (out_groups - live)
+    assert np.asarray(gkeys[0])[:live].tolist() == uniq[:live].tolist()
+    got = np.asarray(state)
+    assert got.dtype == values.dtype and got.shape == (out_groups,)
+    if kind == SUM and values.dtype.kind == "f":
+        np.testing.assert_allclose(got[:live], np.asarray(want)[:live],
+                                   rtol=1e-12, atol=0)
+    else:
+        np.testing.assert_array_equal(got[:live], np.asarray(want)[:live])
+    np.testing.assert_array_equal(got[live:], np.full(out_groups - live,
+                                                      ident, values.dtype))
+
+
+def test_the_fold_kernel_keeps_one_scatter_the_first_index():
+    """sum(decimal) + min + count(*) over more segments than the masked form
+    takes: the five state columns lower without a scatter; the one left is
+    the int32 segment_min that finds each group's first row."""
+    calls = [resolve_aggregate("sum", [decimal_type(12, 2)], False, ()),
+             resolve_aggregate("min", [BIGINT], False, ()),
+             resolve_aggregate("count", [], False, ())]
+    cols = [col for f in calls for col in f.state]
+    rows, groups = 16384, hash_agg.DENSE_REDUCE_MAX_SEGMENTS * 2
+    spec = jax.ShapeDtypeStruct
+    text = hash_agg._combine_kernel.lower(
+        (spec((rows,), np.int64), spec((rows,), np.bool_)),
+        spec((rows,), np.bool_), tuple(spec((rows,), c.dtype) for c in cols),
+        tuple(c.reduce for c in cols), tuple(c.identity for c in cols),
+        groups, (1,) * len(cols)).as_text()
+    lines = text.splitlines()
+    scatters = [i for i, line in enumerate(lines)
+                if '"stablehlo.scatter"(' in line]
+    assert len(cols) == 5 and len(scatters) == 1, scatters
+    # its signature closes the op's region a few lines down
+    op = "\n".join(lines[scatters[0]:scatters[0] + 8])
+    assert f"-> tensor<{groups + 1}xi32>" in op, op
+
+
+def test_a_q3_counts_its_columns_by_form():
+    """A local Q3 reduces sum(decimal)'s two states by the scan in every
+    partial and fold, and scatters none."""
+    from presto_tpu.metadata import Session
+    from presto_tpu.models.tpch_sql import QUERIES
+    from presto_tpu.runner import LocalQueryRunner
+    from presto_tpu.utils.metrics import METRICS
+
+    scan = METRICS.counter_value("agg.sort.scan_columns")
+    scatter = METRICS.counter_value("agg.sort.scatter_columns")
+    rows = LocalQueryRunner(session=Session(
+        catalog="tpch", schema="tiny")).execute(QUERIES[3]).rows
+    assert len(rows) == 10
+    assert METRICS.counter_value("agg.sort.scan_columns") > scan
+    assert METRICS.counter_value("agg.sort.scatter_columns") == scatter
