@@ -27,7 +27,7 @@ from ..block import Dictionary, Page
 from ..metadata import MetadataManager, Session
 from ..ops.aggregates import AggregateCall, resolve_aggregate
 from ..ops.expressions import (Constant, InputLayout, RowExpression, SymbolRef,
-                               input_ref, resolve_symbols, symbol_ref)
+                               call, input_ref, resolve_symbols, symbol_ref)
 from ..ops.filter_project import FilterProjectOperatorFactory, PageProcessor
 from ..ops.hash_agg import SINGLE, HashAggregationOperatorFactory
 from ..ops.hash_join import (ANTI, FULL, INNER, LEFT, SEMI, JoinBuildOperatorFactory,
@@ -43,7 +43,7 @@ from ..sql.planner.plan import (AggregationNode, EnforceSingleRowNode, FilterNod
                                 ProjectNode, RemoteSourceNode, SemiJoinNode,
                                 SortNode, Symbol,
                                 TableScanNode, TopNNode, UnionNode, ValuesNode)
-from ..types import BIGINT, BOOLEAN, Type, is_string
+from ..types import BIGINT, BOOLEAN, Type, is_integral, is_string
 from ..utils.testing import PageConsumerFactory
 from ..exec.driver import Driver
 
@@ -51,6 +51,15 @@ from ..exec.driver import Driver
 # union dictionaries interned by VALUE so re-planning the same query
 # yields the same Dictionary object (stable token -> kernel caches hit)
 _UNION_DICTS: Dict[tuple, Dictionary] = {}
+
+
+def _with_symbols(symbols: List[Symbol], more: List[Symbol]) -> List[Symbol]:
+    """`symbols`, then each of `more` that is not among them yet."""
+    out = list(symbols)
+    for s in more:
+        if all(s.name != o.name for o in out):
+            out.append(s)
+    return out
 
 
 def _intern_union_dict(values: List[str]) -> Dictionary:
@@ -293,6 +302,7 @@ class LocalExecutionPlan:
     # segment-compiler fusion decisions (exec/fused_segment): one entry per
     # candidate run of page-local operators, fused or not, with the reason
     segment_decisions: List[dict] = dataclasses.field(default_factory=list)
+    narrowed_joins: List[str] = dataclasses.field(default_factory=list)
 
     def create_drivers(self, worker: int = 0) -> List[Driver]:
         """Instantiate one driver set for `worker`. The factory list is planned
@@ -368,6 +378,9 @@ class LocalExecutionPlanner:
         self.remote_slots: Dict[int, RemoteSourceSlot] = {}
         self._ids = itertools.count()
         self.pipelines: List[List[object]] = []
+        # joins keyed on one unique clause with the others as a filter
+        # (_narrowed_clause), one line each for EXPLAIN ANALYZE
+        self.narrowed_joins: List[str] = []
 
     # ------------------------------------------------------------------ api
 
@@ -424,7 +437,7 @@ class LocalExecutionPlanner:
         return LocalExecutionPlan(self.pipelines, sink, root.column_names,
                                   [s.type for s in chain.symbols],
                                   list(chain.dicts), self.remote_slots,
-                                  decisions)
+                                  decisions, self.narrowed_joins)
 
     # ------------------------------------------------------ segment fusion
 
@@ -758,10 +771,13 @@ class LocalExecutionPlanner:
     def visit_JoinNode(self, node: JoinNode) -> Chain:
         if not node.criteria:
             return self._plan_cross_join(node)
-        left_keys = [l for l, _ in node.criteria]
-        right_keys = [r for _, r in node.criteria]
         jt = self._join_type(node)
-        unique = self._keys_unique(node.right, right_keys)
+        kept = self._narrowed_clause(node, jt)
+        criteria = [kept] if kept is not None else list(node.criteria)
+        checked = [c for c in node.criteria if c not in criteria]
+        left_keys = [l for l, _ in criteria]
+        right_keys = [r for _, r in criteria]
+        unique = kept is not None or self._keys_unique(node.right, right_keys)
         probe_chain = self._maybe_coalesce(
             self.visit(node.left),
             feeds_unfused_probe=not probe_plan_fusible(jt, left_keys, unique))
@@ -774,27 +790,63 @@ class LocalExecutionPlanner:
         probe_names = {s.name for s in probe_chain.symbols}
         probe_out = [s for s in out_syms if s.name in probe_names]
         build_out = [s for s in out_syms if s.name not in probe_names]
+        # the clauses checked behind the probe read both sides' columns
+        probe_cols = _with_symbols(probe_out, [l for l, _ in checked])
+        build_cols = _with_symbols(build_out, [r for _, r in checked])
 
-        payload_names = [s.name for s in build_out]
+        payload_names = [s.name for s in build_cols]
         payload_ch = [build_chain.channel(n) for n in payload_names]
         payload_meta = build_chain.meta(payload_names)
 
         build_fac = JoinBuildOperatorFactory(
             next(self._ids), build_key_ch, payload_ch, payload_meta,
             unique=unique,
-            track_unmatched=node.type == "full")
+            track_unmatched=node.type == "full",
+            narrowed=kept is not None)
         self._add_pipeline(build_chain.factories + [build_fac])
 
-        probe_out_ch = [probe_chain.channel(s.name) for s in probe_out]
-        probe_meta = probe_chain.meta([s.name for s in probe_out])
+        probe_out_ch = [probe_chain.channel(s.name) for s in probe_cols]
+        probe_meta = probe_chain.meta([s.name for s in probe_cols])
         probe_fac = LookupJoinOperatorFactory(
             next(self._ids), build_fac.lookup_factory, probe_key_ch,
             probe_out_ch, probe_meta, list(range(len(payload_ch))),
             payload_meta, jt, unique_build=unique)
         out_dicts = [probe_chain.dicts[c] for c in probe_out_ch] + \
                     [d for _, d in payload_meta]
-        return Chain(probe_chain.factories + [probe_fac],
-                     probe_out + build_out, out_dicts)
+        joined = Chain(probe_chain.factories + [probe_fac],
+                       probe_cols + build_cols, out_dicts)
+        if not checked:
+            return joined
+        condition = and_all([call("equal", BOOLEAN,
+                                  symbol_ref(l.name, l.type),
+                                  symbol_ref(r.name, r.type))
+                             for l, r in checked])
+        self.narrowed_joins.append(
+            f"{probe_fac.name} on {kept[0]} = {kept[1]}, filter {condition}")
+        return self._append_project(
+            joined, [(s, symbol_ref(s.name, s.type))
+                     for s in probe_out + build_out], condition)
+
+    def _narrowed_clause(self, node: JoinNode,
+                         jt: str) -> Optional[Tuple[Symbol, Symbol]]:
+        """The one clause an INNER join of several clauses is keyed on when
+        its build column alone is a unique integer: the build is then a
+        single-key unique one (a direct-address table, a probe that fuses),
+        and the other clauses, checked as SQL equalities on the joined row,
+        drop exactly the rows they would have failed to match, NULLs
+        included, since at most one build row matches a probe row. LEFT and
+        FULL joins keep every clause (a filter behind the probe would drop
+        rows they must emit with NULL build columns); so does a clause over
+        strings, whose dictionaries an equality does not reconcile."""
+        if jt != INNER or len(node.criteria) < 2:
+            return None
+        if any(l.type != r.type or is_string(l.type)
+               for l, r in node.criteria):
+            return None
+        for l, r in node.criteria:
+            if is_integral(r.type) and self._keys_unique(node.right, [r]):
+                return l, r
+        return None
 
     def _plan_cross_join(self, node: JoinNode) -> Chain:
         """Cross join via constant-key lookup join: both sides project a literal 0
@@ -1193,10 +1245,13 @@ class LocalExecutionPlanner:
     # ---------------------------------------------------------- helpers
 
     def _append_project(self, chain: Chain,
-                        assignments: List[Tuple[Symbol, RowExpression]]) -> Chain:
+                        assignments: List[Tuple[Symbol, RowExpression]],
+                        predicate: Optional[RowExpression] = None) -> Chain:
         channels = chain.channel_map()
         projections = [resolve_symbols(e, channels) for _, e in assignments]
-        processor = PageProcessor(chain.layout(), None, projections)
+        condition = resolve_symbols(predicate, channels) \
+            if predicate is not None else None
+        processor = PageProcessor(chain.layout(), condition, projections)
         fac = FilterProjectOperatorFactory(next(self._ids), processor=processor)
         return Chain(chain.factories + [fac], [s for s, _ in assignments],
                      processor.output_dicts)

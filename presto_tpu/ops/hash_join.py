@@ -21,14 +21,18 @@ structure is one of:
    [lo,hi) ranges and arbitrary key domains; multi-column keys are packed
    bijectively into the 64 bits where their ranges fit (`_plan_packing`), else go
    through a 64-bit mix with post-match verification on the true key columns
-   (collisions only mask rows, never corrupt results). The TPC-H joins that
-   take this form are the two-column ones: partsupp on (ps_partkey,
-   ps_suppkey), probed by lineitem in Q9 - a direct-address table over the
-   packed pair would have 2^32 slots at SF1 - and customer on (c_nationkey,
-   c_custkey) in Q5, unique on its customer key.
+   (collisions only mask rows, never corrupt results). The TPC-H join that
+   takes this form is the two-column one unique only as a pair: partsupp on
+   (ps_partkey, ps_suppkey), probed by lineitem in Q9 - a direct-address
+   table over the packed pair would have 2^32 slots at SF1. Q5's customer
+   join on (c_nationkey, c_custkey) is not built on two columns: the build
+   is unique on the integer c_custkey alone, so the planner keys it on that
+   clause (a direct-address table) and checks the nation as a filter behind
+   the probe (`LocalExecutionPlanner._narrowed_clause`).
 
 What a build became and what a probe page searched is counted: `join.builds`
-with `.dense`, `.sorted` and `.multikey` beside it, `join.probe.pages` with
+with `.dense`, `.sorted`, `.multikey` and `.narrowed` (keyed on one clause of
+several) beside it, `join.probe.pages` with
 `.sorted_pages` and `.expanded_pages` (a build that is not unique on the
 clauses: the path a fan-out takes), and the build's host seconds as the span `join.build` and the
 histogram `join.build_s` (`/v1/metrics`).
@@ -623,10 +627,14 @@ class JoinBuildOperatorFactory(OperatorFactory):
                  payload_channels: List[int],
                  payload_meta: List[Tuple[Type, Optional[Dictionary]]],
                  unique: bool = False,
-                 track_unmatched: bool = False):
+                 track_unmatched: bool = False,
+                 narrowed: bool = False):
         super().__init__(operator_id, "JoinBuild")
         # FULL joins need the NULL-key build rows preserved for unmatched output
         self.track_unmatched = track_unmatched
+        # keyed on one of its join's clauses, the others checked behind the
+        # probe (LocalExecutionPlanner._narrowed_clause): counted, no more
+        self.narrowed = narrowed
         self.key_channels = key_channels
         self.payload_channels = payload_channels
         self.payload_meta = payload_meta
@@ -675,7 +683,8 @@ class JoinBuildOperatorFactory(OperatorFactory):
         METRICS.count_many({"builds": 1,
                             "builds.dense": int(src.kind == "dense"),
                             "builds.sorted": int(src.kind == "sorted"),
-                            "builds.multikey": int(keys > 1)},
+                            "builds.multikey": int(keys > 1),
+                            "builds.narrowed": int(self.narrowed)},
                            prefix="join.")
         self.lookup_factory.set(src, w)
         op._pages = []  # consumed into the lookup source

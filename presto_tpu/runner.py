@@ -621,6 +621,9 @@ class LocalQueryRunner:
                     f"{'+'.join(s['operators'])} "
                     f"({s['dispatches']} dispatches, "
                     f"{s['compiles']} compiles)")
+        if exec_plan.narrowed_joins:
+            lines += ["", f"narrowed joins: {len(exec_plan.narrowed_joins)}"]
+            lines += [f"  {j}" for j in exec_plan.narrowed_joins]
         scan = _scan_pipeline_stats(drivers)
         if scan:
             lines += ["", "scan pipeline: " +

@@ -128,17 +128,20 @@ def test_broadcast_joins_pack_survivors_ahead_of_a_two_column_probe(local):
     """Both builds replicated, so one fragment holds the whole probe chain:
     the s1 join keeps a third of partsupp's rows (INNER, one unique key) and
     feeds a probe on two columns, which cannot fuse, and the local planner
-    puts a Coalesce between them (PR 35). Every worker's stream packs."""
+    puts a Coalesce between them (PR 35). Every worker's stream packs. The
+    s2 join is a LEFT one: an INNER join on these two clauses is keyed on
+    s2's unique `s_suppkey` alone and fuses (`_narrowed_clause`)."""
     from presto_tpu.metadata import Session
     from presto_tpu.utils.metrics import METRICS
 
     bcast = DistributedQueryRunner(
         session=Session(catalog="tpch", schema="tiny",
                         properties={"join_distribution_type": "BROADCAST"}))
-    sql = ("select count(*), sum(ps_supplycost), max(s2.s_acctbal) "
-           "from partsupp, supplier s1, supplier s2 "
-           "where ps_suppkey = s1.s_suppkey and s1.s_nationkey < 8 "
-           "and s2.s_suppkey = ps_suppkey "
+    sql = ("select count(*), sum(ps_supplycost), max(s2.s_acctbal), "
+           "count(s2.s_suppkey) "
+           "from partsupp join supplier s1 on ps_suppkey = s1.s_suppkey "
+           "and s1.s_nationkey < 8 "
+           "left join supplier s2 on s2.s_suppkey = ps_suppkey "
            "and s2.s_nationkey = s1.s_nationkey")
     d = check(bcast, local, sql)
     assert d.rows[0][0] > 0

@@ -6,13 +6,14 @@ builds it, answers Q5 from `benchmark/queries/q5.sql` through `client.dbapi`
 for the five regions and three of the five years; every answer is held, row
 for row and in order, to the benchmark's plain numpy reference
 (`benchmark/queries/q5.py`, which imports nothing of the program). Beside it,
-what the cell's per-layer metrics read: of the query's five join builds four
-take the direct-address table and ONE, customer's on (nation, customer key),
-the sorted form, which is unique on its customer key, so no probe page takes
-the expansion path a fan-out takes; every lineitem page is counted once for
-each of the four fused probes, and what the `CoalesceOperator` packs once for
-the customer probe; the aggregation on the dictionary key `n_name` is the
-dense (direct) one, behind a join; the join order has a span and a histogram.
+what the cell's per-layer metrics read: the query's five join builds all take
+the direct-address table, customer's too: its join on (nation, customer key)
+is keyed on the customer key alone, on which the build is unique, and checks
+the nation behind the probe (`join.builds.narrowed`); no probe page takes the
+expansion path a fan-out takes; every lineitem page is counted once for each
+of the five probes, which fuse into one segment with no `CoalesceOperator`
+behind them; the aggregation on the dictionary key `n_name` is the dense
+(direct) one, behind a join; the join order has a span and a histogram.
 """
 import json
 import os
@@ -30,9 +31,8 @@ from presto_tpu.utils.metrics import METRICS  # noqa: E402
 
 TINY_SF = 0.01
 REGIONS = ["AFRICA", "AMERICA", "ASIA", "EUROPE", "MIDDLE EAST"]
-YEARS = [1993, 1994, 1997]     # qgen's first, its default, its last
+YEARS = [1993, 1994, 1995, 1996, 1997]     # qgen's whole range
 PROBES = 5
-FUSED_PROBES = 4
 
 
 @pytest.fixture(scope="module")
@@ -117,36 +117,36 @@ def test_the_reference_imports_nothing_of_the_program():
 
 @pytest.mark.parametrize("fused", [True, False])
 def test_one_query_counts_its_builds_and_probe_pages(q5, fused):
-    """Five builds, four direct-address and one sorted on two key columns,
-    and no page through `_emit_expanded`: the two-column build is unique on
-    its customer key. Every lineitem page is counted once for each of the
-    four probes ahead of the Coalesce and every packed page once for the
-    customer probe, under `join.probe.sorted_pages` too: the same whether the
-    single-key probes ran inside a fused segment or as `LookupJoinOperator`s
-    (session property `segment_fusion`)."""
+    """Five builds, all direct-address on one key column: customer's join
+    on two clauses is narrowed to its customer key, and no page goes
+    through `_emit_expanded` or a binary search. Every lineitem page is
+    counted once for each of the five probes: the same whether the probes
+    ran inside a fused segment or as `LookupJoinOperator`s (session property
+    `segment_fusion`)."""
     result, gained = _run(q5.template.format(region="ASIA", year=1994),
                           segment_fusion=fused)
     assert len(result.rows) == 5
     segments = (result.stats or {}).get("segments") or {"count": 0}
-    assert segments["count"] == (2 if fused else 0)
+    assert segments["count"] == (1 if fused else 0)
     assert gained["join.builds"] == PROBES
-    assert gained["join.builds.dense"] == 4
-    assert gained["join.builds.sorted"] == 1
-    assert gained["join.builds.multikey"] == 1
+    assert gained["join.builds.dense"] == PROBES
+    assert gained.get("join.builds.sorted", 0) == 0
+    assert gained.get("join.builds.multikey", 0) == 0
+    assert gained["join.builds.narrowed"] == 1
     assert gained["join.build_s.n"] == PROBES
     assert gained.get("join.probe.expanded_pages", 0) == 0
-    packed = gained["join.probe.sorted_pages"]
-    scanned = (gained["join.probe.pages"] - packed) // FUSED_PROBES
-    assert scanned > packed >= 1
-    assert gained["join.probe.pages"] == FUSED_PROBES * scanned + packed
+    assert gained.get("join.probe.sorted_pages", 0) == 0
+    scanned = gained["join.probe.pages"] // PROBES
+    assert scanned >= 1
+    assert gained["join.probe.pages"] == PROBES * scanned
     if fused:
-        first, second = result.stats["segments"]["segments"]
-        assert first["operators"] == ["LookupJoin(inner)"] * FUSED_PROBES
-        assert second["operators"] == ["FilterProject",
-                                       "HashAggregation(single)"]
-        assert (first["dispatches"], second["dispatches"]) == (scanned, packed)
-    # lineitem's pages behind the four probes, and the filtered builds' pages
-    assert gained["coalesce.packed_pages"] == gained["coalesce.pages"] > scanned
+        (segment,) = result.stats["segments"]["segments"]
+        assert segment["operators"] == ["LookupJoin(inner)"] * PROBES + [
+            "FilterProject", "FilterProject", "HashAggregation(single)"]
+        assert segment["dispatches"] == scanned
+    # the filtered builds' pages (region, orders); nothing packs lineitem's
+    # (the plan's test below)
+    assert gained["coalesce.packed_pages"] == gained["coalesce.pages"] >= 1
     # n_name is a dictionary of 25: the dense aggregation, behind a join
     assert gained["agg.direct.dense_pages"] >= 1
     assert gained["agg.direct.dense_pages"] == gained["agg.direct.pages"]
@@ -162,19 +162,23 @@ def test_a_join_that_fans_out_is_counted_as_expanded_pages():
 
 
 def test_the_two_column_probe_stands_alone_between_two_fused_segments(q5):
-    """`probe_plan_fusible` refuses a probe on more than one key column, so
-    Q5's probe pipeline is four fused probes, a Coalesce that packs what the
-    region and the year kept, the customer probe as a standalone
-    `LookupJoinOperator`, then the projection fused with the aggregation."""
+    """It no longer does: the customer join is keyed on `c_custkey` alone,
+    so its probe fuses with the other four and the nation is checked by a
+    `FilterProject` in the same segment; no `Coalesce` stands behind
+    lineitem's probes, and EXPLAIN ANALYZE names the one key and the
+    filter."""
     runner = LocalQueryRunner(session=Session(catalog="tpch", schema="tiny"))
     plan = "\n".join(str(r[0]) for r in runner.execute(
         "explain analyze " + q5.template.format(region="ASIA", year=1994)).rows)
     probe = plan[plan.index("pipeline 5:"):plan.index("fused segments:")]
     operators = [line.split()[0] for line in probe.splitlines()[1:] if line]
     assert operators == ["TableScan", "FusedSegment[LookupJoin(in",
-                         "Coalesce", "LookupJoin(inner)",
-                         "FusedSegment[FilterProject", "OrderBy",
-                         "PageConsumer"]
+                         "OrderBy", "PageConsumer"]
+    assert "pipeline 5: " + "+".join(
+        ["LookupJoin(inner)"] * PROBES +
+        ["FilterProject", "FilterProject", "HashAggregation(single)"]) in plan
+    assert "narrowed joins: 1\n  LookupJoin(inner) on o_custkey = " \
+        "c_custkey, filter equal(s_nationkey, c_nationkey)" in plan
 
 
 def test_the_order_and_the_builds_have_their_spans(q5):
@@ -189,4 +193,4 @@ def test_the_order_and_the_builds_have_their_spans(q5):
     builds = [e["args"] for e in events
               if e.get("cat") == "join" and e["name"] == "build"]
     assert sorted((b["kind"], b["keys"]) for b in builds) == \
-        [("dense", 1)] * 4 + [("sorted", 2)]
+        [("dense", 1)] * PROBES
