@@ -23,10 +23,8 @@ building blocks the distributed planner stitches into stage programs.
 """
 from __future__ import annotations
 
-import functools
-from typing import Sequence, Tuple
+from typing import Sequence
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -41,22 +39,6 @@ def partition_ids(key: jnp.ndarray, n_parts: int) -> jnp.ndarray:
     never diverge."""
     x = _mix64(key)
     return (x % jnp.uint64(n_parts)).astype(jnp.int32)
-
-
-def repartition(arrays: Sequence[jnp.ndarray], mask: jnp.ndarray, key: jnp.ndarray,
-                n_parts: int, out_cap_per_peer: int,
-                axis_name: str = WORKER_AXIS):
-    """All-to-all repartition of a row batch by key hash. Call inside shard_map.
-
-    Each worker sends up to `out_cap_per_peer` rows to each peer (overflow rows are
-    DROPPED and reported via the returned drop count — callers size capacity so this
-    is a correctness assertion, the moral equivalent of the reference's buffer
-    backpressure). Returns (arrays', mask', dropped) where arrays'/mask' hold the rows
-    whose key hashes to THIS worker, shape (n_parts * out_cap_per_peer,).
-    """
-    pid = jnp.where(mask, partition_ids(key, n_parts), n_parts)
-    return repartition_by_pid(arrays, mask, pid, n_parts, out_cap_per_peer,
-                              axis_name)
 
 
 def range_partition_ids(range_key: jnp.ndarray, splitters: jnp.ndarray,
@@ -106,29 +88,15 @@ def move_rows(arrays: Sequence[jnp.ndarray], tgt: jnp.ndarray, n_slots: int,
             for a, b in zip(arrays, into)], filled
 
 
-def repartition_by_pid(arrays: Sequence[jnp.ndarray], mask: jnp.ndarray,
-                       pid: jnp.ndarray, n_parts: int, out_cap_per_peer: int,
-                       axis_name: str = WORKER_AXIS):
-    """Route rows to the peers named by `pid` (n_parts = masked-off). Shared
-    tail of hash REPARTITION and range MERGE exchanges; within a partition
-    rows keep their order."""
-    pos_in_part = _rank_in_partition(pid, n_parts)
-    live = pid < n_parts
-    keep = live & (pos_in_part < out_cap_per_peer)
-    dropped = jnp.sum(live & ~keep)
-    outs, recv_mask = _route_kept(arrays, pid, pos_in_part, keep,
-                                  n_parts, out_cap_per_peer, axis_name)
-    return outs, recv_mask, dropped
-
-
 def repartition_by_pid_with_carry(arrays: Sequence[jnp.ndarray],
                                   mask: jnp.ndarray, pid: jnp.ndarray,
                                   n_parts: int, out_cap_per_peer: int,
                                   axis_name: str = WORKER_AXIS):
-    """Carry-over variant for the STREAMING exchange: overflow rows (the ones
-    `repartition_by_pid` would drop when a peer's slice of this chunk exceeds
-    `out_cap_per_peer`) are returned compacted to the front of same-shape
-    carry buffers instead, staying resident on this worker for the pump to
+    """Route rows to the peers named by `pid` (n_parts = masked-off), the
+    STREAMING exchange's hash REPARTITION and range MERGE; within a partition
+    rows keep their order. Overflow rows (those past `out_cap_per_peer` in a
+    peer's slice of this chunk) are returned compacted to the front of
+    same-shape carry buffers, staying resident on this worker for the pump to
     re-feed into the next chunk. Skewed keys are therefore correct by
     construction — capacity only bounds per-dispatch volume, never rows.
 
@@ -149,7 +117,7 @@ def repartition_by_pid_with_carry(arrays: Sequence[jnp.ndarray],
 def _route_kept(arrays, pid, pos_in_part, keep, n_parts: int,
                 out_cap_per_peer: int, axis_name: str):
     """Move the kept rows into (n_parts, cap) send buffers and run the
-    all_to_all; shared tail of the drop and carry repartitions."""
+    all_to_all."""
     slots = n_parts * out_cap_per_peer
     tgt = jnp.where(keep, pid * out_cap_per_peer + pos_in_part, slots)
     bufs, send_mask = move_rows(arrays, tgt, slots)

@@ -30,7 +30,7 @@ in flight. This module is that data plane, TPU-shaped:
   (XLA dispatch returns futures) and its delivery sync is deferred until
   chunk k+1 has been absorbed and dispatched, so host-side compaction of the
   next chunk overlaps the in-flight collective;
-- REPARTITION/MERGE overflow rows (what `repartition_by_pid` would drop)
+- REPARTITION/MERGE overflow rows (those past a peer's slot count in a chunk)
   come back as same-shape CARRY buffers, re-fed into the next chunk — skewed
   keys are correct by construction, not by worst-case capacity sizing;
 - received rows are PACKED per consumer into pages of the shard's length,

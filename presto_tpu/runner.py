@@ -3,7 +3,7 @@
 Analogue of presto-main testing/LocalQueryRunner.java:210 (executeInternal :620,
 createDrivers :679): the single-process full-engine path used by ring-2 tests and
 benchmarks — no HTTP, real operators. The distributed runner
-(parallel/distributed.py) layers the mesh exchange on the same plans.
+(parallel/runner.py) layers the mesh exchange on the same plans.
 """
 from __future__ import annotations
 

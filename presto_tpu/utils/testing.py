@@ -7,8 +7,10 @@ H2QueryRunner.java:88) — here the oracle is sqlite3 over the same generated da
 """
 from __future__ import annotations
 
+import contextlib
 import math
 import sqlite3
+import threading
 from typing import Any, Iterable, List, Optional, Sequence
 
 import numpy as np
@@ -102,8 +104,81 @@ def assert_no_residue(pool, query_id: Optional[str] = None) -> None:
 
 
 # ---------------------------------------------------------------------------
+# program recorder
+# ---------------------------------------------------------------------------
+
+def _abstract(tree):
+    import jax
+
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)
+        if isinstance(x, (jax.Array, np.ndarray)) else x, tree)
+
+
+class ProgramRecorder:
+    """Wraps jitted callables so that each top-level call leaves (callable,
+    args, kwargs) behind: their shapes, or with `keep_values` the arrays
+    themselves. `recording()` wraps every kernel the engine builds through
+    `kernel_cache.get_or_build`."""
+
+    def __init__(self, keep_values: bool = False):
+        self.keep_values = keep_values
+        self.calls = []
+        self._lock = threading.Lock()
+
+    def wrap(self, fn):
+        import jax
+
+        def recorded(*args, **kwargs):
+            leaves = jax.tree_util.tree_leaves((args, kwargs))
+            if not any(isinstance(x, jax.core.Tracer) for x in leaves):
+                kept = (args, kwargs) if self.keep_values \
+                    else _abstract((args, kwargs))
+                with self._lock:
+                    self.calls.append((fn, kept))
+            return fn(*args, **kwargs)
+        return recorded
+
+    @contextlib.contextmanager
+    def recording(self):
+        from . import kernel_cache
+
+        real_build = kernel_cache.get_or_build
+
+        def recording_build(key, make):
+            fn, built = real_build(key, make)
+            return (self.wrap(fn) if callable(fn) else fn), built
+
+        kernel_cache.get_or_build = recording_build
+        try:
+            yield self
+        finally:
+            kernel_cache.get_or_build = real_build
+
+    def named(self, part: str):
+        """Distinct recorded programs whose function name contains `part`,
+        as (fn, args, kwargs)."""
+        import jax
+
+        seen, out = set(), []
+        for fn, (args, kwargs) in self.calls:
+            name = getattr(fn, "__qualname__", "") or repr(fn)
+            shapes = _abstract((args, kwargs))
+            sig = (name, str(jax.tree_util.tree_structure(shapes)),
+                   tuple(str(x) for x in jax.tree_util.tree_leaves(shapes)))
+            if part in name and sig not in seen:
+                seen.add(sig)
+                out.append((fn, args, kwargs))
+        return out
+
+
+# ---------------------------------------------------------------------------
 # sqlite oracle
 # ---------------------------------------------------------------------------
+
+TPCH_KEY_SUFFIXES = ("_orderkey", "_partkey", "_suppkey", "_custkey",
+                     "_nationkey", "_regionkey")
+
 
 class SqliteOracle:
     """Loads generated TPC-H data into sqlite and runs reference SQL.
@@ -134,6 +209,12 @@ class SqliteOracle:
                 data = g.generate_rows(t, 0, n, schema_sf,
                                        [c[0] for c in cols])
             self._load_table(t, cols, data)
+            # without them sqlite plans a correlated subquery (Q21's
+            # EXISTS / NOT EXISTS over lineitem) as a scan per outer row
+            for name, _, _ in cols:
+                if name.endswith(TPCH_KEY_SUFFIXES):
+                    self.conn.execute(f"CREATE INDEX IF NOT EXISTS "
+                                      f"{name}_idx ON {t} ({name})")
         self.conn.commit()
 
     def load_tpcds(self, schema_sf: float, tables: Sequence[str]) -> None:

@@ -174,10 +174,12 @@ def test_live_progress_is_monotone_while_running(server):
     snaps = []
     for _attempt in range(3):
         qid = _submit(base, sql)
+        deadline = time.monotonic() + 120
         while True:
             q = json.loads(_get(base, f"/v1/query/{qid}"))
             if q["state"] in ("FAILED", "FINISHED"):
                 break
+            assert time.monotonic() < deadline, f"the query never ended: {q}"
             if q["state"] == "RUNNING" and q.get("progress"):
                 snaps.append(q["progress"])
             time.sleep(0.01)

@@ -141,11 +141,13 @@ class _ChunkSource:
 
 def _drain(pipe):
     pages = []
+    deadline = time.monotonic() + 60
     while True:
         p = pipe.next()
         if p is None:
             return pages
         pages.append(p)
+        assert time.monotonic() < deadline, "the pipeline never ended"
 
 
 def test_pipeline_preserves_split_order_under_racing_readers():

@@ -118,11 +118,13 @@ class _SplitSource:
 
 def _drain_rows(pipe: ScanPipeline):
     got = []
+    deadline = time.monotonic() + 60
     while True:
         page = pipe.next()
         if page is None:
             break
         got.append(np.asarray(page.blocks[0].data)[np.asarray(page.mask)])
+        assert time.monotonic() < deadline, "the pipeline never ended"
     pipe.close()
     return np.concatenate(got).tolist() if got else []
 

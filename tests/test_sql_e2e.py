@@ -10,7 +10,8 @@ import pytest
 
 from presto_tpu.runner import LocalQueryRunner
 from presto_tpu.models.tpch_sql import QUERIES
-from presto_tpu.utils.testing import SqliteOracle, assert_rows_equal
+from presto_tpu.utils.testing import (TPCH_KEY_SUFFIXES, SqliteOracle,
+                                      assert_rows_equal)
 
 
 def to_sqlite(sql: str) -> str:
@@ -208,3 +209,21 @@ def test_cross_join_small(runner, oracle):
 @pytest.mark.parametrize("q", sorted(QUERIES))
 def test_tpch(runner, oracle, q):
     check(runner, oracle, QUERIES[q], ordered=True, rel_tol=1e-9)
+
+
+def test_oracle_indexes_the_tpch_keys(oracle):
+    """Every key column of the loaded tables carries an index, so that sqlite
+    plans Q21's correlated NOT EXISTS as an index search of lineitem, not a
+    scan of it per outer row."""
+    keys = {col for t, in oracle.query(
+                "select name from sqlite_master where type = 'table'")
+            for _, col, *_ in oracle.query(f"pragma table_info({t})")
+            if col.endswith(TPCH_KEY_SUFFIXES)}
+    assert len(keys) == 15, keys
+    indexed = {col for col, in oracle.query(
+        "select ii.name from sqlite_master m, pragma_index_info(m.name) ii "
+        "where m.type = 'index'")}
+    assert keys <= indexed, keys - indexed
+    plan = [detail for _, _, _, detail in oracle.query(
+        "explain query plan " + to_sqlite(QUERIES[21]))]
+    assert any(d.startswith("SEARCH l3 USING INDEX") for d in plan), plan

@@ -310,8 +310,11 @@ def _run_exchange(mesh, kind, pages_by_worker, lengths=None, **kw):
 
     def consume(w):
         buf = ex.out_buffer(w)
+        deadline = time.monotonic() + 60
         try:
             while True:
+                assert time.monotonic() < deadline, \
+                    f"consumer {w}: the stream never ended"
                 page = buf.poll()
                 if page is not None:
                     if lengths is not None:
@@ -588,8 +591,9 @@ def test_close_while_blocked(mesh2):
 
     def consume():
         buf = ex.out_buffer(1)
+        deadline = time.monotonic() + 10
         try:
-            while True:
+            while time.monotonic() < deadline:
                 if buf.poll() is None:
                     if buf.is_done(None):
                         poll_error["done"] = True

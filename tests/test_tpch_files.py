@@ -367,7 +367,8 @@ def test_racing_lookups_store_a_table_once(tmp_path):
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(timeout=60)
+        assert not t.is_alive(), "a lookup never came back from the store"
     assert not errors and len(handles) == 4 and len(set(handles)) == 1
     gained = _gained(before)
     assert gained["tpch.store.tables"] == 1
